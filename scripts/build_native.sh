@@ -16,4 +16,7 @@ cmake -S src/cc -B build -G Ninja \
   -DTDX_SANITIZERS="${SANS}" >/dev/null
 cmake --build build >/dev/null
 cp build/libtdx_core.so torchdistx_tpu/lib/
+# The source-hash stamp _native.py compares before trusting a .so.
+cat src/cc/tdx_core/graph.cc src/cc/tdx_core/graph.h | sha256sum \
+  | cut -d' ' -f1 > torchdistx_tpu/lib/libtdx_core.so.srchash
 echo "built torchdistx_tpu/lib/libtdx_core.so"

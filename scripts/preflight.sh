@@ -3,8 +3,9 @@
 #
 # Round 3 shipped a half-finished refactor that broke 1F1B for every model
 # because nothing gated the snapshot commit.  This script is the gate: a
-# fast pytest subset covering the paths the driver artifacts depend on,
-# plus the full multi-chip dryrun.  ~5 minutes; refuse to commit if red.
+# fast pytest subset plus the dry run of every parallelism stage on a
+# virtual 8-device CPU mesh.  It says nothing about the chip — that is
+# `python chip_smoke.py`.  ~5 minutes; refuse to commit if red.
 #
 # Usage: scripts/preflight.sh [extra pytest args]
 set -euo pipefail
@@ -18,10 +19,10 @@ python -m pytest \
     tests/test_materialize_jax.py \
     -x -q "$@"
 
-echo "== preflight: multi-chip dryrun (8 virtual devices) =="
+echo "== preflight: parallelism dry run (8 virtual CPU devices) =="
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-echo "== preflight: single-chip entry compile check =="
+echo "== preflight: entry() compile check (default backend) =="
 python - <<'EOF'
 import jax
 import __graft_entry__ as g

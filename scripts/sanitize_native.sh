@@ -20,9 +20,16 @@ PY_INCLUDE=$(python -c "import sysconfig; print(sysconfig.get_paths()['include']
 g++ -std=c++17 -O1 -g -fPIC -shared $SAN -I"$PY_INCLUDE" -Isrc/cc/tdx_core \
   -o "$LIBDIR/_tdx_stack.so" src/cc/tdx_core/stack.cc src/cc/tdx_core/graph.cc
 
-# Touch the libs so the loaders' staleness check doesn't rebuild over the
-# sanitized artifacts.
-touch "$LIBDIR"/libtdx_core.so "$LIBDIR"/_tdx_stack.so
+# Stamp the libs with their sources' hash (what _native.py compares) so
+# the loaders don't rebuild over the sanitized artifacts.
+stamp_libs() {
+  local S=src/cc/tdx_core
+  cat $S/graph.cc $S/graph.h | sha256sum | cut -d' ' -f1 \
+    > "$LIBDIR/libtdx_core.so.srchash"
+  cat $S/stack.cc $S/graph.cc $S/graph.h | sha256sum | cut -d' ' -f1 \
+    > "$LIBDIR/_tdx_stack.so.srchash"
+}
+stamp_libs
 
 ASAN_LIB=$(g++ -print-file-name=libasan.so)
 UBSAN_LIB=$(g++ -print-file-name=libubsan.so)
@@ -38,4 +45,5 @@ g++ -std=c++17 -O2 -fPIC -shared \
   -o "$LIBDIR/libtdx_core.so" src/cc/tdx_core/graph.cc
 g++ -std=c++17 -O2 -fPIC -shared -I"$PY_INCLUDE" -Isrc/cc/tdx_core \
   -o "$LIBDIR/_tdx_stack.so" src/cc/tdx_core/stack.cc src/cc/tdx_core/graph.cc
+stamp_libs
 echo "sanitizer lane: OK"

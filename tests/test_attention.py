@@ -461,6 +461,74 @@ class TestAutoSelection:
             "auto", None, None, (8, 64, 4, 16), (8, 64, 2, 16)
         ) == "pallas"
 
+    def test_on_tpu_propagates_backend_error(self, monkeypatch):
+        """A backend that fails to initialize is an error, never "not on
+        TPU": impl="auto" must not quietly train on the jnp path."""
+        from torchdistx_tpu.ops import attention as A
+
+        def broken():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        A._on_tpu.cache_clear()
+        monkeypatch.setattr(jax, "devices", broken)
+        try:
+            with pytest.raises(RuntimeError, match="initialize backend"):
+                A._on_tpu()
+            with pytest.raises(RuntimeError, match="initialize backend"):
+                A._select_impl(
+                    "auto", None, None, (8, 64, 4, 16), (8, 64, 2, 16)
+                )
+        finally:
+            monkeypatch.undo()
+            A._on_tpu.cache_clear()
+        assert A._on_tpu() is False  # the error was not cached as an answer
+
+    def test_resolved_choice_is_counted(self):
+        """What ``attention`` resolved to, and whether the flash kernel ran
+        interpreted, is observable per trace (chip_smoke.py asserts on it)."""
+        from torchdistx_tpu import telemetry
+
+        def delta(fn):
+            c0 = telemetry.counters()
+            fn()
+            c1 = telemetry.counters()
+            return {
+                k: c1[k] - c0.get(k, 0)
+                for k in c1
+                if k.startswith("attention.") and c1[k] != c0.get(k, 0)
+            }
+
+        q, k, v = _qkv(b=1, s=16)
+        # CPU backend: "auto" resolves to jnp — and says so.
+        assert delta(lambda: attention(q, k, v, impl="auto")) == {
+            "attention.dispatch{impl=jnp}": 1
+        }
+        # An explicit kernel request off-TPU runs the interpreter — and
+        # says so.
+        assert delta(lambda: attention(q, k, v, impl="pallas")) == {
+            "attention.dispatch{impl=pallas}": 1,
+            "attention.flash{interpret=true}": 1,
+        }
+
+    def test_kernels_lower_through_mosaic_for_tpu(self):
+        """All four kernels lower for the TPU platform with the installed
+        JAX (Mosaic's Python-side lowering needs no chip): forward + fused
+        backward below ``_FUSED_BWD_MAX_KV``, forward + the streamed dq and
+        dk/dv pair above it."""
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=True, interpret=False)
+            return out.astype(jnp.float32).sum()
+
+        for s, n_calls in ((1024, 2), (4096, 3)):
+            x = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
+            lowered = (
+                jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                .trace(x, x, x)
+                .lower(lowering_platforms=("tpu",))
+            )
+            assert lowered.as_text().count("tpu_custom_call") == n_calls
+
     def test_pp_forward_pins_jnp(self):
         from torchdistx_tpu.models import llama
 

@@ -29,18 +29,21 @@ def test_one_failing_stage_does_not_blank_the_rest(capsys):
         return "skipped (reason)"
 
     failures = _run_stages(
-        [("a", ok("a")), ("boom", boom), ("b", ok("b")), ("s", skipped)]
+        [("a", ok("a")), ("boom", boom), ("b", ok("b")), ("s", skipped)],
+        "cpu x8",
     )
     # Every stage ran despite the injected failure in the second.
     assert ran == ["a", "boom", "b", "skipped"]
     assert [name for name, _ in failures] == ["boom"]
     assert isinstance(failures[0][1], ValueError)
     out = capsys.readouterr().out
-    assert "[dryrun] a: PASS" in out
-    assert "[dryrun] boom: FAIL (ValueError: injected)" in out
-    assert "[dryrun] b: PASS" in out
-    assert "[dryrun] s: skipped (reason)" in out
+    assert "[dryrun cpu x8] a: PASS" in out
+    assert "[dryrun cpu x8] boom: FAIL (ValueError: injected)" in out
+    assert "[dryrun cpu x8] b: PASS" in out
+    assert "[dryrun cpu x8] s: skipped (reason)" in out
 
 
 def test_all_green_returns_no_failures():
-    assert _run_stages([("a", lambda: None), ("b", lambda: None)]) == []
+    assert _run_stages(
+        [("a", lambda: None), ("b", lambda: None)], "cpu x8"
+    ) == []

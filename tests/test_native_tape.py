@@ -36,6 +36,43 @@ def test_stack_ops_available():
     )
 
 
+def test_so_is_trusted_by_source_hash_not_mtime(tmp_path):
+    """A copied tree (or a fresh checkout beside an old build) resets
+    mtimes, so a ``.so`` counts as built from the sources only when the
+    hash stamped beside it equals theirs — otherwise it is rebuilt."""
+    import shutil
+
+    if shutil.which("g++") is None:
+        pytest.skip("no g++")
+    src = tmp_path / "f.cc"
+    src.write_text('extern "C" int f() { return 1; }\n')
+    lib = str(tmp_path / "lib" / "libf.so")
+    srcs = (str(src),)
+
+    assert not _native._built_from(lib, srcs)  # nothing there yet
+    assert _native._build(lib, srcs, [str(src)])
+    assert _native._built_from(lib, srcs)
+    assert open(lib + ".srchash").read().strip() == _native._src_hash(srcs)
+
+    # The sources change: the .so no longer matches, however new it is.
+    src.write_text('extern "C" int f() { return 2; }\n')
+    os.utime(lib)  # newest file in the tree — the old mtime test passes it
+    assert not _native._built_from(lib, srcs)
+    assert _native._build(lib, srcs, [str(src)])
+    assert _native._built_from(lib, srcs)
+
+    # A .so of unknown provenance (no stamp) is not preferred either.
+    os.unlink(lib + ".srchash")
+    assert not _native._built_from(lib, srcs)
+
+    # No sources at all (an installed wheel): the .so is all there is.
+    assert _native._built_from(lib, (str(tmp_path / "absent.cc"),))
+    # ... and nothing to build from.
+    assert not _native._build(
+        lib, (str(tmp_path / "absent.cc"),), [str(src)]
+    )
+
+
 def test_stack_leaves_matches_pytree():
     import torch.utils._pytree as pytree
 

@@ -672,3 +672,81 @@ class TestCompileCacheErrorCounter:
         finally:
             cc._done = True  # leave the module in its settled state
         assert c.value - before == 1
+
+
+class TestCompileCacheDirectory:
+    """Where the two on-disk tiers live: placed from outside when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, else one fixed path in the
+    checkout — never the home directory."""
+
+    @pytest.fixture
+    def on_accelerator(self, monkeypatch):
+        """Fake the accelerator path and record (not apply) every
+        ``jax.config.update``, so no test leaves a cache directory
+        configured for the CPU suite."""
+        jax = pytest.importorskip("jax")
+        from torchdistx_tpu.utils import compilation_cache as cc
+
+        updates = []
+        monkeypatch.setattr(cc, "_done", False)
+        monkeypatch.delenv("TDX_NO_COMPILATION_CACHE", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.append((k, v))
+        )
+        made = []
+        monkeypatch.setattr(
+            cc.os, "makedirs", lambda d, **k: made.append(d)
+        )
+        yield cc, updates, made
+        cc._done = True  # settled state, as the suite found it
+
+    def test_unset_uses_the_fixed_in_checkout_path(self, on_accelerator):
+        cc, updates, made = on_accelerator
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        cc.ensure_compilation_cache()
+        assert updates == [
+            ("jax_compilation_cache_dir", cc.DEFAULT_CACHE_DIR)
+        ]
+        assert made == [cc.DEFAULT_CACHE_DIR]
+        assert cc.cache_dir() == cc.DEFAULT_CACHE_DIR
+
+    def test_placed_from_outside_configures_nothing(
+        self, on_accelerator, monkeypatch, tmp_path
+    ):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX holds the directory
+        (it reads the variable itself at import): the code sets none, and
+        the executable tier follows to the same place."""
+        import stat
+
+        import jax
+
+        import torchdistx_tpu.materialize as M
+
+        cc, updates, made = on_accelerator
+        placed = str(tmp_path / "placed")
+        monkeypatch.setattr(
+            type(jax.config), "jax_compilation_cache_dir",
+            property(lambda self: placed), raising=False,
+        )
+        cc.ensure_compilation_cache()
+        assert updates == [] and made == []
+        assert cc.cache_dir() == placed
+        monkeypatch.undo()  # real makedirs back for the tier below
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            type(jax.config), "jax_compilation_cache_dir",
+            property(lambda self: placed), raising=False,
+        )
+        d = M._exec_disk_dir()
+        assert d == os.path.join(placed, "tdx_exec")
+        assert stat.S_IMODE(os.stat(d).st_mode) == 0o700
+
+        # A refused tier (group-writable: never unpickle from it) is
+        # counted, not silent.
+        c = telemetry.counter("compile_cache.errors")
+        before = c.value
+        os.chmod(d, 0o770)
+        assert M._exec_disk_dir() is None
+        assert c.value - before == 1

@@ -234,10 +234,7 @@ def test_state_dict_missing_key():
 def test_grad_sync_hook():
     # slowmo_comm parity: pmean over an explicit intra axis in shard_map.
     from jax.sharding import PartitionSpec as P
-    try:  # jax >= 0.7 promoted the export; 0.4.x has only the module
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(MeshSpec(dp=2, tp=4))
     g = jnp.arange(8.0).reshape(2, 4)

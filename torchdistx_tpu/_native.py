@@ -5,62 +5,102 @@ The reference binds its C++ runtime through a pybind11 extension
 environment, so the native core exposes a C ABI (src/cc/tdx_core/graph.h)
 bound here with ctypes — same layering, different binding tech.
 
-Loading is lazy and failure-tolerant: if the library isn't built (or g++ is
-unavailable for the on-demand build), the tape falls back to the pure-Python
-graph with identical semantics.  ``TDX_DISABLE_NATIVE=1`` forces the
-fallback (used by tests to compare both paths).
+Loading is lazy.  In a checkout the libraries are built from ``src/cc`` at
+first use and rebuilt whenever the sources' content hash differs from the
+one stored beside the ``.so`` at build time — never by mtime, which a copy
+or a fresh checkout resets, so a binary of unknown provenance is not
+preferred over the sources.  If the build fails (no ``g++``) the tape falls
+back to the pure-Python graph with identical semantics, with a warning.
+An installed wheel ships the ``.so`` without sources and loads it as is.
+``TDX_DISABLE_NATIVE=1`` forces the fallback (used by tests to compare
+both paths).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO_ROOT = os.path.dirname(_PKG_DIR)
+_SRC_DIR = os.path.join(_REPO_ROOT, "src", "cc", "tdx_core")
 _LIB_PATH = os.path.join(_PKG_DIR, "lib", "libtdx_core.so")
-_SRC = os.path.join(_REPO_ROOT, "src", "cc", "tdx_core", "graph.cc")
+_SRC = os.path.join(_SRC_DIR, "graph.cc")
+_HDR = os.path.join(_SRC_DIR, "graph.h")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _stale() -> bool:
+def _src_hash(srcs: Sequence[str]) -> Optional[str]:
+    """sha256 of the sources' bytes, concatenated in order (what
+    ``cat srcs | sha256sum`` prints — the build scripts write the same
+    stamp); None when they are not there (an installed wheel)."""
+    h = hashlib.sha256()
     try:
-        return os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH)
+        for path in srcs:
+            with open(path, "rb") as f:
+                h.update(f.read())
     except OSError:
+        return None
+    return h.hexdigest()
+
+
+def _built_from(lib: str, srcs: Sequence[str]) -> bool:
+    """Whether ``lib`` exists and was built from ``srcs`` as they are now:
+    the hash stamped beside it (``<lib>.srchash``) equals theirs.  With no
+    sources to compare against, an existing ``lib`` is all there is."""
+    if not os.path.exists(lib):
+        return False
+    want = _src_hash(srcs)
+    if want is None:
         return True
+    try:
+        with open(lib + ".srchash") as f:
+            return f.read().strip() == want
+    except OSError:
+        return False
 
 
-def _try_build() -> bool:
-    """One-shot on-demand build (g++, single TU) so the native path is live
-    in dev checkouts without a separate build step.
+def _build(lib: str, srcs: Sequence[str], args: Sequence[str]) -> bool:
+    """One-shot on-demand build (g++) so the native path is live in dev
+    checkouts without a separate build step.
 
     Compiles to a process-unique temp file and ``os.replace``s it into
     place: concurrent processes (parallel pytest, pytest + bench) must never
     dlopen a half-written .so or truncate one another process has mapped.
+    The source hash is stamped after the library is in place, so a reader
+    between the two steps rebuilds rather than trusts.
     """
-    if not os.path.exists(_SRC):
+    want = _src_hash(srcs)
+    if want is None:
         return False
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib}.{os.getpid()}.tmp"
     try:
-        os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+        os.makedirs(os.path.dirname(lib), exist_ok=True)
         subprocess.run(
-            [
-                "g++", "-std=c++17", "-O2", "-fPIC", "-shared",
-                "-o", tmp, _SRC,
-            ],
+            ["g++", "-std=c++17", "-O2", "-fPIC", "-shared", *args,
+             "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib)
+        with open(tmp, "w") as f:
+            f.write(want + "\n")
+        os.replace(tmp, lib + ".srchash")
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        logging.getLogger(__name__).warning(
+            "native core: building %s failed (%s); falling back to the "
+            "pure-Python path", os.path.basename(lib), e,
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -78,13 +118,11 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get("TDX_DISABLE_NATIVE"):
             _load_failed = True
             return None
-        if (not os.path.exists(_LIB_PATH) or _stale()) and not _try_build():
+        if not _built_from(_LIB_PATH, (_SRC, _HDR)) and not _build(
+            _LIB_PATH, (_SRC, _HDR), [_SRC]
+        ):
             _load_failed = True
-            if not os.path.exists(_LIB_PATH):
-                return None
-            # Stale but rebuild failed: fall through and use the existing
-            # library rather than silently losing the native path entirely.
-            _load_failed = False
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:
@@ -121,54 +159,24 @@ def native_available() -> bool:
 # Native stack utilities (_tdx_stack extension module — the stack_utils.cc
 # analog; see src/cc/tdx_core/stack.cc)
 
-_STACK_SRC = os.path.join(_REPO_ROOT, "src", "cc", "tdx_core", "stack.cc")
+_STACK_SRC = os.path.join(_SRC_DIR, "stack.cc")
 _STACK_LIB = os.path.join(_PKG_DIR, "lib", "_tdx_stack.so")
+# The extension links the graph engine (graph.cc) in.
+_STACK_SRCS = (_STACK_SRC, _SRC, _HDR)
 
 _stack_lock = threading.Lock()
 _stack_mod = None
 _stack_failed = False
 
 
-def _try_build_stack() -> bool:
+def _build_stack() -> bool:
     import sysconfig
 
-    if not os.path.exists(_STACK_SRC):
-        return False
     include = sysconfig.get_paths()["include"]
-    tmp = f"{_STACK_LIB}.{os.getpid()}.tmp"
-    try:
-        os.makedirs(os.path.dirname(_STACK_LIB), exist_ok=True)
-        subprocess.run(
-            [
-                "g++", "-std=c++17", "-O2", "-fPIC", "-shared",
-                f"-I{include}", f"-I{os.path.dirname(_SRC)}",
-                "-o", tmp, _STACK_SRC, _SRC,
-            ],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        os.replace(tmp, _STACK_LIB)
-        return True
-    except Exception:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return False
-
-
-def _stale_stack() -> bool:
-    # The extension links the graph engine (graph.cc) in — either source
-    # being newer triggers a rebuild.
-    try:
-        lib_mtime = os.path.getmtime(_STACK_LIB)
-        return (
-            os.path.getmtime(_STACK_SRC) > lib_mtime
-            or os.path.getmtime(_SRC) > lib_mtime
-        )
-    except OSError:
-        return True
+    return _build(
+        _STACK_LIB, _STACK_SRCS,
+        [f"-I{include}", f"-I{_SRC_DIR}", _STACK_SRC, _SRC],
+    )
 
 
 def stack_ops():
@@ -186,11 +194,9 @@ def stack_ops():
         if os.environ.get("TDX_DISABLE_NATIVE"):
             _stack_failed = True
             return None
-        if (not os.path.exists(_STACK_LIB) or _stale_stack()) \
-                and not _try_build_stack():
-            if not os.path.exists(_STACK_LIB):
-                _stack_failed = True
-                return None
+        if not _built_from(_STACK_LIB, _STACK_SRCS) and not _build_stack():
+            _stack_failed = True
+            return None
         try:
             import importlib.util
 

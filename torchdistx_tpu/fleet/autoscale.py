@@ -4,8 +4,8 @@ PRs 9–13 made the serving stack self-diagnosing — per-tenant SLO
 burn-rate (:class:`~torchdistx_tpu.telemetry.ops.SLOMonitor`), per-engine
 occupancy/goodput/TTFT attribution, stall/recompile-storm/divergence
 latches — and the fleet layer made capacity elastic (deferred-init
-shard-then-materialize spins a warm standby up in ~0.13 s for gpt2-xl,
-BENCH_r05).  This module connects them: :class:`Autoscaler` is a control
+shard-then-materialize spins a warm standby up without a second copy of
+the weights on the host).  This module connects them: :class:`Autoscaler` is a control
 loop that *consumes* those signals and *drives* the existing actuators —
 scale-out via an engine factory (typically
 :func:`~torchdistx_tpu.fleet.hot_swap.materialize_standby` under the

@@ -23,6 +23,8 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+from .. import telemetry as _telemetry
+
 __all__ = [
     "attention",
     "cached_attention",
@@ -189,12 +191,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions):
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
+    # A backend that fails to initialize raises here (and is not cached):
+    # "no TPU" must never be inferred from an error, or impl="auto" would
+    # train on the jnp path and still report a loss.
     import jax
 
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def attention(
@@ -224,6 +226,9 @@ def attention(
     under ``pp_axis``.
     """
     impl = _select_impl(impl, mesh, seq_axis, q.shape, k.shape)
+    # The resolved choice, counted per trace: a quiet downgrade of "auto"
+    # (jnp where the kernel was expected) shows in the counters.
+    _telemetry.counter("attention.dispatch", impl=impl).add()
     if impl in ("ring", "ring_zigzag"):
         from ..parallel.ring_attention import ring_attention
 

@@ -46,6 +46,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry as _telemetry
+
 __all__ = ["flash_attention", "flash_attention_sharded", "shardable"]
 
 # Finite "minus infinity": keeps the online-softmax recurrences NaN-free for
@@ -88,21 +90,15 @@ _BWD_BLOCK_KV_DEFAULT = 1024
 _FUSED_BWD_MAX_KV = 2048
 # Known-good f32 working-set budget for one (bq, bkv) p/ds pair in the
 # fused backward (1024² — the S=1024 training case); bq 1024 × bkv 2048
-# overflows VMEM server-side.  The fused path halves bq down to 128 to
-# stay under this, and falls back to the streamed two-kernel backward
-# when even bq=128 cannot fit (bkv = s_pad > 8192).
+# runs out of VMEM (Mosaic, libtpu 0.0.34, v5e: RESOURCE_EXHAUSTED at
+# compile time, as does 16 MB at S=4096).  The fused path halves bq down
+# to 128 to stay under this, and falls back to the streamed two-kernel
+# backward when even bq=128 cannot fit (bkv = s_pad > 8192).
 _FUSED_BWD_VMEM_CAP = 1024 * 1024 * 4
 _FWD_BLOCK_Q = None
 _FWD_BLOCK_KV = None
 _FWD_BLOCK_Q_DEFAULT = 1024
 _FWD_BLOCK_KV_DEFAULT = 1024
-
-
-def _compiler_params(pltpu, **kw):
-    """``pltpu.CompilerParams``, falling back to the pre-rename
-    ``TPUCompilerParams`` (jax < 0.6) — same kwargs either way."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def _pick_block(s_pad: int, override, default) -> int:
@@ -306,8 +302,7 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -633,8 +628,7 @@ def _fa_backward_fused_nk1(q, k, v, out, lse, do, s, *, causal, interpret):
             pltpu.VMEM((bkv, d), jnp.float32),
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -703,8 +697,7 @@ def _fa_backward_streamed(
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -747,8 +740,7 @@ def _fa_backward_streamed(
             pltpu.VMEM((bkv, d), jnp.float32),
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -795,6 +787,11 @@ def flash_attention(
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    # Counted per trace: a kernel that ran through the interpreter (and
+    # not through Mosaic) must be visible to whoever reads the counters.
+    _telemetry.counter(
+        "attention.flash", interpret=str(bool(interpret)).lower()
+    ).add()
     b, s, hq, d = q.shape
     s_pad = _pad_len(s)
     # Kernel layout is (B, H, S, D).
@@ -822,21 +819,6 @@ def flash_attention(
 # while dp/fsdp shard batch and tp shards heads exactly as the Megatron
 # projections already laid them out (contiguous head chunks align q-head
 # groups with their kv heads under GQA).
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
 
 
 def _mesh_split(mesh, batch_axes, head_axis):
@@ -902,4 +884,7 @@ def flash_attention_sharded(
     def local(ql, kl, vl):
         return flash_attention(ql, kl, vl, causal=causal, interpret=interpret)
 
-    return _shard_map(local, mesh, (spec, spec, spec), spec)(q, k, v)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

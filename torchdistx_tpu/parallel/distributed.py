@@ -80,14 +80,8 @@ def initialize(
     global _initialized
     import jax
 
-    already = _initialized
-    if not already:
-        # Adopt a runtime initialized by an outer launcher/framework.
-        is_init = getattr(jax.distributed, "is_initialized", None)
-        if is_init is not None:
-            already = bool(is_init())
-
-    if not already:
+    # Adopt a runtime initialized by an outer launcher/framework.
+    if not (_initialized or jax.distributed.is_initialized()):
         kwargs = {}
         if coordinator_address is not None:
             kwargs["coordinator_address"] = coordinator_address
@@ -97,15 +91,7 @@ def initialize(
             kwargs["process_id"] = process_id
         if local_device_ids is not None:
             kwargs["local_device_ids"] = list(local_device_ids)
-        try:
-            jax.distributed.initialize(**kwargs)
-        except RuntimeError as e:
-            # Double-init fallback for jax versions without
-            # is_initialized(); the message is "distributed.initialize
-            # should only be called once.".
-            msg = str(e).lower()
-            if "already" not in msg and "once" not in msg:
-                raise
+        jax.distributed.initialize(**kwargs)
     _initialized = True
     return world_info()
 

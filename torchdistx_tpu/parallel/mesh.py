@@ -72,9 +72,9 @@ def make_mesh(
     (the reference's intra-node/inter-node split, slowmo_comm.py:24-27,
     mapped onto the TPU interconnect hierarchy).
 
-    Uses ``mesh_utils.create_device_mesh`` for ICI-topology-aware device
-    ordering when the devices form a single slice; falls back to a reshape
-    for virtual/CPU devices.
+    Uses ``mesh_utils.create_device_mesh``: ICI-topology-aware ordering on
+    TPU devices (an error there is raised, not papered over with an
+    arbitrary order), a plain reshape on virtual/CPU devices.
     """
     import jax
     import numpy as np
@@ -93,14 +93,11 @@ def make_mesh(
             f"Mesh of shape {dict(zip(names, sizes))} needs {n} devices, "
             f"got {len(devices)}."
         )
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(
-            tuple(sizes), devices=list(devices)
-        )
-    except Exception:
-        dev_array = np.asarray(list(devices)).reshape(tuple(sizes))
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
+
+    dev_array = mesh_utils.create_device_mesh(
+        tuple(sizes), devices=list(devices)
+    )
 
     return Mesh(dev_array, tuple(names))

@@ -48,22 +48,6 @@ from jax.sharding import PartitionSpec as P
 __all__ = ["pipeline_forward", "pipeline_value_and_grad", "stage_specs"]
 
 
-def _shard_map(fn, mesh, in_specs, out_specs, manual_axes):
-    try:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=frozenset(manual_axes), check_vma=False,
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            auto=frozenset(mesh.axis_names) - frozenset(manual_axes),
-            check_rep=False,
-        )
-
-
 def stage_specs(layer_specs, *, pp: str = "pp"):
     """Prefix every stacked-layer spec with the ``pp`` axis on the layer dim
     (composes with tp/fsdp on the trailing dims)."""
@@ -188,9 +172,9 @@ def pipeline_forward(
             lambda out, l: out.reshape(l.shape), outputs, x_local
         )
 
-    return _shard_map(
-        body, mesh, in_specs=(x_spec, param_specs_local), out_specs=x_spec,
-        manual_axes={axis},
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(x_spec, param_specs_local),
+        out_specs=x_spec, axis_names=frozenset({axis}), check_vma=False,
     )(x, layer_params)
 
 
@@ -552,9 +536,9 @@ def pipeline_value_and_grad(
     lp_spec = jax.tree.map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), layer_params
     )
-    loss, g_ep, g_lp, g_hp, g_sp = _shard_map(
+    loss, g_ep, g_lp, g_hp, g_sp = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(
             rep(embed_params),
             lp_spec,
@@ -570,7 +554,8 @@ def pipeline_value_and_grad(
             rep(head_params),
             rep(sp_in),
         ),
-        manual_axes={axis},
+        axis_names=frozenset({axis}),
+        check_vma=False,
     )(embed_params, layer_params, head_params, sp_in, tokens, targets)
     if has_shared:
         return loss, (g_ep, g_lp, g_hp, g_sp)

@@ -44,32 +44,6 @@ __all__ = ["ring_attention"]
 _NEG_INF = float("-inf")
 
 
-def _axis_size(axis: str) -> int:
-    """Static size of the mapped axis — ``jax.lax.axis_size`` on current
-    jax; jax < 0.6 exposes it only as the axis-env frame."""
-    ax = getattr(jax.lax, "axis_size", None)
-    if ax is not None:
-        return ax(axis)
-    from jax._src.core import axis_frame
-
-    return axis_frame(axis)
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
 def _block_contrib(q, k, v, q_off, k_off, causal):
     """One K/V block's unnormalized contribution (GQA-aware).
 
@@ -117,7 +91,7 @@ def _merge(acc, blk):
 
 def _ring_body(q, k, v, *, axis: str, causal: bool):
     """Per-device body under shard_map: local blocks in, local out."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     b, sl, hq, d = q.shape
     q_off = idx * sl
@@ -176,7 +150,7 @@ def _zigzag_ring_body(q, k, v, *, axis: str):
     on every device — the causal load balance the contiguous assignment
     lacks.
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     b, sl, hq, d = q.shape
     h = sl // 2
@@ -314,8 +288,9 @@ def ring_attention(
                 f"zigzag needs seq {s} divisible by 2·{axis}={2 * n}"
             )
         body = functools.partial(_zigzag_ring_body, axis=axis)
-        zz = _shard_map(
-            body, mesh, in_specs=(spec, spec, spec), out_specs=spec
+        zz = jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )
         if pre_permuted:
             return zz(q, k, v)
@@ -327,6 +302,7 @@ def ring_attention(
     if pre_permuted:
         raise ValueError("pre_permuted requires schedule='zigzag'")
     body = functools.partial(_ring_body, axis=axis, causal=causal)
-    return _shard_map(
-        body, mesh, in_specs=(spec, spec, spec), out_specs=spec
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )(q, k, v)

@@ -98,12 +98,9 @@ _OOM_MARKERS = (
 
 _tls = threading.local()
 
-# The jax.monitoring duration-event names that mean "XLA compiled a
-# program" across the jax versions this stack supports.
-_COMPILE_EVENTS = (
-    "/jax/core/compile/backend_compile_duration",
-    "/jax/core/compile/backend_compile_duration_sec",
-)
+# The jax.monitoring duration event that means "XLA compiled a program"
+# (a persistent-cache load fires it too: it wraps compile_or_get_cached).
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _install_lock = threading.Lock()
 _monitoring = False  # listener registered successfully
@@ -176,7 +173,7 @@ def _on_duration_event(name: str, duration_s: float, **kwargs) -> None:
     scope (``other`` when none).  Never raises — telemetry must not
     fail the compile it observes."""
     try:
-        if name not in _COMPILE_EVENTS:
+        if name != _COMPILE_EVENT:
             return
         scope = _current_scope()
         if scope is not None:

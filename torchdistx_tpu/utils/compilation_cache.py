@@ -9,11 +9,16 @@ can hit on re-runs.  A training job that restarts (preemption, resharding,
 hyperparameter sweeps) re-materializes the same architecture and pays only
 trace + cache-lookup time.
 
-Enabled on first materialization unless the user configured a cache dir
-themselves (their setting wins) or disabled it via
-``TDX_NO_COMPILATION_CACHE=1``.  The default location honors
-``JAX_COMPILATION_CACHE_DIR`` and falls back to
-``~/.cache/torchdistx_tpu/xla_cache``.
+Enabled on first materialization unless disabled via
+``TDX_NO_COMPILATION_CACHE=1``.  Where the cache lives: with
+``JAX_COMPILATION_CACHE_DIR`` set (or ``jax_compilation_cache_dir``
+configured by the user) JAX has the directory already and this module sets
+none; otherwise :data:`DEFAULT_CACHE_DIR`, one fixed directory inside the
+checkout.  The path is part of the cache key, so it never depends on the
+home directory, a temp name, a pid or the time — a copied checkout on a
+throw-away machine finds what an earlier process of the same command
+wrote.  The AOT-executable tier (``materialize._exec_disk_dir``) lives in
+a sub-directory of the same place (:func:`cache_dir`).
 """
 
 from __future__ import annotations
@@ -35,6 +40,14 @@ _T_ENABLED = _telemetry.gauge("compilation_cache.enabled")
 # but silent degradation (every compile suddenly cold) must still be
 # visible in traces, so every swallowed exception counts here.
 _T_ERRORS = _telemetry.counter("compile_cache.errors")
+
+# <checkout>/.jax_cache (git-ignored).
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 _lock = threading.Lock()
 _done = False
@@ -65,24 +78,33 @@ def ensure_compilation_cache() -> None:
             import jax
 
             if jax.config.jax_compilation_cache_dir:
+                # JAX_COMPILATION_CACHE_DIR (jax reads it itself) or a
+                # programmatic setting: the directory is placed from
+                # outside and this module configures none.
                 _T_ENABLED.set(1)
-                return  # user configured their own — leave it alone
+                return
             if jax.default_backend() == "cpu":
                 # CPU executables are AOT-compiled against the build host's
                 # exact machine features; reloading them elsewhere warns (or
                 # SIGILLs).  The cache's value is on accelerators, where
                 # executables are device-kind-portable.
                 return
-            cache_dir = os.environ.get(
-                "JAX_COMPILATION_CACHE_DIR"
-            ) or os.path.expanduser("~/.cache/torchdistx_tpu/xla_cache")
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
             _T_ENABLED.set(1)
         except Exception:
             # Cache is a pure optimization — never fail materialization
-            # over it (read-only HOME, old jax flag names, ...).
+            # over it (read-only checkout, ...).
             _T_ERRORS.add()
+
+
+def cache_dir() -> str:
+    """The directory both cache tiers use: whatever JAX is configured
+    with — ``JAX_COMPILATION_CACHE_DIR`` when set — else
+    :data:`DEFAULT_CACHE_DIR`."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or DEFAULT_CACHE_DIR
 
 
 class cache_everything:
