@@ -193,8 +193,11 @@ def _layernorm(x, scale, bias, eps):
 def _embed(params, tokens, cfg: GPT2Config):
     """wte[tokens] + wpe[:S] — ``params`` needs only ``wte``/``wpe``."""
     s = tokens.shape[1]
-    x = jnp.take(params["wte"]["weight"], tokens, axis=0).astype(cfg.dtype)
-    return x + params["wpe"]["weight"][:s].astype(cfg.dtype)[None]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"]["weight"], tokens, axis=0).astype(
+            cfg.dtype
+        )
+        return x + params["wpe"]["weight"][:s].astype(cfg.dtype)[None]
 
 
 def _head(params, x, cfg: GPT2Config):
@@ -227,7 +230,8 @@ def _head_ce(params, x, targets, cfg: GPT2Config):
     """Loss-path :func:`_head` + CE with ``cfg.dtype`` logits (see
     llama._head_ce; bitwise-identical to ``_ce(_head_logits(...))`` at
     float32)."""
-    return _ce(_head(params, x, cfg), targets)
+    with jax.named_scope("head"):
+        return _ce(_head(params, x, cfg), targets)
 
 
 def _build_block(
@@ -236,29 +240,40 @@ def _build_block(
     """One transformer block as ``block(x, lp) -> x`` over unstacked layer
     params — shared by :func:`forward` and the 1F1B pipeline pieces."""
 
+    # attn/mlp named_scope regions, the names forward_paged uses: HLO
+    # metadata only, so a profile's device time can be read by scope
+    # (docs/observability.md, "Scopes inside the train step").
     def block(x, lp):
         bb, s = x.shape[0], x.shape[1]
-        h = _layernorm(x, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.norm_eps)
-        qkv = h @ lp["attn_qkv"]["weight"] + lp["attn_qkv"]["bias"].astype(
-            cfg.dtype
-        )
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(bb, s, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(bb, s, cfg.n_heads, cfg.head_dim)
-        v = v.reshape(bb, s, cfg.n_heads, cfg.head_dim)
-        attn = attention(
-            q, k, v, causal=True, impl=attn_impl, mesh=mesh, seq_axis=seq_axis
-        ).reshape(bb, s, -1)
-        x = x + attn @ lp["attn_proj"]["weight"] + lp["attn_proj"][
-            "bias"
-        ].astype(cfg.dtype)
-        h = _layernorm(x, lp["ln_2"]["scale"], lp["ln_2"]["bias"], cfg.norm_eps)
-        h = jax.nn.gelu(
-            h @ lp["mlp_fc"]["weight"] + lp["mlp_fc"]["bias"].astype(cfg.dtype)
-        )
-        x = x + h @ lp["mlp_proj"]["weight"] + lp["mlp_proj"]["bias"].astype(
-            cfg.dtype
-        )
+        with jax.named_scope("attn"):
+            h = _layernorm(
+                x, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.norm_eps
+            )
+            qkv = h @ lp["attn_qkv"]["weight"] + lp["attn_qkv"][
+                "bias"
+            ].astype(cfg.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+            v = v.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+            attn = attention(
+                q, k, v, causal=True, impl=attn_impl, mesh=mesh,
+                seq_axis=seq_axis,
+            ).reshape(bb, s, -1)
+            x = x + attn @ lp["attn_proj"]["weight"] + lp["attn_proj"][
+                "bias"
+            ].astype(cfg.dtype)
+        with jax.named_scope("mlp"):
+            h = _layernorm(
+                x, lp["ln_2"]["scale"], lp["ln_2"]["bias"], cfg.norm_eps
+            )
+            h = jax.nn.gelu(
+                h @ lp["mlp_fc"]["weight"]
+                + lp["mlp_fc"]["bias"].astype(cfg.dtype)
+            )
+            x = x + h @ lp["mlp_proj"]["weight"] + lp["mlp_proj"][
+                "bias"
+            ].astype(cfg.dtype)
         return x
 
     return block

@@ -275,7 +275,16 @@ def _rmsnorm(x, weight, eps):
 
 
 # Shared by the unpipelined forward/loss and the 1F1B pieces — one
-# definition of the head and the loss, so the paths cannot drift.
+# definition of the embedding, the head and the loss, so the paths cannot
+# drift.
+
+
+def _embed(params, tokens, cfg: LlamaConfig):
+    """embed[tokens] in ``cfg.dtype`` — ``params`` needs only ``embed``."""
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"]["weight"], tokens, axis=0).astype(
+            cfg.dtype
+        )
 
 
 def _head(params, x, cfg: LlamaConfig):
@@ -311,7 +320,8 @@ def _head_ce(params, x, targets, cfg: LlamaConfig):
     (B, S, V) float32 logits that :func:`forward`'s public contract
     returns — at bf16 that halves the loss path's HBM traffic).
     Bitwise-identical to ``_ce(_head_logits(...))`` at float32."""
-    return _ce(_head(params, x, cfg), targets)
+    with jax.named_scope("head"):
+        return _ce(_head(params, x, cfg), targets)
 
 
 def _rope_tables(positions, theta, half, dtype):
@@ -354,25 +364,30 @@ def _build_block(
     params — shared by :func:`forward` and the 1F1B pipeline pieces.
     ``positions=None`` derives contiguous positions from the input shape."""
 
+    # attn/mlp named_scope regions, the names forward_paged uses: HLO
+    # metadata only, so a profile's device time can be read by scope
+    # (docs/observability.md, "Scopes inside the train step").
     def block(x, lp):
         bb, s = x.shape[0], x.shape[1]
-        pos = (
-            jnp.arange(s)[None] if positions is None else positions
-        )
-        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(bb, s, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
-        q = _rope(q, pos, cfg.rope_theta)
-        k = _rope(k, pos, cfg.rope_theta)
-        attn = attention(
-            q, k, v, causal=True, impl=attn_impl, mesh=mesh,
-            seq_axis=seq_axis, pre_permuted=pre_permuted,
-        )
-        x = x + attn.reshape(bb, s, -1) @ lp["wo"]
-        h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        gated = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
-        x = x + gated @ lp["w_down"]
+        with jax.named_scope("attn"):
+            pos = (
+                jnp.arange(s)[None] if positions is None else positions
+            )
+            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            q = (h @ lp["wq"]).reshape(bb, s, cfg.n_heads, cfg.head_dim)
+            k = (h @ lp["wk"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
+            q = _rope(q, pos, cfg.rope_theta)
+            k = _rope(k, pos, cfg.rope_theta)
+            attn = attention(
+                q, k, v, causal=True, impl=attn_impl, mesh=mesh,
+                seq_axis=seq_axis, pre_permuted=pre_permuted,
+            )
+            x = x + attn.reshape(bb, s, -1) @ lp["wo"]
+        with jax.named_scope("mlp"):
+            h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+            gated = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
+            x = x + gated @ lp["w_down"]
         return x
 
     return block
@@ -465,7 +480,7 @@ def _forward_hidden(
         from ..ops.attention import resolve_stage_attn_impl
 
         attn_impl = resolve_stage_attn_impl(attn_impl)
-    x = jnp.take(params["embed"]["weight"], tokens, axis=0).astype(cfg.dtype)
+    x = _embed(params, tokens, cfg)
 
     block = _build_block(
         cfg, positions=positions, mesh=mesh, seq_axis=seq_axis,
@@ -725,9 +740,7 @@ def pp_pieces(cfg: LlamaConfig, *, mesh=None, attn_impl: str = "auto"):
     body = jax.checkpoint(block) if cfg.remat else block
 
     def embed_fn(ep, tokens_mb):
-        return jnp.take(
-            ep["embed"]["weight"], tokens_mb, axis=0
-        ).astype(cfg.dtype)
+        return _embed(ep, tokens_mb, cfg)
 
     def head_loss_fn(hp, h, targets_mb):
         return _head_ce(hp, h, targets_mb, cfg)

@@ -306,6 +306,7 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -632,6 +633,7 @@ def _fa_backward_fused_nk1(q, k, v, out, lse, do, s, *, causal, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -701,6 +703,7 @@ def _fa_backward_streamed(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid over kv blocks with the (group, q-block) reduction as the
@@ -744,6 +747,7 @@ def _fa_backward_streamed(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

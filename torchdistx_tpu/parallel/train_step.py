@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import llama
 from ..resilience import guard as _guard
+from ..telemetry import perf as _perf
 from .sharding import fit_shardings
 from .slowmo import SlowMomentumOptimizer, SlowMoState
 
@@ -132,7 +133,11 @@ def make_train_step(
 
     ``step_fn(state, batch) -> (state, metrics)`` — one jitted SPMD training
     step; ``batch`` is ``{"tokens": (B,S), "targets": (B,S)}`` sharded with
-    :func:`batch_sharding`.  State buffers are donated.
+    :func:`batch_sharding`.  State buffers are donated.  What is returned
+    is the jitted function as the compile observatory's tracked program
+    ``train_step`` (:class:`telemetry.perf.JitProgram`: call it, ``.lower``
+    it; ``compile.count{program=train_step}`` counts its compiles, and with
+    a span sink active each compile records its scope map).
 
     ``nonfinite_guard`` (default on) adds a jit-side all-reduced
     finiteness check over loss and gradients: a poisoned step returns
@@ -235,14 +240,18 @@ def make_train_step(
         jax.jit, out_shardings=(state_shardings, None), donate_argnums=(0,)
     )
     def step_fn(state: TrainState, batch):
-        if value_and_grad is not None:
-            loss, grads = value_and_grad(
-                state.params, batch["tokens"], batch["targets"]
-            )
-        else:
-            loss, grads = jax.value_and_grad(_loss)(
-                state.params, batch["tokens"], batch["targets"]
-            )
+        # loss / optimizer / guard: named_scope regions (HLO metadata
+        # only) by which a profile's device time is read; the model
+        # families add embed / attn / mlp / head under loss.
+        with jax.named_scope("loss"):
+            if value_and_grad is not None:
+                loss, grads = value_and_grad(
+                    state.params, batch["tokens"], batch["targets"]
+                )
+            else:
+                loss, grads = jax.value_and_grad(_loss)(
+                    state.params, batch["tokens"], batch["targets"]
+                )
         if "_tdx_nan" in batch:
             # Deterministic fault injection (resilience.faults, kind
             # "nan"): poison the loss so the guard's real detection path
@@ -253,14 +262,18 @@ def make_train_step(
                 jnp.asarray(jnp.nan, dtype=loss.dtype),
                 loss,
             )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
         import optax
 
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(params, opt_state, state.step + 1)
         if nonfinite_guard:
-            ok = _guard.tree_allfinite(loss, grads)
-            new_state = _guard.select_tree(ok, new_state, state)
+            with jax.named_scope("guard"):
+                ok = _guard.tree_allfinite(loss, grads)
+                new_state = _guard.select_tree(ok, new_state, state)
             metrics = {
                 "loss": loss,
                 "step": new_state.step,
@@ -270,7 +283,10 @@ def make_train_step(
             metrics = {"loss": loss, "step": new_state.step}
         return new_state, metrics
 
-    return init_fn, step_fn
+    _perf.install_monitoring()
+    return init_fn, _perf.JitProgram(
+        lambda: step_fn, "train_step", scopes=True
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -363,14 +379,21 @@ def make_slowmo_train_step(
     )
     def step_fn(state: TrainState, batch):
         # Per-replica loss/grads — the vmap axis IS the dp axis.
-        losses, grads = jax.vmap(jax.value_and_grad(_loss))(
-            state.params, batch["tokens"], batch["targets"]
-        )
-        params, opt_state = opt.update(grads, state.opt_state, state.params)
+        with jax.named_scope("loss"):
+            losses, grads = jax.vmap(jax.value_and_grad(_loss))(
+                state.params, batch["tokens"], batch["targets"]
+            )
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(
+                grads, state.opt_state, state.params
+            )
         metrics = {"loss": losses.mean(), "step": state.step + 1}
         return TrainState(params, opt_state, state.step + 1), metrics
 
-    return init_fn, step_fn
+    _perf.install_monitoring()
+    return init_fn, _perf.JitProgram(
+        lambda: step_fn, "train_step_slowmo", scopes=True
+    )
 
 
 def slowmo_batch_sharding(mesh, *, dp_axis="dp", data_axes=("fsdp",)):
