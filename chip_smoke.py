@@ -534,13 +534,16 @@ def phase_serve(sz: Sizes, ctx: dict, clock: _Clock) -> dict:
 def _train_bytes(cfg, rows: int, seq: int, n_dev: int) -> int:
     """Bytes per device one train step needs, from shapes: this device's
     share of params, grads and the two AdamW moments, and for its ``rows``
-    of the batch the per-layer residuals remat keeps plus the logits
+    of the batch the per-layer residuals remat keeps (the block's input,
+    the flash kernel's output and its f32 log-sum-exp) plus the logits
     (stored + f32 softmax + its gradient)."""
     from torchdistx_tpu.models import gpt2
 
     itemsize = 2 if "bfloat16" in str(cfg.dtype) else 4
     state = 4 * gpt2.num_params(cfg) * itemsize // n_dev
-    resid = cfg.n_layers * rows * seq * cfg.dim * itemsize
+    resid = cfg.n_layers * rows * seq * (
+        2 * cfg.dim * itemsize + 4 * cfg.n_heads
+    )
     logits = rows * seq * cfg.vocab_size * (itemsize + 8)
     return state + resid + logits
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
+from ..ops.pallas.flash_attention import REMAT_POLICY
 
 __all__ = [
     "GPT2Config",
@@ -320,7 +321,9 @@ def _forward_hidden(
     block = _build_block(
         cfg, mesh=mesh, seq_axis=seq_axis, attn_impl=attn_impl
     )
-    body = jax.checkpoint(block) if cfg.remat else block
+    body = (
+        jax.checkpoint(block, policy=REMAT_POLICY) if cfg.remat else block
+    )
     if pp_axis is not None:
         from ..parallel.pipeline import pipeline_forward
 
@@ -500,7 +503,9 @@ def pp_pieces(cfg: GPT2Config, *, mesh=None, attn_impl: str = "auto"):
 
     impl = resolve_stage_attn_impl(attn_impl)
     block = _build_block(cfg, mesh=mesh, attn_impl=impl)
-    body = jax.checkpoint(block) if cfg.remat else block
+    body = (
+        jax.checkpoint(block, policy=REMAT_POLICY) if cfg.remat else block
+    )
 
     def embed_fn(ep, tokens_mb):
         return _embed(ep, tokens_mb, cfg)

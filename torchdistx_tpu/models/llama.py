@@ -15,7 +15,12 @@ partitioner, not a port of any torch module:
   ``PartitionSpec`` pytree (Megatron-style TP + ZeRO-style FSDP dims);
   the forward is sharding-agnostic and XLA inserts the collectives.
 * **Selective remat**: ``cfg.remat`` wraps the scanned block in
-  ``jax.checkpoint`` — the standard HBM-for-FLOPs trade on TPU.
+  ``jax.checkpoint`` with ``flash_attention.REMAT_POLICY``.  Saved per
+  layer: the block's input and, where the Pallas flash kernel runs, its
+  output ``flash_out`` (B, S, H*D) and log-sum-exp ``flash_lse`` (B, H, S)
+  — B*S*H*(D*itemsize + 4) bytes.  Recomputed in the backward pass: the
+  norms, the projections (q, k, v for the backward kernels) and the MLP,
+  never the forward kernel.
 
 Capability parity note: the reference's BASELINE configs name Llama-2-7B/70B
 as deferred-init workloads (BASELINE.md configs 4-5); this module provides
@@ -37,6 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
+from ..ops.pallas.flash_attention import REMAT_POLICY
 
 __all__ = [
     "LlamaConfig",
@@ -486,7 +492,9 @@ def _forward_hidden(
         cfg, positions=positions, mesh=mesh, seq_axis=seq_axis,
         attn_impl=attn_impl, pre_permuted=pre_permuted,
     )
-    body = jax.checkpoint(block) if cfg.remat else block
+    body = (
+        jax.checkpoint(block, policy=REMAT_POLICY) if cfg.remat else block
+    )
     if pp_axis is not None:
         from ..parallel.pipeline import pipeline_forward
 
@@ -737,7 +745,9 @@ def pp_pieces(cfg: LlamaConfig, *, mesh=None, attn_impl: str = "auto"):
 
     impl = resolve_stage_attn_impl(attn_impl)
     block = _build_block(cfg, mesh=mesh, attn_impl=impl)
-    body = jax.checkpoint(block) if cfg.remat else block
+    body = (
+        jax.checkpoint(block, policy=REMAT_POLICY) if cfg.remat else block
+    )
 
     def embed_fn(ep, tokens_mb):
         return _embed(ep, tokens_mb, cfg)

@@ -45,10 +45,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ... import telemetry as _telemetry
 
-__all__ = ["flash_attention", "flash_attention_sharded", "shardable"]
+__all__ = [
+    "REMAT_POLICY", "flash_attention", "flash_attention_sharded", "shardable",
+]
 
 # Finite "minus infinity": keeps the online-softmax recurrences NaN-free for
 # rows whose valid keys haven't streamed in yet (exp(-1e30 − m) underflows to
@@ -561,7 +564,7 @@ def _dqkv_fused_kernel(
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _fa_backward_fused_nk1(q, k, v, out, lse, do, s, *, causal, interpret):
+def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     """One-kernel backward for ``s_pad <= bkv`` (single kv block)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
@@ -583,16 +586,11 @@ def _fa_backward_fused_nk1(q, k, v, out, lse, do, s, *, causal, interpret):
         # block (the known-good 1024² working set) fits the cap fine —
         # a 128-row handoff would just run 8× more dq grid iterations.
         return _fa_backward_streamed(
-            q, k, v, out, lse, do, s, causal=causal, interpret=interpret,
+            q, k, v, delta, lse, do, s, causal=causal, interpret=interpret,
             bkv=_block_for(s_pad),
         )
     nq = s_pad // bq
     scale = 1.0 / (d**0.5)
-
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
 
     gq_q_spec = pl.BlockSpec(
         (1, 1, bq, d),
@@ -638,7 +636,7 @@ def _fa_backward_fused_nk1(q, k, v, out, lse, do, s, *, causal, interpret):
     return dq, dk, dv
 
 
-def _fa_backward(q, k, v, out, lse, do, s, *, causal, interpret):
+def _fa_backward(q, k, v, delta, lse, do, s, *, causal, interpret):
     s_pad = q.shape[2]
     # Whole kv extent in one block → fused one-kernel path.  An explicit
     # smaller kv-block override (sweeps/tests) forces the streamed pair.
@@ -647,15 +645,15 @@ def _fa_backward(q, k, v, out, lse, do, s, *, causal, interpret):
         or s_pad == _pick_block(s_pad, _BWD_BLOCK_KV, _BWD_BLOCK_KV_DEFAULT)
     ):
         return _fa_backward_fused_nk1(
-            q, k, v, out, lse, do, s, causal=causal, interpret=interpret
+            q, k, v, delta, lse, do, s, causal=causal, interpret=interpret
         )
     return _fa_backward_streamed(
-        q, k, v, out, lse, do, s, causal=causal, interpret=interpret
+        q, k, v, delta, lse, do, s, causal=causal, interpret=interpret
     )
 
 
 def _fa_backward_streamed(
-    q, k, v, out, lse, do, s, *, causal, interpret, bq=None, bkv=None
+    q, k, v, delta, lse, do, s, *, causal, interpret, bq=None, bkv=None
 ):
     """The streamed two-kernel backward (dq kernel + dk/dv kernel), kv as
     a grid axis.  ``bq``/``bkv`` are normally derived from the sweep
@@ -673,11 +671,6 @@ def _fa_backward_streamed(
         bkv = _pick_block(s_pad, _BWD_BLOCK_KV, _BWD_BLOCK_KV_DEFAULT)
     nq, nk = s_pad // bq, s_pad // bkv
     scale = 1.0 / (d**0.5)
-
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )  # (B, Hq, S_pad, 1)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
     kv_clamp = _diag_clamp(causal, bq, bkv, jnp.minimum)
@@ -753,26 +746,69 @@ def _fa_backward_streamed(
 
 
 # ---------------------------------------------------------------------------
-# Differentiable entry (operates on padded (B, H, S_pad, D) layout)
+# Differentiable entry: q, k, v in the padded kernel layout (B, H, S_pad, D),
+# the result in the model's (B, S_pad, Hq*D)
 
 
+# What a rematerialised block keeps of this kernel: ``jax.checkpoint(block,
+# policy=REMAT_POLICY)`` saves the forward kernel's two results beside the
+# block's input, so the backward pass recomputes q, k and v (the backward
+# kernels read them) and never ``flash_fwd``.  Per layer that is
+# B*S_pad*Hq*(D*itemsize + 4) bytes: the output in the compute dtype and
+# one float32 log-sum-exp per query row.  Where the names never appear
+# (jnp or ring attention, serving) the policy saves nothing.
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    "flash_out", "flash_lse"
+)
+
+
+def _rows(x):
+    """Kernel layout ``(B, H, S, D)`` -> ``(B, S, H*D)``, the model's own
+    layout of the attention output.  It is what leaves the VJP and what a
+    remat policy saves: stacked over the layers of a scan it is lane-dense,
+    where the kernel's 64-wide minor dimension is tiled out to 128."""
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+# The primal: runs only where nothing differentiates the call (inference,
+# ``jax.eval_shape``).  Under ``jax.grad``/``jax.vjp`` JAX traces ``_fa_fwd``
+# in its place, so a name given here would never reach a remat policy.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _fa(q, k, v, s, causal, interpret):
     out, _ = _fa_forward_padded(q, k, v, s, causal=causal, interpret=interpret)
-    return out
+    return _rows(out)
 
 
 def _fa_fwd(q, k, v, s, causal, interpret):
     out, lse = _fa_forward_padded(
         q, k, v, s, causal=causal, interpret=interpret
     )
+    # The named ``out`` is BOTH the result and the residual: were the
+    # residual another array than the one returned, the saved value and
+    # the one the backward pass reads would be two variables, and the
+    # recompute would keep ``flash_fwd`` alive to produce the second.
+    out = checkpoint_name(_rows(out), "flash_out")
+    # Without the kernel's unit minor dimension, which a stacked residual
+    # would carry tiled out to 128 lanes.
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(s, causal, interpret, res, do):
     q, k, v, out, lse = res
+    b, hq, s_pad, d = q.shape
+    do = do.reshape(b, s_pad, hq, d)
+    # delta = rowsum(do * out), per query row and head: taken in the layout
+    # both arrive in, so ``out`` is never carried back to the kernel's.
+    delta = jnp.sum(
+        do.astype(jnp.float32)
+        * out.reshape(b, s_pad, hq, d).astype(jnp.float32),
+        axis=-1,
+    ).transpose(0, 2, 1)[..., None]  # (B, Hq, S_pad, 1)
     return _fa_backward(
-        q, k, v, out, lse, do, s, causal=causal, interpret=interpret
+        q, k, v, delta, lse[..., None], do.transpose(0, 2, 1, 3), s,
+        causal=causal, interpret=interpret,
     )
 
 
@@ -805,10 +841,8 @@ def flash_attention(
     if s_pad != s:
         pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
         qt, kt, vt = (jnp.pad(t, pad) for t in (qt, kt, vt))
-    out = _fa(qt, kt, vt, s, causal, interpret)
-    if s_pad != s:
-        out = out[:, :, :s, :]
-    return out.transpose(0, 2, 1, 3)
+    out = _fa(qt, kt, vt, s, causal, interpret)  # (B, S_pad, Hq*D)
+    return out[:, :s].reshape(b, s, hq, d)
 
 
 # ---------------------------------------------------------------------------
