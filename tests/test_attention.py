@@ -3,6 +3,11 @@
 Test rig per SURVEY.md §4: single host, virtual 8-device CPU mesh.
 """
 
+import base64
+import functools
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -19,6 +24,96 @@ def _qkv(b=2, s=64, hq=4, hkv=2, d=16, dtype=jnp.float32):
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hkv, d), dtype=dtype)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, hkv, d), dtype=dtype)
     return q, k, v
+
+
+def _grads(fn, q, k, v, w):
+    return jax.grad(
+        lambda q, k, v: (fn(q, k, v) * w).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+
+
+class TestFlashTwoWidths:
+    """q and k of one head width, v and the output of another (latent
+    attention: 192 against 128), through all four kernels."""
+
+    @pytest.mark.parametrize("backward", ["fused", "streamed"])
+    @pytest.mark.parametrize("d_qk,d_v", [(192, 128), (48, 32)])
+    def test_forward_and_gradients_match_reference(
+        self, d_qk, d_v, backward, monkeypatch
+    ):
+        from torchdistx_tpu.ops.pallas import flash_attention as fa
+
+        if backward == "streamed":  # several q and kv blocks, three kernels
+            monkeypatch.setattr(fa, "_BWD_BLOCK_Q", 128)
+            monkeypatch.setattr(fa, "_BWD_BLOCK_KV", 128)
+            monkeypatch.setattr(fa, "_FWD_BLOCK_KV", 128)
+        b, s, hq, hkv = 1, 384, 4, 2
+        ks = jax.random.split(jax.random.PRNGKey(d_qk), 4)
+        q = jax.random.normal(ks[0], (b, s, hq, d_qk))
+        k = jax.random.normal(ks[1], (b, s, hkv, d_qk))
+        v = jax.random.normal(ks[2], (b, s, hkv, d_v))
+        w = jax.random.normal(ks[3], (b, s, hq, d_v))
+        flash = functools.partial(flash_attention, causal=True, interpret=True)
+        n_kernels = str(jax.make_jaxpr(
+            lambda *a: _grads(flash, *a, w)
+        )(q, k, v)).count("pallas_call")
+        assert n_kernels == (2 if backward == "fused" else 3)
+        out = flash(q, k, v)
+        ref = mha_reference(q, k, v, causal=True)
+        assert out.shape == ref.shape == (b, s, hq, d_v)
+        assert jnp.allclose(out, ref, atol=2e-5)
+        for name, g, r in zip(
+            "qkv", _grads(flash, q, k, v, w),
+            _grads(functools.partial(mha_reference, causal=True), q, k, v, w),
+        ):
+            assert g.shape == r.shape, name
+            assert jnp.allclose(g, r, atol=1e-4), name
+
+    @pytest.mark.parametrize(
+        "seq,heads,d,pinned",
+        [
+            (1024, 4, 64, "b877060f52d6c20b4175a1ea56c04f39da24e15ed5bbb0ecb8edde73772688d4"),
+            (4096, 2, 64, "d3af703b63c17d89f0e2bc8b66e4c3c6836a40b0734f3bc5d3631708b89d15ce"),
+            (4096, 2, 128, "d3440a3b7169f59a843fd57b615c2d80fcba4ff9b5ed415a2a14bb84bc0f5e3e"),
+        ],
+    )
+    def test_equal_widths_lower_to_the_program_of_pr_26(
+        self, seq, heads, d, pinned, monkeypatch
+    ):
+        """With ``d_qk == d_v`` the kernels are the ones that took a single
+        width: the gradient's StableHLO lowered for the TPU (fused backward
+        at 1024, the streamed pair at 4096), each Mosaic body printed
+        without its source locations, hashes to what commit bb7fb95 (PR 26)
+        gives.  A PR that changes the kernels on purpose re-pins."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        x = jax.ShapeDtypeStruct((1, seq, heads, d), jnp.bfloat16)
+        grad = jax.grad(
+            lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )
+        text = jax.jit(grad).trace(x, x, x).lower(
+            lowering_platforms=("tpu",)
+        ).as_text()
+        assert hashlib.sha256(_without_locations(text).encode()).hexdigest() == pinned
+
+
+def _without_locations(stablehlo: str) -> str:
+    """Each Mosaic kernel body (MLIR bytecode in base64, which carries file
+    names and line numbers) replaced by the hash of its text without them."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return '"body": "' + hashlib.sha256(asm.encode()).hexdigest() + '"'
+
+    out, n = re.subn(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, stablehlo)
+    assert n, "no Mosaic kernel in the lowered text"
+    return out
 
 
 class TestFlashAttention:

@@ -1,0 +1,320 @@
+"""DeepSeek-V3 family (latent attention, routed experts told which they
+hold): the benchmark's plain reference against ``transformers``, the family
+against the reference, the share against the whole, dropless routing, and
+the paper's path with the absent experts dropped before materialization.
+
+CPU, float32, seeded: values and counts only.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torchdistx_tpu import telemetry
+from torchdistx_tpu.models import convert, deepseek_v3 as ds
+from torchdistx_tpu.ops.routed_experts import routed_experts
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from families import deepseek_v3 as family  # noqa: E402
+from reference import common, deepseek_v3 as ref  # noqa: E402
+
+CONFIG = "kanana-2-30b-a3b-instruct-2601"
+
+
+def _sizes(**over):
+    """The configuration file's ``tiny`` block over its published keys."""
+    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
+        c = json.load(f)
+    c.update(c.pop("tiny"))
+    c.update(over)
+    return c
+
+
+def _ref_loss(params, tokens, targets, sizes):
+    with common.precision(jnp.float32):
+        x = ref.hidden(params, tokens, sizes, jnp.float32)
+        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
+
+
+def _tokens(sizes, shape=(2, 48), seed=1):
+    ids = np.random.default_rng(seed).integers(
+        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
+    )
+    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+
+
+@pytest.fixture(scope="module")
+def share():
+    """Sizes, native config and seeded parameters of a share: experts 2-5
+    of 8, selection bias not zero."""
+    sizes = _sizes()
+    mod, cfg = family.native(sizes, jnp.float32)
+    assert (cfg.held, cfg.first_expert_held, cfg.n_experts) == (4, 2, 8)
+    params = mod.init_params(jax.random.PRNGKey(0), cfg)
+    params["moe_layers"]["router_bias"] = 0.05 * jax.random.normal(
+        jax.random.PRNGKey(9), params["moe_layers"]["router_bias"].shape
+    )
+    return sizes, cfg, params
+
+
+def test_reference_matches_transformers():
+    """(a) every expert held: the plain reference's logits are those of
+    ``DeepseekV3ForCausalLM`` (eager attention) on the same weights."""
+    import torch
+
+    sizes = _sizes(n_routed_experts=8, first_expert_held=0)
+    build, hf_config = family.hf(sizes)
+    hf_config._attn_implementation = "eager"
+    torch.manual_seed(0)
+    module = build(hf_config).eval()
+    for layer in module.model.layers[1:]:
+        layer.mlp.gate.e_score_correction_bias.normal_(0.0, 0.05)
+    _, cfg = family.native(sizes, jnp.float32)
+    arrays = {
+        k: v.detach().numpy()
+        for k, v in {
+            **dict(module.named_parameters()), **dict(module.named_buffers())
+        }.items()
+    }
+    params = convert.deepseek_v3_params_from_hf(arrays, cfg)
+    tokens, _ = _tokens(sizes)
+    with torch.no_grad():
+        want = module(torch.tensor(np.asarray(tokens))).logits.numpy()
+    with common.precision(jnp.float32):
+        x = ref.hidden(params, tokens, sizes, jnp.float32)
+        got = ref.head(params, x, jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=0)
+    # ... and so are the native family's, every expert held
+    np.testing.assert_allclose(
+        np.asarray(ds.forward(params, tokens, cfg, attn_impl="jnp")), want,
+        atol=1e-4, rtol=0,
+    )
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_loss_and_gradients_match_the_reference(share, impl, remat):
+    """(b) a held subset: loss and every gradient, through jnp attention
+    and the interpreted flash kernels, with and without remat."""
+    sizes, cfg, params = share
+    cfg = dataclasses.replace(cfg, remat=remat)
+    tokens, targets = _tokens(sizes)
+    (loss, aux), grads = jax.value_and_grad(
+        lambda p: ds.loss_fn(p, tokens, targets, cfg, attn_impl=impl),
+        has_aux=True,
+    )(params)
+    want, want_grads = jax.value_and_grad(_ref_loss)(
+        params, tokens, targets, sizes
+    )
+    assert abs(float(loss) - float(want)) < 1e-5
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        scale = float(jnp.abs(w).max()) + 1e-8
+        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    # the selection bias is a buffer: no gradient moves it
+    assert not np.asarray(grads["moe_layers"]["router_bias"]).any()
+    n = tokens.size * cfg.experts_per_token * cfg.n_moe_layers
+    assert 0 < float(aux["moe"]["local_assignments"]) < n
+    assert float(aux["moe"]["load_max_over_mean"]) >= 1.0
+
+
+def test_four_shares_add_up_to_the_whole_layer():
+    """(c) 32 experts in four shares of 8: the routed parts summed, the
+    shared expert counted once, are the uncut reference's layer output."""
+    sizes = _sizes(
+        n_routed_experts=32, n_routed_experts_total=32, first_expert_held=0,
+        num_experts_per_tok=6,
+    )
+    _, cfg = family.native(sizes, jnp.float32)
+    cfg = dataclasses.replace(cfg, n_dense_layers=0, n_moe_layers=1)
+    params = ds.init_params(jax.random.PRNGKey(2), cfg)
+    lp = jax.tree.map(lambda a: a[0], params["moe_layers"])
+    lp["router_bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (32,))
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 40, cfg.dim))
+    shared = ds._swiglu(h, lp["s_gate"], lp["s_up"], lp["s_down"])
+    total, assigned = shared, 0.0
+    for first in range(0, 32, 8):
+        part = dict(lp, **{
+            k: lp[k][first:first + 8] for k in ("e_gate", "e_up", "e_down")
+        })
+        out, stats = ds.moe_block(
+            h, part, dataclasses.replace(
+                cfg, n_experts_held=8, first_expert_held=first
+            ),
+        )
+        total = total + (out - shared)
+        assigned += float(stats["local_assignments"])
+    with common.precision(jnp.float32):
+        want = ref.routed(h, lp, sizes) + shared
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=0)
+    assert assigned == h.shape[0] * h.shape[1] * 6  # every choice, once
+
+
+@pytest.mark.parametrize("gates", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("case", ["one_expert", "one_held_one_absent", "all_absent"])
+def test_dropless_under_imbalance(gates, case):
+    """(e) a router that sends EVERY token to the same experts loses
+    nothing (the capacity cases of tests/test_moe.py, which drop, have no
+    counterpart here): the output is the dense sum over the held choices,
+    for every token."""
+    t, d, f, e = 96, 16, 8, 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    h = jax.random.normal(ks[0], (t, d))
+    eg, eu = (0.3 * jax.random.normal(k, (4, d, f)) for k in ks[1:3])
+    ed = 0.3 * jax.random.normal(ks[3], (4, f, d))
+    first = 2  # held: experts 2..5
+    picked = {"one_expert": (3, 4), "one_held_one_absent": (5, 7),
+              "all_absent": (0, 6)}[case]
+    router = 0.01 * jax.random.normal(ks[4], (d, e))
+    bias = jnp.zeros(e).at[jnp.asarray(picked)].set(50.0)
+    if gates == "softmax":  # no selection bias: tilt the logits themselves
+        h = h.at[:, 0].set(40.0)
+        router = router.at[0].set(bias / 40.0)
+        bias = None
+    out, stats = routed_experts(
+        h, router, eg, eu, ed, top_k=2, gates=gates, bias=bias, scale=1.5,
+        first_held=first,
+    )
+    assert (np.sort(np.asarray(stats["selected"]), -1) == np.asarray(picked)).all()
+    logits = h @ router
+    s = jax.nn.softmax(logits, -1) if gates == "softmax" else jax.nn.sigmoid(logits)
+    w = s[:, list(picked)]
+    w = 1.5 * w / w.sum(-1, keepdims=True)
+    want = jnp.zeros_like(h)
+    for j, ex in enumerate(picked):
+        if first <= ex < first + 4:
+            i = ex - first
+            want += w[:, j:j + 1] * (
+                (jax.nn.silu(h @ eg[i]) * (h @ eu[i])) @ ed[i]
+            )
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-4)
+    held = [ex for ex in picked if first <= ex < first + 4]
+    sizes = np.zeros(4, int)
+    sizes[[ex - first for ex in held]] = t
+    assert (np.asarray(stats["group_sizes"]) == sizes).all()
+    assert float(stats["local_assignments"]) == t * len(held)
+    if held:
+        assert (np.abs(np.asarray(out)).max(-1) > 0).all()  # no token lost
+    else:
+        assert not np.asarray(out).any()
+
+
+def test_absent_experts_are_never_materialized():
+    """(f) the paper's path: the layer is constructed with every expert,
+    fake; the absent ones are dropped; materialization fills the share's
+    parameters and no more."""
+    import torch
+
+    import torchdistx_tpu.deferred_init as di
+    import torchdistx_tpu.materialize as M
+
+    sizes = _sizes()
+    build, hf_config = family.hf(sizes)
+    module = di.deferred_init(build, hf_config)
+    _, cfg = family.native(sizes, jnp.float32)
+    assert sum(p.numel() for p in module.parameters()) == ds.num_params(cfg) - (
+        cfg.n_moe_layers * cfg.n_experts  # the bias is a buffer there
+    )
+    full = dataclasses.replace(cfg, n_experts_held=None)
+    assert ds.num_params(full) - ds.num_params(cfg) == (
+        cfg.n_moe_layers * 4 * 3 * cfg.dim * cfg.expert_dim
+    )
+    c0 = telemetry.counters()
+    arrays = M.materialize_module_jax(module, seed=3, dtype=torch.float32)
+    c1 = telemetry.counters()
+    ran = {
+        k: c1[k] - c0.get(k, 0) for k in c1
+        if k.startswith("materialize.") and c1[k] != c0.get(k, 0)
+    }
+    assert not any("experts.4." in k for k in arrays)
+    n_leaves = len(
+        jax.tree.leaves(ds.abstract_params(cfg))
+    ) - 3 * cfg.n_moe_layers  # e_gate/e_up/e_down are stacks of leaves
+    per_layer = 7
+    want_fills = (
+        3  # embed, final norm, head
+        + cfg.n_layers * per_layer + cfg.n_dense_layers * 3
+        + cfg.n_moe_layers * (1 + 3 + 3 * cfg.held)
+    )
+    # every fill that ran is a held parameter's (the buffers are not fills)
+    assert ran.get("materialize.fill_fastpath_hits") == want_fills
+    assert ran.get("materialize.torch_fallback_params", 0) == 0
+    assert n_leaves > 0
+    params = family.to_params(arrays, cfg)
+    assert jax.tree.map(jnp.shape, params) == jax.tree.map(
+        lambda a: a.shape, ds.abstract_params(cfg)
+    )
+
+
+def test_scopes_and_counters():
+    """The names a trace is read by: ``attn`` with the kernels under it,
+    ``mlp``, ``moe/router|dispatch|experts|combine|shared``; the host
+    counters of the share."""
+    sizes, (_, cfg) = _sizes(), family.native(_sizes(), jnp.float32)
+    params = jax.eval_shape(lambda: ds.init_params(jax.random.PRNGKey(0), cfg))
+    tok = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    c0 = telemetry.counters()
+    text = jax.jit(
+        jax.grad(lambda p, t: ds.loss_fn(p, t, t, cfg, attn_impl="pallas")[0])
+    ).lower(params, tok).as_text(debug_info=True)
+    c1 = telemetry.counters()
+    for scope in ("attn", "mlp", "moe/router", "moe/dispatch", "moe/experts",
+                  "moe/combine", "moe/shared"):
+        assert f"{scope}/" in text, scope
+    assert "attn/flash_fwd/" in text and "flash_bwd_fused/" in text
+    held = c1["moe.experts_held"] - c0.get("moe.experts_held", 0)
+    total = c1["moe.experts_total"] - c0.get("moe.experts_total", 0)
+    assert held > 0 and total == 2 * held
+    assert sizes["n_routed_experts_total"] == 2 * sizes["n_routed_experts"]
+
+
+def test_train_step_carries_the_counts_out_and_fit_records_them():
+    """``make_train_step`` differentiates a ``LOSS_HAS_AUX`` family with
+    ``has_aux`` and hands ``metrics["moe"]`` on; ``fit`` reads it into the
+    histograms ``moe.local_assignments`` and ``moe.load_max_over_mean``."""
+    import optax
+
+    from torchdistx_tpu.parallel import train_step as ts
+    from torchdistx_tpu.parallel.fit import fit
+    from torchdistx_tpu.parallel.mesh import MeshSpec, make_mesh
+
+    cfg = dataclasses.replace(
+        ds.deepseek_v3_test(), n_experts_held=4, first_expert_held=2
+    )
+    mesh = make_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
+    init_fn, step_fn = ts.make_train_step(
+        cfg, mesh, optax.adamw(1e-2), model=ds, attn_impl="jnp"
+    )
+    tokens = jax.device_put(
+        jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size),
+        ts.batch_sharding(mesh),
+    )
+    batch = {"tokens": tokens, "targets": tokens}
+    before = {
+        k: telemetry.histograms().get(k, {}).get("count", 0)
+        for k in ("moe.local_assignments", "moe.load_max_over_mean")
+    }
+    seen = []
+    state, metrics = fit(
+        init_fn, step_fn, [batch] * 5, key=jax.random.PRNGKey(0), n_steps=5,
+        handle_preemption=False,
+        on_metrics=lambda step, m: seen.append(float(m["loss"])),
+    )
+    assert seen[-1] < seen[0] and np.isfinite(seen).all()
+    assert set(metrics["moe"]) == {"local_assignments", "load_max_over_mean"}
+    n = tokens.size * cfg.experts_per_token * cfg.n_moe_layers
+    assert 0 < float(metrics["moe"]["local_assignments"]) < n
+    after = telemetry.histograms()
+    for k, n0 in before.items():
+        assert after[k]["count"] == n0 + 5, k
+    assert after["moe.load_max_over_mean"]["min"] >= 1.0
+    assert state.params["moe_layers"]["e_gate"].shape[:2] == (2, 4)

@@ -7,6 +7,8 @@ ships JAX-native model families designed for the TPU training stack:
 
 * :mod:`torchdistx_tpu.models.llama` — Llama-2-family decoder (flagship).
 * :mod:`torchdistx_tpu.models.gpt2` — GPT-2 family.
+* :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
+  — routed-expert families (imported where used).
 """
 
 from . import gpt2, llama  # noqa: F401

@@ -32,6 +32,7 @@ __all__ = [
     "llama_config_from_hf",
     "gpt2_params_from_hf",
     "llama_params_from_hf",
+    "deepseek_v3_params_from_hf",
 ]
 
 
@@ -168,6 +169,64 @@ def llama_params_from_hf(
         },
         "norm": {"weight": _get(arrays, "norm.weight")},
         "lm_head": {"weight": lm_head.T},
+    }
+
+
+def deepseek_v3_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat HF DeepSeek-V3 param dict -> the two stacks of
+    :mod:`~torchdistx_tpu.models.deepseek_v3` (linears transposed to
+    ``(in, out)``; the experts HELD, ``experts.0 .. experts.{held-1}`` of
+    the module as it stands after the others were dropped, stacked on an
+    expert axis; ``e_score_correction_bias`` as ``router_bias``)."""
+    attn = {
+        "attn_norm": "input_layernorm", "wq": "self_attn.q_proj",
+        "wkv_a": "self_attn.kv_a_proj_with_mqa",
+        "kv_norm": "self_attn.kv_a_layernorm", "wkv_b": "self_attn.kv_b_proj",
+        "wo": "self_attn.o_proj", "mlp_norm": "post_attention_layernorm",
+    }
+    mlp = {"gate": "gate_proj", "up": "up_proj", "down": "down_proj"}
+
+    def leaf(i, name):
+        a = _get(arrays, f"layers.{i}.{name}.weight")
+        return a.T if a.ndim == 2 else a
+
+    def stack(layers, table):
+        return {
+            k: jnp.stack([leaf(i, name) for i in layers])
+            for k, name in table.items()
+        }
+
+    dense = range(cfg.n_dense_layers)
+    moe = range(cfg.n_dense_layers, cfg.n_layers)
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "dense_layers": {
+            **stack(dense, attn),
+            **stack(dense, {f"w_{k}": f"mlp.{v}" for k, v in mlp.items()}),
+        },
+        "moe_layers": {
+            **stack(moe, attn),
+            **stack(moe, {"router": "mlp.gate"}),
+            "router_bias": jnp.stack([
+                _get(arrays, f"layers.{i}.mlp.gate.e_score_correction_bias")
+                for i in moe
+            ]),
+            **{
+                f"e_{k}": jnp.stack([
+                    jnp.stack([
+                        leaf(i, f"mlp.experts.{e}.{v}")
+                        for e in range(cfg.held)
+                    ])
+                    for i in moe
+                ])
+                for k, v in mlp.items()
+            },
+            **stack(
+                moe, {f"s_{k}": f"mlp.shared_experts.{v}" for k, v in mlp.items()}
+            ),
+        },
+        "norm": {"weight": _get(arrays, "norm.weight")},
+        "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
     }
 
 

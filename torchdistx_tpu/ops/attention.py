@@ -43,8 +43,9 @@ def _neg_inf(dtype):
 def mha_reference(q, k, v, *, causal: bool = True, segment_ids=None):
     """Reference multi-head attention (GQA-aware) in plain jax.numpy.
 
-    Shapes: q ``(B, Sq, Hq, D)``; k/v ``(B, Sk, Hkv, D)`` with
-    ``Hq % Hkv == 0`` (grouped-query attention).  Returns ``(B, Sq, Hq, D)``.
+    Shapes: q ``(B, Sq, Hq, D)``; k ``(B, Sk, Hkv, D)``; v ``(B, Sk, Hkv,
+    Dv)`` (``Dv`` = ``D`` everywhere but in latent attention) with
+    ``Hq % Hkv == 0`` (grouped-query attention).  Returns ``(B, Sq, Hq, Dv)``.
     Softmax is computed in float32 regardless of input dtype (bfloat16-safe).
     """
     import jax.numpy as jnp
@@ -70,7 +71,7 @@ def mha_reference(q, k, v, *, causal: bool = True, segment_ids=None):
     probs = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 def _attend_cached(q, k_cache, v_cache, valid):

@@ -257,15 +257,18 @@ def _fwd_kernel(
 
 
 def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
-    """q: (B, Hq, S_pad, D); k/v: (B, Hkv, S_pad, D); ``s`` = valid length.
+    """q: (B, Hq, S_pad, D); k: (B, Hkv, S_pad, D); v: (B, Hkv, S_pad, Dv);
+    ``s`` = valid length.  ``Dv`` may differ from ``D`` (latent attention:
+    192-wide q and k against 128-wide v); the scale is ``D**-0.5``.
 
-    Returns ``(out, lse)`` with ``out`` matching q's shape and ``lse``
-    ``(B, Hq, S_pad)`` float32.
+    Returns ``(out, lse)`` with ``out`` ``(B, Hq, S_pad, Dv)`` and ``lse``
+    ``(B, Hq, S_pad, 1)`` float32.
     """
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
     b, hq, s_pad, d = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[1]
     groups = hq // hkv
     bq = _pick_block(s_pad, _FWD_BLOCK_Q, _FWD_BLOCK_Q_DEFAULT)
@@ -288,20 +291,20 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bkv, d), kv_index),
-            pl.BlockSpec((1, 1, bkv, d), kv_index),
+            pl.BlockSpec((1, 1, bkv, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec(
                 (1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, hq, s_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, s_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
@@ -570,6 +573,7 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     import jax.experimental.pallas.tpu as pltpu
 
     b, hq, s_pad, d = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[1]
     groups = hq // hkv
     bq = _pick_block(s_pad, _BWD_BLOCK_Q, _BWD_BLOCK_Q_DEFAULT)
@@ -592,21 +596,19 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     nq = s_pad // bq
     scale = 1.0 / (d**0.5)
 
-    gq_q_spec = pl.BlockSpec(
-        (1, 1, bq, d),
-        lambda bi, hkvi, idx, g=groups, n=nq: (
-            bi, hkvi * g + idx // n, idx % n, 0
-        ),
-    )
-    gq_row_spec = pl.BlockSpec(
-        (1, 1, bq, 1),
-        lambda bi, hkvi, idx, g=groups, n=nq: (
-            bi, hkvi * g + idx // n, idx % n, 0
-        ),
-    )
-    kv_spec = pl.BlockSpec(
-        (1, 1, bkv, d), lambda bi, hkvi, idx: (bi, hkvi, 0, 0)
-    )
+    def gq_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda bi, hkvi, idx, g=groups, n=nq: (
+                bi, hkvi * g + idx // n, idx % n, 0
+            ),
+        )
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bkv, width), lambda bi, hkvi, idx: (bi, hkvi, 0, 0)
+        )
+
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _dqkv_fused_kernel, scale=scale, causal=causal, bq=bq,
@@ -614,10 +616,10 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
         ),
         grid=(b, hkv, groups * nq),
         in_specs=[
-            gq_q_spec, kv_spec, kv_spec, gq_q_spec, gq_row_spec,
-            gq_row_spec,
+            gq_spec(d), kv_spec(d), kv_spec(dv), gq_spec(dv), gq_spec(1),
+            gq_spec(1),
         ],
-        out_specs=[gq_q_spec, kv_spec, kv_spec],
+        out_specs=[gq_spec(d), kv_spec(d), kv_spec(dv)],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -625,7 +627,7 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -663,6 +665,7 @@ def _fa_backward_streamed(
     import jax.experimental.pallas.tpu as pltpu
 
     b, hq, s_pad, d = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[1]
     groups = hq // hkv
     if bq is None:
@@ -672,15 +675,21 @@ def _fa_backward_streamed(
     nq, nk = s_pad // bq, s_pad // bkv
     scale = 1.0 / (d**0.5)
 
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+        )
+
     kv_clamp = _diag_clamp(causal, bq, bkv, jnp.minimum)
-    kv_spec = pl.BlockSpec(
-        (1, 1, bkv, d),
-        lambda bi, hi, qi, ki, g=groups: (bi, hi // g, kv_clamp(ki, qi), 0),
-    )
-    row_spec = pl.BlockSpec(
-        (1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    )
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bkv, width),
+            lambda bi, hi, qi, ki, g=groups: (
+                bi, hi // g, kv_clamp(ki, qi), 0
+            ),
+        )
+
 
     dq = pl.pallas_call(
         functools.partial(
@@ -688,8 +697,11 @@ def _fa_backward_streamed(
             s_pad=s_pad,
         ),
         grid=(b, hq, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[
+            q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv), q_spec(1),
+            q_spec(1),
+        ],
+        out_specs=q_spec(d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -702,21 +714,20 @@ def _fa_backward_streamed(
     # dk/dv: grid over kv blocks with the (group, q-block) reduction as the
     # innermost axis — the GQA head-group sum happens in the accumulator.
     _q_block = _diag_clamp(causal, bq, bkv, jnp.maximum)
-    gq_q_spec = pl.BlockSpec(
-        (1, 1, bq, d),
-        lambda bi, hkvi, ki, idx, g=groups, n=nq: (
-            bi, hkvi * g + idx // n, _q_block(idx % n, ki), 0
-        ),
-    )
-    gq_row_spec = pl.BlockSpec(
-        (1, 1, bq, 1),
-        lambda bi, hkvi, ki, idx, g=groups, n=nq: (
-            bi, hkvi * g + idx // n, _q_block(idx % n, ki), 0
-        ),
-    )
-    kv_out_spec = pl.BlockSpec(
-        (1, 1, bkv, d), lambda bi, hkvi, ki, idx: (bi, hkvi, ki, 0)
-    )
+
+    def gq_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda bi, hkvi, ki, idx, g=groups, n=nq: (
+                bi, hkvi * g + idx // n, _q_block(idx % n, ki), 0
+            ),
+        )
+
+    def kv_out_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bkv, width), lambda bi, hkvi, ki, idx: (bi, hkvi, ki, 0)
+        )
+
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv, s=s,
@@ -724,17 +735,17 @@ def _fa_backward_streamed(
         ),
         grid=(b, hkv, nk, groups * nq),
         in_specs=[
-            gq_q_spec, kv_out_spec, kv_out_spec, gq_q_spec,
-            gq_row_spec, gq_row_spec,
+            gq_spec(d), kv_out_spec(d), kv_out_spec(dv), gq_spec(dv),
+            gq_spec(1), gq_spec(1),
         ],
-        out_specs=[kv_out_spec, kv_out_spec],
+        out_specs=[kv_out_spec(d), kv_out_spec(dv)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -797,7 +808,8 @@ def _fa_fwd(q, k, v, s, causal, interpret):
 
 def _fa_bwd(s, causal, interpret, res, do):
     q, k, v, out, lse = res
-    b, hq, s_pad, d = q.shape
+    b, hq, s_pad, _ = q.shape
+    d = v.shape[-1]
     do = do.reshape(b, s_pad, hq, d)
     # delta = rowsum(do * out), per query row and head: taken in the layout
     # both arrive in, so ``out`` is never carried back to the kernel's.
@@ -819,6 +831,8 @@ def flash_attention(
     q, k, v, *, causal: bool = True, interpret: Optional[bool] = None
 ):
     """Fused attention.  Layout matches the model stack: ``(B, S, H, D)``.
+    ``v`` may have a head width of its own (``(B, S, Hkv, Dv)``, as in
+    latent attention): the result is then ``(B, S, Hq, Dv)``.
 
     Any sequence length is accepted (padded to the TPU tile grain and masked
     in-kernel).  ``interpret``: force the Pallas interpreter (None = auto:
@@ -832,7 +846,8 @@ def flash_attention(
     _telemetry.counter(
         "attention.flash", interpret=str(bool(interpret)).lower()
     ).add()
-    b, s, hq, d = q.shape
+    b, s, hq, _ = q.shape
+    d = v.shape[-1]
     s_pad = _pad_len(s)
     # Kernel layout is (B, H, S, D).
     qt = q.transpose(0, 2, 1, 3)

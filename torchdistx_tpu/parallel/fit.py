@@ -60,6 +60,11 @@ _T_TOKENS_S = _telemetry.gauge("train.tokens_per_s")
 _T_MFU = _telemetry.gauge("train.mfu")
 _T_DATA_RETRIES = _telemetry.counter("data.retries")
 _T_PREEMPTIONS = _telemetry.counter("train.preemptions")
+# A step may carry groups of device scalars out beside its loss
+# (``metrics[group][name]``: a ``LOSS_HAS_AUX`` family's aux, see
+# make_train_step).  The step names them; each is observed into the
+# histogram ``<group>.<name>``, read with the guard flag's lag.
+_AUX_BOUNDS = tuple(2.0 ** (i / 4) for i in range(97))  # 1 .. 16.8M
 
 # Steps of lag before the host reads a step's `nonfinite` flag: reading a
 # device scalar blocks until that step finishes, so checking the freshest
@@ -67,6 +72,14 @@ _T_PREEMPTIONS = _telemetry.counter("train.preemptions")
 # lag keeps the async-dispatch pipeline full while bounding how late an
 # escalation fires.
 _NONFINITE_LAG = 2
+
+
+def _observe_aux(groups) -> None:
+    for group, scalars in groups.items():
+        for name, value in scalars.items():
+            _telemetry.histogram(f"{group}.{name}", _AUX_BOUNDS).observe(
+                float(value)
+            )
 
 
 def _batch_tokens(batch) -> Optional[int]:
@@ -210,6 +223,7 @@ def fit(
 
     tracker = _guard.SkipTracker(max_consecutive_nonfinite)
     pending_flags: deque = deque()  # (step, device nonfinite scalar)
+    pending_aux: deque = deque()  # (step, the step's groups of scalars)
     completed = start  # last step whose state we hold
     saved_at = start  # last step with a dispatched checkpoint
     preempted = False
@@ -312,6 +326,13 @@ def fit(
                 ):
                     s, flag = pending_flags.popleft()
                     tracker.observe(bool(flag), s)
+            groups = {
+                k: v for k, v in metrics.items() if isinstance(v, dict)
+            } if isinstance(metrics, dict) else {}
+            if groups:
+                pending_aux.append((done, groups))
+            while pending_aux and done - pending_aux[0][0] >= _NONFINITE_LAG:
+                _observe_aux(pending_aux.popleft()[1])
             if on_metrics is not None:
                 on_metrics(done, metrics)
             if ckptr is not None and (
@@ -330,6 +351,8 @@ def fit(
         while pending_flags:
             s, flag = pending_flags.popleft()
             tracker.observe(bool(flag), s)
+        while pending_aux:
+            _observe_aux(pending_aux.popleft()[1])
 
         # Always persist the final completed step: the loop may exit with
         # work done since the last periodic save — `batches` exhausted

@@ -125,7 +125,11 @@ def make_train_step(
     ``init_params(key, cfg)`` / ``abstract_params(cfg)`` /
     ``param_specs(cfg, tp=, fsdp=)`` / ``loss_fn(params, tokens, targets,
     cfg, ...)`` — :mod:`torchdistx_tpu.models.llama` (default) and
-    :mod:`~torchdistx_tpu.models.gpt2` both qualify.
+    :mod:`~torchdistx_tpu.models.gpt2` both qualify.  A family that sets
+    ``LOSS_HAS_AUX`` returns ``(loss, aux)`` from ``loss_fn``; ``aux`` (a
+    dict of device values, e.g. ``{"moe": routing counts}`` of
+    :mod:`~torchdistx_tpu.models.deepseek_v3`) is merged into the step's
+    ``metrics`` without a host sync.
 
     ``init_fn(key) -> TrainState`` — shard-then-materialize: parameters are
     initialized by one compiled program whose ``out_shardings`` place every
@@ -187,6 +191,7 @@ def make_train_step(
         model.loss_fn, cfg=cfg, mesh=mesh, seq_axis=seq_axis,
         attn_impl=attn_impl, **pp_loss_kw, **layout_kw,
     )
+    has_aux = loss_fn is None and getattr(model, "LOSS_HAS_AUX", False)
 
     if pp_schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"unknown pp_schedule: {pp_schedule!r}")
@@ -243,15 +248,18 @@ def make_train_step(
         # loss / optimizer / guard: named_scope regions (HLO metadata
         # only) by which a profile's device time is read; the model
         # families add embed / attn / mlp / head under loss.
+        aux = {}
         with jax.named_scope("loss"):
             if value_and_grad is not None:
                 loss, grads = value_and_grad(
                     state.params, batch["tokens"], batch["targets"]
                 )
             else:
-                loss, grads = jax.value_and_grad(_loss)(
+                loss, grads = jax.value_and_grad(_loss, has_aux=has_aux)(
                     state.params, batch["tokens"], batch["targets"]
                 )
+                if has_aux:
+                    loss, aux = loss
         if "_tdx_nan" in batch:
             # Deterministic fault injection (resilience.faults, kind
             # "nan"): poison the loss so the guard's real detection path
@@ -281,7 +289,7 @@ def make_train_step(
             }
         else:
             metrics = {"loss": loss, "step": new_state.step}
-        return new_state, metrics
+        return new_state, {**aux, **metrics}
 
     _perf.install_monitoring()
     return init_fn, _perf.JitProgram(
