@@ -32,21 +32,39 @@ def _grads(fn, q, k, v, w):
     )(q, k, v)
 
 
+def _flash_bwd_built(fn):
+    """``(fn(), {kernel: traces})``: which flash backward ``fn`` built, as
+    ``attention.flash_bwd{kernel=...}`` counted it."""
+    from torchdistx_tpu import telemetry
+
+    c0 = telemetry.counters()
+    out = fn()
+    c1 = telemetry.counters()
+    prefix = "attention.flash_bwd{kernel="
+    return out, {
+        key[len(prefix):-1]: c1[key] - c0.get(key, 0)
+        for key in c1
+        if key.startswith(prefix) and c1[key] != c0.get(key, 0)
+    }
+
+
 class TestFlashTwoWidths:
     """q and k of one head width, v and the output of another (latent
     attention: 192 against 128), through all four kernels."""
 
-    @pytest.mark.parametrize("backward", ["fused", "streamed"])
+    @pytest.mark.parametrize("backward", ["fused", "streamed", "fused_stream"])
     @pytest.mark.parametrize("d_qk,d_v", [(192, 128), (48, 32)])
     def test_forward_and_gradients_match_reference(
         self, d_qk, d_v, backward, monkeypatch
     ):
         from torchdistx_tpu.ops.pallas import flash_attention as fa
 
-        if backward == "streamed":  # several q and kv blocks, three kernels
+        if backward != "fused":  # several q and kv blocks
             monkeypatch.setattr(fa, "_BWD_BLOCK_Q", 128)
             monkeypatch.setattr(fa, "_BWD_BLOCK_KV", 128)
             monkeypatch.setattr(fa, "_FWD_BLOCK_KV", 128)
+        if backward == "streamed":  # no room for dq in VMEM: three kernels
+            monkeypatch.setattr(fa, "_FUSED_BWD_DQ_VMEM", 0)
         b, s, hq, hkv = 1, 384, 4, 2
         ks = jax.random.split(jax.random.PRNGKey(d_qk), 4)
         q = jax.random.normal(ks[0], (b, s, hq, d_qk))
@@ -57,7 +75,7 @@ class TestFlashTwoWidths:
         n_kernels = str(jax.make_jaxpr(
             lambda *a: _grads(flash, *a, w)
         )(q, k, v)).count("pallas_call")
-        assert n_kernels == (2 if backward == "fused" else 3)
+        assert n_kernels == (3 if backward == "streamed" else 2)
         out = flash(q, k, v)
         ref = mha_reference(q, k, v, causal=True)
         assert out.shape == ref.shape == (b, s, hq, d_v)
@@ -69,22 +87,99 @@ class TestFlashTwoWidths:
             assert g.shape == r.shape, name
             assert jnp.allclose(g, r, atol=1e-4), name
 
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("seq", [384, 400])  # 3 and 4 blocks, 400 padded
+    @pytest.mark.parametrize("groups", [1, 2])
+    @pytest.mark.parametrize("d_qk,d_v", [(192, 128), (48, 32), (32, 32)])
+    def test_one_backward_kernel_over_several_kv_blocks(
+        self, d_qk, d_v, groups, seq, causal, monkeypatch
+    ):
+        """dq, dk and dv of the one-kernel backward past one kv block
+        (the sequence's dq resident across the kv blocks) against
+        ``mha_reference``, and against the streamed pair on the same
+        inputs: the same p, ds and f32 sums in the same order."""
+        from torchdistx_tpu.ops.pallas import flash_attention as fa
+
+        monkeypatch.setattr(fa, "_BWD_BLOCK_Q", 128)
+        monkeypatch.setattr(fa, "_BWD_BLOCK_KV", 128)
+        ks = jax.random.split(jax.random.PRNGKey(seq + d_qk), 4)
+        q = jax.random.normal(ks[0], (1, seq, groups, d_qk))
+        k = jax.random.normal(ks[1], (1, seq, 1, d_qk))
+        v = jax.random.normal(ks[2], (1, seq, 1, d_v))
+        w = jax.random.normal(ks[3], (1, seq, groups, d_v))
+        flash = functools.partial(
+            flash_attention, causal=causal, interpret=True
+        )
+
+        def built(budget):
+            monkeypatch.setattr(fa, "_FUSED_BWD_DQ_VMEM", budget)
+            return _flash_bwd_built(lambda: _grads(flash, q, k, v, w))
+
+        fused, kernel = built(fa._FUSED_BWD_DQ_VMEM)
+        assert kernel == {"fused": 1}
+        pair, kernel = built(0)
+        assert kernel == {"pair": 1}
+        ref = _grads(
+            functools.partial(mha_reference, causal=causal), q, k, v, w
+        )
+        for name, g, p, r in zip("qkv", fused, pair, ref):
+            assert g.shape == r.shape, name
+            assert jnp.allclose(g, r, atol=1e-4), name
+            assert jnp.allclose(g, p, atol=1e-4), name
+
+    def test_the_shapes_alone_choose_the_backward(self):
+        """One kv block builds ``fused_nk1``; several build ``fused`` while
+        the sequence's f32 dq, ``groups * s_pad * lanes(d_qk) * 4`` bytes,
+        is within ``_FUSED_BWD_DQ_VMEM``, and the streamed ``pair`` past
+        it: the same call, nothing set, read from the counter."""
+        from torchdistx_tpu.ops.pallas import flash_attention as fa
+
+        def built(seq, hq, hkv, d):
+            q = jax.ShapeDtypeStruct((1, seq, hq, d), jnp.bfloat16)
+            kv = jax.ShapeDtypeStruct((1, seq, hkv, d), jnp.bfloat16)
+            grad = jax.grad(
+                lambda q, k, v: flash_attention(q, k, v, interpret=True)
+                .astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )
+            return _flash_bwd_built(lambda: jax.eval_shape(grad, q, kv, kv))[1]
+
+        under = fa._FUSED_BWD_DQ_VMEM // (256 * 4)  # positions of 192-wide heads
+        assert under >= 8192
+        assert built(1024, 2, 2, 64) == {"fused_nk1": 1}
+        assert built(8192, 2, 2, 192) == {"fused": 1}
+        assert built(under, 1, 1, 192) == {"fused": 1}
+        assert built(2 * under, 1, 1, 192) == {"pair": 1}
+        # The same positions at 128 lanes fit; a GQA group of four does not.
+        assert built(2 * under, 1, 1, 128) == {"fused": 1}
+        assert built(2 * under, 4, 1, 128) == {"pair": 1}
+
     @pytest.mark.parametrize(
-        "seq,heads,d,pinned",
+        "seq,heads,d,dq_vmem,pinned",
         [
-            (1024, 4, 64, "b877060f52d6c20b4175a1ea56c04f39da24e15ed5bbb0ecb8edde73772688d4"),
-            (4096, 2, 64, "d3af703b63c17d89f0e2bc8b66e4c3c6836a40b0734f3bc5d3631708b89d15ce"),
-            (4096, 2, 128, "d3440a3b7169f59a843fd57b615c2d80fcba4ff9b5ed415a2a14bb84bc0f5e3e"),
+            (1024, 4, 64, None, "b877060f52d6c20b4175a1ea56c04f39da24e15ed5bbb0ecb8edde73772688d4"),
+            (4096, 2, 64, None, "7f565f94ee1e9f3dcd84f4944d5e25b4d848bdf8623d5e7df529f57ec27d9597"),
+            (4096, 2, 128, None, "fe8906d8bdb3665a97cc3b1492797b81489df29dffe8dbad5ebb47606b13af70"),
+            (4096, 2, 64, 0, "d3af703b63c17d89f0e2bc8b66e4c3c6836a40b0734f3bc5d3631708b89d15ce"),
+            (4096, 2, 128, 0, "d3440a3b7169f59a843fd57b615c2d80fcba4ff9b5ed415a2a14bb84bc0f5e3e"),
         ],
     )
     def test_equal_widths_lower_to_the_program_of_pr_26(
-        self, seq, heads, d, pinned, monkeypatch
+        self, seq, heads, d, dq_vmem, pinned, monkeypatch
     ):
         """With ``d_qk == d_v`` the kernels are the ones that took a single
-        width: the gradient's StableHLO lowered for the TPU (fused backward
-        at 1024, the streamed pair at 4096), each Mosaic body printed
-        without its source locations, hashes to what commit bb7fb95 (PR 26)
-        gives.  A PR that changes the kernels on purpose re-pins."""
+        width: the gradient's StableHLO lowered for the TPU, each Mosaic
+        body printed without its source locations, hashes to what commit
+        bb7fb95 (PR 26) gives: the single-block backward at 1024, and at
+        4096 the streamed pair, which a shape takes since PR 28 only with
+        no room for dq in VMEM (``dq_vmem`` 0).  The two hashes at 4096 as
+        shapes alone choose it are PR 28's, re-pinned when the backward
+        there became one kernel.  A PR that changes the kernels on purpose
+        re-pins."""
+        from torchdistx_tpu.ops.pallas import flash_attention as fa
+
+        if dq_vmem is not None:
+            monkeypatch.setattr(fa, "_FUSED_BWD_DQ_VMEM", dq_vmem)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         x = jax.ShapeDtypeStruct((1, seq, heads, d), jnp.bfloat16)
         grad = jax.grad(
@@ -205,15 +300,17 @@ class TestFlashAttention:
             fused_calls.append(1)
             return orig_fused(*a, **kw)
 
-        old = fa._BWD_BLOCK_Q, fa._BWD_BLOCK_KV
+        old = fa._BWD_BLOCK_Q, fa._BWD_BLOCK_KV, fa._FUSED_BWD_DQ_VMEM
         fa._fa_backward_fused_nk1 = spy
         try:
             # Defaults: bkv == s_pad, fused single-kernel path.
             g_fused = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
             assert fused_calls, "defaults no longer take the fused path"
             n_fused = len(fused_calls)
-            # Force two kv blocks: the streamed dq + dkv kernel pair.
+            # Force two kv blocks and leave dq no room in VMEM: the
+            # streamed dq + dkv kernel pair.
             fa._BWD_BLOCK_Q, fa._BWD_BLOCK_KV = 128, 128
+            fa._FUSED_BWD_DQ_VMEM = 0
             jax.clear_caches()
             g_two = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
             assert len(fused_calls) == n_fused, (
@@ -221,7 +318,7 @@ class TestFlashAttention:
             )
         finally:
             fa._fa_backward_fused_nk1 = orig_fused
-            fa._BWD_BLOCK_Q, fa._BWD_BLOCK_KV = old
+            fa._BWD_BLOCK_Q, fa._BWD_BLOCK_KV, fa._FUSED_BWD_DQ_VMEM = old
             jax.clear_caches()
         for a, b_ in zip(g_fused, g_two):
             assert jnp.allclose(a, b_, atol=5e-5)
@@ -605,24 +702,31 @@ class TestAutoSelection:
             "attention.flash{interpret=true}": 1,
         }
 
-    def test_kernels_lower_through_mosaic_for_tpu(self):
-        """All four kernels lower for the TPU platform with the installed
-        JAX (Mosaic's Python-side lowering needs no chip): forward + fused
-        backward below ``_FUSED_BWD_MAX_KV``, forward + the streamed dq and
-        dk/dv pair above it."""
+    @pytest.mark.parametrize(
+        "s,n_calls",
+        [
+            (1024, 2),  # flash_fwd + flash_bwd_fused, one kv block
+            (4096, 2),  # flash_fwd + flash_bwd_fused, four (3 until PR 28)
+            (65536, 3),  # dq past the VMEM budget: the streamed pair
+        ],
+    )
+    def test_kernels_lower_through_mosaic_for_tpu(self, s, n_calls):
+        """All five kernels lower for the TPU platform with the installed
+        JAX (Mosaic's Python-side lowering needs no chip): forward + a
+        one-kernel backward while dq fits ``_FUSED_BWD_DQ_VMEM``, forward +
+        the streamed dq and dk/dv pair past it."""
 
         def loss(q, k, v):
             out = flash_attention(q, k, v, causal=True, interpret=False)
             return out.astype(jnp.float32).sum()
 
-        for s, n_calls in ((1024, 2), (4096, 3)):
-            x = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
-            lowered = (
-                jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-                .trace(x, x, x)
-                .lower(lowering_platforms=("tpu",))
-            )
-            assert lowered.as_text().count("tpu_custom_call") == n_calls
+        x = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
+        lowered = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            .trace(x, x, x)
+            .lower(lowering_platforms=("tpu",))
+        )
+        assert lowered.as_text().count("tpu_custom_call") == n_calls
 
     def test_pp_forward_pins_jnp(self):
         from torchdistx_tpu.models import llama

@@ -15,6 +15,7 @@ from jax.ad_checkpoint import print_saved_residuals
 
 from torchdistx_tpu.models import gpt2, llama
 from torchdistx_tpu.ops.pallas.flash_attention import (
+    _FUSED_BWD_DQ_VMEM,
     _FUSED_BWD_MAX_KV,
     REMAT_POLICY,
 )
@@ -42,7 +43,11 @@ def _grad_fn(mod, cfg, impl):
     "seq,n_calls",
     [
         (1024, 2),  # flash_fwd + flash_bwd_fused (3 with the forward rerun)
-        (2 * _FUSED_BWD_MAX_KV, 3),  # flash_fwd + the streamed pair (was 4)
+        # Several kv blocks: still one backward kernel (the pair until PR 28)
+        (2 * _FUSED_BWD_MAX_KV, 2),
+        # dq (f32, 128 lanes a head) at twice its VMEM budget: flash_fwd +
+        # the streamed pair (4 with the forward rerun)
+        (2 * _FUSED_BWD_DQ_VMEM // (128 * 4), 3),
     ],
 )
 def test_backward_holds_no_second_flash_fwd(family, seq, n_calls, monkeypatch):

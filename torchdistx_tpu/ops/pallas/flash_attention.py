@@ -18,14 +18,22 @@ log2 e folds into the softmax scale); only padded kv cols and
 causal-diagonal blocks are masked (padded q rows cancel structurally).
 Together: fwd+bwd 61→22 ms, attention MFU 0.16→0.44.
 
-The backward is two Pallas kernels using the standard flash-attention
-gradient identities (dv = pᵀ·do, ds = p∘(do·vᵀ − rowsum(do∘o)),
-dq = ds·k, dk = dsᵀ·q), each streaming its reduction axis through a grid
-dimension with VMEM scratch accumulators:
+The backward uses the standard flash-attention gradient identities
+(dv = pᵀ·do, ds = p∘(do·vᵀ − rowsum(do∘o)), dq = ds·k, dk = dsᵀ·q).  The
+shapes alone choose its kernels (``_fa_backward``; counted per trace as
+``attention.flash_bwd{kernel=}``):
 
-* dq kernel: grid ``(B, Hq, nq, nkv)`` — accumulates dq over kv blocks;
-* dk/dv kernel: grid ``(B, Hkv, nkv, groups·nq)`` — accumulates dk/dv over
-  (gqa-group, q-block) pairs, summing the GQA group reduction in-kernel.
+* one kv block (S ≤ 2048, the training regime): ONE kernel, grid
+  ``(B, Hkv, groups·nq)`` — dq is complete after each grid step;
+* several kv blocks: still ONE kernel, grid ``(B, Hkv, nkv, groups·nq)``,
+  the sequence's float32 dq held in VMEM across the kv blocks — while it
+  fits ``_FUSED_BWD_DQ_VMEM``;
+* past that, two kernels, each streaming its reduction axis through a grid
+  dimension with VMEM scratch accumulators, so VMEM stays O(bq·d + bkv·d):
+  dq, grid ``(B, Hq, nq, nkv)``, and dk/dv, grid
+  ``(B, Hkv, nkv, groups·nq)``.  The pair forms p and ds twice.
+
+dk/dv always sum the GQA group reduction in-kernel.
 
 Sequence lengths are padded to the TPU tile grain (128, or 8 below one
 block); padded keys/queries are masked in-kernel, so any length is accepted.
@@ -98,6 +106,12 @@ _FUSED_BWD_MAX_KV = 2048
 # to 128 to stay under this, and falls back to the streamed two-kernel
 # backward when even bq=128 cannot fit (bkv = s_pad > 8192).
 _FUSED_BWD_VMEM_CAP = 1024 * 1024 * 4
+# Past one kv block the backward stays ONE kernel while the whole
+# sequence's f32 dq accumulator, (groups, s_pad, d_qk padded to 128
+# lanes), fits this much VMEM beside the blocks (a v5e core has 128 MiB;
+# 8 MiB at 8,192 positions of 192-wide heads, 16 MiB at 32k of 128).
+# Longer sequences and wider GQA groups take the streamed pair.
+_FUSED_BWD_DQ_VMEM = 1024 * 1024 * 16
 _FWD_BLOCK_Q = None
 _FWD_BLOCK_KV = None
 _FWD_BLOCK_Q_DEFAULT = 1024
@@ -567,6 +581,86 @@ def _dqkv_fused_kernel(
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _dqkv_stream_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
+    dv_ref, dk_acc, dv_acc, dq_acc,
+    *, scale, causal, bq, bkv, s, s_pad, nq,
+):
+    """Several kv blocks, still ONE kernel: the grid is ``_dkv_kernel``'s
+    (kv block outer, (gqa group, q block) pairs inner), and the whole
+    sequence's dq accumulates in VMEM across the kv blocks, so p and ds
+    are formed once per (q block, kv block) pair: five block products
+    where the streamed pair runs seven, half its exp2s, and q, do, lse
+    and delta read from HBM once.  Each q block's dq sums over the kv
+    blocks in f32 in the order ``_dq_kernel`` sums them.
+    """
+    import jax.experimental.pallas as pl
+
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+    idx = pl.program_id(3)  # (gqa group, q block) pairs
+    n_idx = pl.num_programs(3)
+    g = idx // nq
+    qi = idx % nq
+    q_start = qi * bq
+    k_start = ki * bkv
+    rows = pl.ds(pl.multiple_of(q_start, bq), bq)
+
+    @pl.when(idx == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_acc[g, rows, :] = jnp.zeros((bq, dq_acc.shape[-1]), dq_acc.dtype)
+
+    run = (q_start + bq - 1 >= k_start) if causal else True
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+
+    def _body(apply_mask):
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        do = do_ref[0, 0]
+        p, ds = _p_ds(
+            q, k, v_ref[0, 0], do, lse_ref[0, 0], delta_ref[0, 0],
+            q_start, k_start,
+            scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
+            apply_mask=apply_mask,
+        )
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[g, rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(run & needs_mask)
+    def _body_masked():
+        _body(True)
+
+    @pl.when(run & jnp.logical_not(needs_mask))
+    def _body_plain():
+        _body(False)
+
+    # The dq block is the whole (groups, s_pad) extent of this kv head:
+    # it leaves VMEM once, after the last kv block filled it in.
+    @pl.when(ki == nk - 1)
+    def _finish_dq():
+        dq_ref[0, g, rows, :] = dq_acc[g, rows, :].astype(dq_ref.dtype)
+
+    @pl.when(idx == n_idx - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
 def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     """One-kernel backward for ``s_pad <= bkv`` (single kv block)."""
     import jax.experimental.pallas as pl
@@ -593,6 +687,7 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
             q, k, v, delta, lse, do, s, causal=causal, interpret=interpret,
             bkv=_block_for(s_pad),
         )
+    _count_bwd("fused_nk1")
     nq = s_pad // bq
     scale = 1.0 / (d**0.5)
 
@@ -638,20 +733,143 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     return dq, dk, dv
 
 
+def _lanes(d: int) -> int:
+    """A minor dimension as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+def _vmem_bytes(rows: int, cols: int, dtype) -> int:
+    """A ``(rows, cols)`` block in VMEM: 128 lanes by 8 32-bit sublanes a
+    tile (16 rows of bf16)."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // item)
+    return -(-rows // sub) * sub * _lanes(cols) * item
+
+
+def _count_bwd(kernel: str):
+    # Counted per trace, where the kernel is built: which backward a shape
+    # took (``fused_nk1``, ``fused`` or ``pair``) shows in the counters.
+    _telemetry.counter("attention.flash_bwd", kernel=kernel).add()
+
+
 def _fa_backward(q, k, v, delta, lse, do, s, *, causal, interpret):
-    s_pad = q.shape[2]
-    # Whole kv extent in one block → fused one-kernel path.  An explicit
-    # smaller kv-block override (sweeps/tests) forces the streamed pair.
+    """The backward for these shapes, chosen from the shapes alone: one kv
+    block -> ``_fa_backward_fused_nk1``; several, and the sequence's f32 dq
+    within ``_FUSED_BWD_DQ_VMEM`` -> ``_fa_backward_fused``; past that the
+    streamed pair."""
+    _, hq, s_pad, d = q.shape
+    # Whole kv extent in one block → the single-block kernel.  An explicit
+    # smaller kv-block override (sweeps/tests) asks for several blocks.
     if (_BWD_BLOCK_KV is None or _BWD_BLOCK_KV >= s_pad) and (
         s_pad <= _FUSED_BWD_MAX_KV
         or s_pad == _pick_block(s_pad, _BWD_BLOCK_KV, _BWD_BLOCK_KV_DEFAULT)
     ):
-        return _fa_backward_fused_nk1(
-            q, k, v, delta, lse, do, s, causal=causal, interpret=interpret
-        )
-    return _fa_backward_streamed(
+        backward = _fa_backward_fused_nk1
+    elif (hq // k.shape[1]) * s_pad * _lanes(d) * 4 <= _FUSED_BWD_DQ_VMEM:
+        backward = _fa_backward_fused
+    else:
+        backward = _fa_backward_streamed
+    return backward(
         q, k, v, delta, lse, do, s, causal=causal, interpret=interpret
     )
+
+
+def _kv_major_specs(*, bq, bkv, nq, groups, causal):
+    """Block specs of a grid ``(b, hkv, kv block, (gqa group, q block))``:
+    ``gq_spec(width)`` for what a query row carries (q, do, lse, delta),
+    its q axis clamped to the causal diagonal, and ``kv_spec(width)`` for
+    k, v, dk and dv."""
+    import jax.experimental.pallas as pl
+
+    q_block = _diag_clamp(causal, bq, bkv, jnp.maximum)
+
+    def gq_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bq, width),
+            lambda bi, hkvi, ki, idx, g=groups, n=nq: (
+                bi, hkvi * g + idx // n, q_block(idx % n, ki), 0
+            ),
+        )
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bkv, width), lambda bi, hkvi, ki, idx: (bi, hkvi, ki, 0)
+        )
+
+    return gq_spec, kv_spec
+
+
+def _fa_backward_fused(q, k, v, delta, lse, do, s, *, causal, interpret):
+    """One-kernel backward for several kv blocks (``_dqkv_stream_kernel``)."""
+    import jax.experimental.pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    _count_bwd("fused")
+    b, hq, s_pad, d = q.shape
+    dv = v.shape[-1]
+    hkv = k.shape[1]
+    groups = hq // hkv
+    bq = _pick_block(s_pad, _BWD_BLOCK_Q, _BWD_BLOCK_Q_DEFAULT)
+    bkv = _pick_block(s_pad, _BWD_BLOCK_KV, _BWD_BLOCK_KV_DEFAULT)
+    nq, nk = s_pad // bq, s_pad // bkv
+    gq_spec, kv_spec = _kv_major_specs(
+        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal
+    )
+    dq_spec = pl.BlockSpec(
+        (1, groups, s_pad, d), lambda bi, hkvi, ki, idx: (bi, hkvi, 0, 0)
+    )
+    # Mosaic's default scoped limit (16 MiB) is below the accumulator and
+    # its output block alone at 8k, so the call states what it holds:
+    # every block twice (the pipeline's two buffers), the scratch, and the
+    # (bq, bkv) temporaries of ``_p_ds``: logits, p, dp, ds in f32, and p
+    # and ds again in the matmul dtype.
+    f32 = jnp.float32
+    blocks = (
+        _vmem_bytes(bq, d, q.dtype) + _vmem_bytes(bq, dv, do.dtype)
+        + 2 * _vmem_bytes(bq, 1, f32)
+        + 2 * _vmem_bytes(bkv, d, k.dtype) + 2 * _vmem_bytes(bkv, dv, v.dtype)
+        + _vmem_bytes(groups * s_pad, d, q.dtype)
+    )
+    scratch = (
+        _vmem_bytes(bkv, d, f32) + _vmem_bytes(bkv, dv, f32)
+        + _vmem_bytes(groups * s_pad, d, f32)
+    )
+    temporaries = 4 * _vmem_bytes(bq, bkv, f32) + 2 * _vmem_bytes(
+        bq, bkv, q.dtype
+    )
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _dqkv_stream_kernel, scale=1.0 / (d**0.5), causal=causal, bq=bq,
+            bkv=bkv, s=s, s_pad=s_pad, nq=nq,
+        ),
+        grid=(b, hkv, nk, groups * nq),
+        in_specs=[
+            gq_spec(d), kv_spec(d), kv_spec(dv), gq_spec(dv), gq_spec(1),
+            gq_spec(1),
+        ],
+        out_specs=[dq_spec, kv_spec(d), kv_spec(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bkv, d), f32),
+            pltpu.VMEM((bkv, dv), f32),
+            pltpu.VMEM((groups, s_pad, d), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            # Both inner axes carry accumulators: dq over the kv blocks,
+            # dk and dv over the (group, q block) pairs.
+            dimension_semantics=(
+                "parallel", "parallel", "arbitrary", "arbitrary"
+            ),
+            vmem_limit_bytes=2 * blocks + scratch + temporaries,
+        ),
+        interpret=interpret,
+        name="flash_bwd_fused",
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
 
 
 def _fa_backward_streamed(
@@ -664,6 +882,7 @@ def _fa_backward_streamed(
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
+    _count_bwd("pair")
     b, hq, s_pad, d = q.shape
     dv = v.shape[-1]
     hkv = k.shape[1]
@@ -713,20 +932,9 @@ def _fa_backward_streamed(
 
     # dk/dv: grid over kv blocks with the (group, q-block) reduction as the
     # innermost axis — the GQA head-group sum happens in the accumulator.
-    _q_block = _diag_clamp(causal, bq, bkv, jnp.maximum)
-
-    def gq_spec(width):
-        return pl.BlockSpec(
-            (1, 1, bq, width),
-            lambda bi, hkvi, ki, idx, g=groups, n=nq: (
-                bi, hkvi * g + idx // n, _q_block(idx % n, ki), 0
-            ),
-        )
-
-    def kv_out_spec(width):
-        return pl.BlockSpec(
-            (1, 1, bkv, width), lambda bi, hkvi, ki, idx: (bi, hkvi, ki, 0)
-        )
+    gq_spec, kv_out_spec = _kv_major_specs(
+        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal
+    )
 
     dk, dv = pl.pallas_call(
         functools.partial(
