@@ -16,7 +16,8 @@ calls, at the published GPT-2-XL widths (48 layers, dim 1600, 25 heads of
               forward, and counted against solo generate
   train       make_train_step(attn_impl="pallas") on a mesh of the
               devices present, S=1024, loss finite and falling
-  kernels     all four flash kernels compiled by Mosaic (not interpreted,
+  kernels     the flash forward and the one-kernel backward, at one kv
+              block and at several, compiled by Mosaic (not interpreted,
               not jnp) and agreeing with ops.attention.mha_reference
   four_chip   (>= 4 devices) shard-then-materialize over fsdp=4, shards
               on 4 distinct devices, per-device bytes near an even share

@@ -36,9 +36,9 @@ Usage::
         # violation, or unaccounted time above --tolerance (fraction of
         # the request's wall time, default 0.05)
 
-``bench.py``'s serving scenarios import :func:`reconstruct` directly,
-so bench numbers and post-mortem numbers come from the same
-reconstruction path.
+The ops plane's live ``/requests`` view imports :func:`reconstruct`
+directly (``telemetry/ops.py``), so live numbers and post-mortem numbers
+come from the same reconstruction path.
 """
 
 from __future__ import annotations

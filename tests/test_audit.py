@@ -215,6 +215,36 @@ def test_auditor_clean_traffic_no_divergence(family):
     eng.close()
 
 
+def test_auditing_compiles_no_second_decode_chunk(family):
+    """Audit replays are shadow traffic through the SAME compiled
+    programs: once an unaudited engine of this geometry has served,
+    the same traffic with the auditor at 100% sampling leaves
+    ``compile.count{program=decode_chunk}`` where it was."""
+    model, cfg, params = family
+    name = "compile.count{program=decode_chunk}"
+
+    def serve(**kw):
+        eng = Engine(
+            params, model=model, cfg=cfg, eos_id=EOS, **kw, **ENGINE_KW
+        )
+        for i in range(3):
+            eng.submit(prompt_of(4 + i), max_new_tokens=6, key=100 + i)
+        eng.drain()
+        st = eng.stats()
+        eng.close()
+        return st
+
+    serve()  # warm-up: every program this traffic needs
+    warm = telemetry.counters().get(name, 0)
+    st = serve(audit_sample=1.0)
+    assert st["audit_checked"] == 3 and st["audit_divergences"] == 0
+    assert telemetry.counters().get(name, 0) == warm
+    assert (
+        "compile.recompiles{program=decode_chunk}"
+        not in telemetry.counters()
+    )
+
+
 def test_auditor_off_by_default_and_sample_zero(family):
     model, cfg, params = family
     eng = Engine(params, model=model, cfg=cfg, **ENGINE_KW)

@@ -1,20 +1,16 @@
-"""Perf plane (ISSUE 12): compile observatory, HBM ledger, OOM
-forensics, and the bench regression gate.
+"""Perf plane (ISSUE 12): compile observatory, HBM ledger and OOM
+forensics.
 
 The acceptance bar: per-program compile counts are exact (the decode
 chunk compiles exactly ONCE through a full serving lifecycle —
 admission, chunked prefill, decode, slot recycling, preempt/resume); an
 injected shape-churn storm trips the detector (latch gauge +
 ``reason="recompile_storm"`` flight dump + the engine marked
-OVERLOADED); an induced pool-exhaustion failure's flight dump carries
-the HBM ledger snapshot; and ``scripts/bench_gate.py`` exits nonzero on
-a synthetically regressed metric and zero on a round replayed against
-itself.
+OVERLOADED); and an induced pool-exhaustion failure's flight dump
+carries the HBM ledger snapshot.
 """
 
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -27,13 +23,6 @@ from torchdistx_tpu.models.generate import generate  # noqa: E402
 from torchdistx_tpu.serving import Engine  # noqa: E402
 from torchdistx_tpu.serving.blocks import BlockAllocator  # noqa: E402
 from torchdistx_tpu.telemetry import perf  # noqa: E402
-
-sys.path.insert(
-    0,
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 "scripts"),
-)
-import bench_gate  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -386,86 +375,3 @@ def test_allocator_fragmentation_estimate():
     assert a.fragmentation() == pytest.approx(1 - 1 / 3)
     a.free([pages[0], pages[2], pages[4], pages[6], pages[7]])
     assert a.fragmentation() == 0.0  # everything free again
-
-
-# ---------------------------------------------------------------------------
-# Bench regression gate
-
-
-def _history_round(tmp_path, name, xl_s, warm_s, serving=None):
-    doc = {
-        "metric": "deferred_init_materialize_gpt2xl_bf16_1chip",
-        "value": xl_s,
-        "details": {
-            "gpt2xl_1p6b_bf16": {"ours_s": xl_s, "ours_warm_s": warm_s},
-        },
-    }
-    if serving is not None:
-        doc["details"]["serving_llama_350m_continuous"] = serving
-    path = tmp_path / name
-    path.write_text(json.dumps({"parsed": doc}))
-    return str(path)
-
-
-SERVING_ROW = {
-    "sustained_decode_tokens_per_s": 4000.0,
-    "ttft_p95_s": 0.5,
-    "tpot_p95_s": 0.002,
-    "goodput_tokens_per_s": 3500.0,
-}
-
-
-def test_bench_gate_round_replayed_against_itself_passes(tmp_path):
-    r = _history_round(tmp_path, "BENCH_r09.json", 1.6, 0.13, SERVING_ROW)
-    assert bench_gate.main(["--baseline", r, "--candidate", r]) == 0
-
-
-def test_bench_gate_empty_history_gates_vacuously(tmp_path, capsys):
-    """No history is recorded for the current installation, so the
-    default invocation has none: the gate passes and says so — every
-    row ``no_baseline``, none silently "ok"."""
-    cand = _history_round(tmp_path, "candidate.json", 1.6, 0.13, SERVING_ROW)
-    assert bench_gate.main(["--candidate", cand]) == 0
-    verdict = json.loads(capsys.readouterr().out)
-    assert verdict["pass"] is True and verdict["baseline_rounds"] == []
-    assert {r["status"] for r in verdict["metrics"].values()} == {
-        "no_baseline"
-    }
-
-
-def test_bench_gate_fails_synthetic_regression(tmp_path, capsys):
-    base = _history_round(tmp_path, "BENCH_r09.json", 1.6, 0.13, SERVING_ROW)
-    bad_serving = dict(SERVING_ROW, sustained_decode_tokens_per_s=2000.0)
-    cand = _history_round(
-        tmp_path, "candidate.json", 1.6, 0.13, bad_serving
-    )
-    assert bench_gate.main(["--baseline", base, "--candidate", cand]) == 1
-    verdict = json.loads(capsys.readouterr().out)
-    row = verdict["metrics"]["serving_sustained_decode_tok_s"]
-    assert row["status"] == "regressed" and verdict["pass"] is False
-
-
-def test_bench_gate_fails_when_tracked_metric_vanishes(tmp_path, capsys):
-    base = _history_round(tmp_path, "BENCH_r09.json", 1.6, 0.13, SERVING_ROW)
-    cand = _history_round(tmp_path, "candidate.json", 1.6, 0.13, None)
-    assert bench_gate.main(["--baseline", base, "--candidate", cand]) == 1
-    verdict = json.loads(capsys.readouterr().out)
-    assert (
-        verdict["metrics"]["serving_ttft_p95_s"]["status"]
-        == "missing_from_candidate"
-    )
-
-
-def test_bench_gate_tolerance_band(tmp_path):
-    base = _history_round(tmp_path, "BENCH_r09.json", 1.0, 0.1, SERVING_ROW)
-    slower = dict(SERVING_ROW, ttft_p95_s=0.6)  # +20% < 35% band
-    cand = _history_round(tmp_path, "candidate.json", 1.2, 0.12, slower)
-    assert bench_gate.main(["--baseline", base, "--candidate", cand]) == 0
-    # The same candidate fails a tightened band.
-    assert (
-        bench_gate.main(
-            ["--baseline", base, "--candidate", cand,
-             "--tolerance", "0.05"]
-        )
-        == 1
-    )

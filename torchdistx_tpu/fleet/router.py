@@ -122,8 +122,8 @@ _H_FAILOVER_ADDED = _telemetry.histogram("fleet.failover_added_s")
 # Warm stream migrations: completed page-level moves vs. imports that
 # failed and fell back to the cold key-pinned replay.  The histogram is
 # the full export→import wall clock — what a migrated stream's consumer
-# waited, the number the bench compares against cold-replay added
-# latency.
+# waited, the number to hold against cold-replay added latency
+# (``fleet.failover_added_s``).
 _T_MIGRATIONS = _telemetry.counter("fleet.migrations")
 _T_MIGRATION_FALLBACKS = _telemetry.counter("fleet.migration_fallbacks")
 _H_MIGRATION = _telemetry.histogram("fleet.migration_s")

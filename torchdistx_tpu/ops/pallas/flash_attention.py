@@ -74,15 +74,15 @@ def _pad_len(s: int) -> int:
     return -(-s // 8) * 8
 
 
-# Block-size overrides (None = measured-best default).  Module-level
-# knobs so the bench/tuning harness (scripts/flash_sweep.py) can sweep
-# them.
+# Block-size overrides (None = the defaults below).  Tests set them to
+# get several q and kv blocks at small sizes (``tests/test_attention.py``);
+# nothing else does.
 #
-# NOTE (advisor r4): these globals are read at TRACE time and are not part
-# of any jit cache key — a sweep that mutates them under a caller's cached
-# ``jax.jit`` keeps executing the previously-traced blocks.  Sweeps must
-# call ``jax.clear_caches()`` after each override change (the bench
-# harness does).
+# They are read at TRACE time and are not part of any jit cache key: a
+# function already traced under a caller's ``jax.jit`` keeps the blocks it
+# was traced with.  Whoever sets one traces anew afterwards (a fresh
+# ``jax.jit``, or ``jax.clear_caches()``) and restores it when done
+# (``monkeypatch`` does).
 #
 # With the mask-free interior bodies, SQUARE blocks measure best both
 # directions at S=16k d=128 on v5e (adjacent same-window runs:

@@ -152,8 +152,8 @@ def fit(
     (``tokens_per_batch``, or auto-detected from a ``{"tokens": ...}``
     batch dict); ``mfu`` additionally needs ``flops_per_step`` (model
     FLOPs per optimizer step) and ``peak_flops`` (the chip's peak, in
-    FLOP/s — see bench.py's per-device-kind table).  When ``metrics`` is a
-    dict, the derived values are merged in before ``on_metrics`` sees it.
+    FLOP/s: ``benchmarks/peaks.json``, by ``device_kind``).  When ``metrics``
+    is a dict, the derived values are merged in before ``on_metrics`` sees it.
 
     Returns ``(state, last_metrics)``.
     """

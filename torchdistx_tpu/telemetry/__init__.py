@@ -3,9 +3,9 @@
 The observability layer for the whole stack — the tape recorder
 (``_tape.py``), materialization (``materialize.py``), the compilation
 cache (``utils/compilation_cache.py``), and the training loop
-(``parallel/fit.py``) all report through this module; ``bench.py``
-assembles its headline JSON from it.  See ``docs/observability.md`` for
-the span/counter catalog and the export formats.
+(``parallel/fit.py``) all report through this module, and the benchmark
+(``benchmarks/run.py``) reads its counters and spans.  See
+``docs/observability.md`` for the span/counter catalog and export formats.
 
 Quick start::
 

@@ -273,7 +273,7 @@ _reconstruct: Any = "__unset__"
 
 def _load_reconstruct() -> Optional[Callable]:
     """Lazy import of ``scripts/trace_report.reconstruct`` — the same
-    reconstruction path bench and the CI gates use, so the live
+    reconstruction path the CI gates use, so the live
     ``/requests`` view can never drift from the post-mortem one.  In a
     checkout (editable install) the scripts directory sits beside the
     package; an installation without it degrades ``/requests`` to 503."""
@@ -1113,8 +1113,8 @@ def env_ops_port() -> Optional[int]:
         return None
 
 
-# Per-tick attribution without a server (bench: utilization numbers with
-# no HTTP listener).  The engine's gate is
+# Per-tick attribution without a server (utilization numbers with no
+# HTTP listener, as the tests read them).  The engine's gate is
 # ``self._ops_plane is not None or ops.tick_attribution_enabled()`` —
 # one attribute read and one module-global read per tick, no allocation.
 _TICK_ATTRIBUTION = False
@@ -1123,7 +1123,7 @@ _TICK_ATTRIBUTION = False
 def enable_tick_attribution(on: bool = True) -> bool:
     """Force per-tick utilization attribution on (or off) process-wide,
     independent of any ops server.  Returns the previous value so a
-    scope (bench) can restore it."""
+    scope can restore it."""
     global _TICK_ATTRIBUTION
     prev = _TICK_ATTRIBUTION
     _TICK_ATTRIBUTION = bool(on)

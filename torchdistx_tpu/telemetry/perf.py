@@ -47,8 +47,8 @@ program recompiled ``TDX_RECOMPILE_STORM_N`` times (default 3) inside
 ``reason="recompile_storm"``, and marks the owning engine OVERLOADED
 (the stall-watchdog convention: a fleet router routes around it; the
 latch clears once the program goes a full window without recompiling).
-A shape leak in the decode chunk is caught live, not in next week's
-bench.
+A shape leak in the decode chunk is caught live, not as a lower rate
+on the benchmark's next ledger line.
 
 **HBM ledger.**  Device memory is spent by four subsystems — weights,
 the paged KV pool, swap staging, prefix-cache-held pages — and a

@@ -240,7 +240,7 @@ def publish_tick(engine, timer: TickTimer, tick_s: float, idle: bool = False) ->
 def phase_summaries(engine_id: str) -> Dict[str, Dict[str, Any]]:
     """One engine's tick-phase breakdown, phase → histogram summary
     (``{count, sum, min, max, p50, p95, p99}``; phases never observed
-    omitted).  The ONE readback bench/bench_gate consume — callers must
+    omitted).  The ONE readback of the breakdown — callers must
     not hand-parse the rendered ``serve.tick_phase_s{...}`` registry
     names, whose label encoding belongs to ``_core``."""
     out: Dict[str, Dict[str, Any]] = {}
