@@ -18,6 +18,7 @@ import pytest
 
 from torchdistx_tpu import telemetry
 from torchdistx_tpu.models import convert, deepseek_v3 as ds
+from torchdistx_tpu.ops import routed_experts as routed_mod
 from torchdistx_tpu.ops.routed_experts import routed_experts
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
@@ -208,6 +209,116 @@ def test_dropless_under_imbalance(gates, case):
         assert not np.asarray(out).any()
 
 
+def _dense_held_sum(h, router, eg, eu, ed, *, top_k, bias, scale, first):
+    """The layer with no sort and no chunk: every held expert's FFN over
+    EVERY token, weighted by what the (sigmoid) router gave that token's
+    choice of it, 0 where it was not chosen."""
+    scores = jax.nn.sigmoid(h @ router)
+    _, selected = jax.lax.top_k(scores + bias, top_k)
+    w = jnp.take_along_axis(scores, selected, axis=-1)
+    w = scale * w / w.sum(-1, keepdims=True)
+    out = jnp.zeros_like(h)
+    for i in range(eg.shape[0]):
+        wi = jnp.where(selected == first + i, w, 0.0).sum(-1, keepdims=True)
+        out += wi * ds._swiglu(h, eg[i], eu[i], ed[i])
+    return out
+
+
+# name: (top_k, experts every token is sent to (None: the router's own
+# choice), the row bound forced, held assignments M, chunks = ceil(M / R))
+CHUNK_CASES = {
+    "no_chunk_all_absent": (2, (0, 6), 64, 0, 0),
+    "one_chunk": (2, None, 128, 79, 1),
+    "two_chunks": (2, None, 64, 79, 2),
+    "every_token_to_one_held_expert": (1, (3,), 32, 96, 3),  # T*k / R
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_row_chunks_cover_every_held_row(case, monkeypatch):
+    """The sorted side runs ``ceil(M / R)`` chunks of ``R`` rows, counted
+    from the routing, and whatever the count the layer and its gradients
+    (h, router, the three expert matrices) are the dense per-token sum
+    over the held choices; ``stats["row_chunks"]`` says how many ran."""
+    top_k, picked, bound, m, chunks = CHUNK_CASES[case]
+    t, d, f, e, first = 96, 16, 8, 8, 2  # held: experts 2..5
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    h = jax.random.normal(ks[0], (t, d))
+    router = 0.3 * jax.random.normal(ks[1], (d, e))
+    eg, eu = (0.3 * jax.random.normal(k, (4, d, f)) for k in ks[2:4])
+    ed = 0.3 * jax.random.normal(ks[4], (4, f, d))
+    cot = jax.random.normal(ks[5], (t, d))
+    bias = 0.05 * jnp.arange(e, dtype=jnp.float32)
+    if picked is not None:
+        bias = bias.at[jnp.asarray(picked)].set(50.0)
+    monkeypatch.setattr(routed_mod, "_row_bound", lambda *shape: bound)
+    kw = dict(top_k=top_k, bias=bias, scale=1.5)
+
+    def layer(*args):
+        out, stats = routed_experts(
+            *args, gates="sigmoid", first_held=first, **kw
+        )
+        return (out * cot).sum(), (out, stats)
+
+    def dense(*args):
+        out = _dense_held_sum(*args, first=first, **kw)
+        return (out * cot).sum(), out
+
+    args = (h, router, eg, eu, ed)
+    grads, (out, stats) = jax.jit(
+        jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    )(*args)
+    want_grads, want = jax.grad(dense, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    assert int(stats["local_assignments"]) == m
+    assert int(stats["row_chunks"]) == chunks == -(-m // bound)
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=0)
+    for name, g, g_want in zip(
+        ("h", "router", "e_gate", "e_up", "e_down"), grads, want_grads
+    ):
+        np.testing.assert_allclose(g, g_want, atol=2e-5, rtol=1e-5, err_msg=name)
+    if not m:
+        assert not any(np.asarray(g).any() for g in grads)
+
+
+def _avals(jaxpr):
+    """Every array a jaxpr computes, the bodies of its loops, calls and
+    custom rules included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _avals(sub)
+
+
+@pytest.mark.parametrize("program", ["layer", "gradient"])
+def test_no_worst_case_buffer_when_a_share_is_held(program):
+    """With ``Eh < E`` nothing on the sorted side is ``T*k`` rows long:
+    no array of the layer, nor of its gradient, holds ``T*k`` rows of an
+    expert's width ``F`` or more (the routing's integer vectors and the
+    ``(T*k, Eh)`` comparison that counts the groups are narrower)."""
+    t, d, f, e, n_held, k = 256, 32, 16, 16, 4, 4
+    shapes = [(t, d), (d, e), (n_held, d, f), (n_held, d, f), (n_held, f, d)]
+    bound = routed_mod._row_bound(t * k, n_held, e)
+    assert bound < t * k
+
+    def layer(*args):
+        return routed_experts(*args, top_k=k, gates="sigmoid")[0].sum()
+
+    fn = layer if program == "layer" else jax.grad(layer, argnums=(0, 1, 2, 3, 4))
+    jaxpr = jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)
+    )
+    avals = [a for a in _avals(jaxpr.jaxpr) if getattr(a, "shape", ())]
+    assert any(a.shape == (bound, f) for a in avals)
+    wide = [
+        a for a in avals
+        if np.prod(a.shape[:-1]) == t * k and a.shape[-1] >= f
+    ]
+    assert not wide, wide
+
+
 def test_absent_experts_are_never_materialized():
     """(f) the paper's path: the layer is constructed with every expert,
     fake; the absent ones are dropped; materialization fills the share's
@@ -280,7 +391,8 @@ def test_scopes_and_counters():
 def test_train_step_carries_the_counts_out_and_fit_records_them():
     """``make_train_step`` differentiates a ``LOSS_HAS_AUX`` family with
     ``has_aux`` and hands ``metrics["moe"]`` on; ``fit`` reads it into the
-    histograms ``moe.local_assignments`` and ``moe.load_max_over_mean``."""
+    histograms ``moe.local_assignments``, ``moe.load_max_over_mean`` and
+    ``moe.row_chunks``."""
     import optax
 
     from torchdistx_tpu.parallel import train_step as ts
@@ -301,7 +413,9 @@ def test_train_step_carries_the_counts_out_and_fit_records_them():
     batch = {"tokens": tokens, "targets": tokens}
     before = {
         k: telemetry.histograms().get(k, {}).get("count", 0)
-        for k in ("moe.local_assignments", "moe.load_max_over_mean")
+        for k in (
+            "moe.local_assignments", "moe.load_max_over_mean", "moe.row_chunks"
+        )
     }
     seen = []
     state, metrics = fit(
@@ -310,7 +424,12 @@ def test_train_step_carries_the_counts_out_and_fit_records_them():
         on_metrics=lambda step, m: seen.append(float(m["loss"])),
     )
     assert seen[-1] < seen[0] and np.isfinite(seen).all()
-    assert set(metrics["moe"]) == {"local_assignments", "load_max_over_mean"}
+    assert set(metrics["moe"]) == {
+        "local_assignments", "load_max_over_mean", "row_chunks"
+    }
+    # Half the experts are held, so a chunk is two thirds of the T*k rows:
+    # every layer runs one or two, and the step carries their sum out.
+    assert cfg.n_moe_layers <= int(metrics["moe"]["row_chunks"]) <= 2 * cfg.n_moe_layers
     n = tokens.size * cfg.experts_per_token * cfg.n_moe_layers
     assert 0 < float(metrics["moe"]["local_assignments"]) < n
     after = telemetry.histograms()

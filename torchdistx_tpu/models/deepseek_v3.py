@@ -282,7 +282,8 @@ def moe_block(h, lp, cfg: DeepseekV3Config):
 
 def _build_blocks(cfg: DeepseekV3Config, *, mesh=None, attn_impl="auto"):
     """``(dense_block, moe_block)``: ``x, lp -> x`` and ``x, lp -> (x,
-    (assignments to held experts, busiest held expert over their mean))``."""
+    (assignments to held experts, busiest held expert over their mean,
+    row chunks the routed layer ran))``."""
 
     def dense(x, lp):
         x = _attn(x, lp, cfg, mesh=mesh, attn_impl=attn_impl)
@@ -295,7 +296,8 @@ def _build_blocks(cfg: DeepseekV3Config, *, mesh=None, attn_impl="auto"):
         h = llama_mod._rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         out, stats = moe_block(h, lp, cfg)
         return x + out, (
-            stats["local_assignments"], stats["load_max_over_mean"]
+            stats["local_assignments"], stats["load_max_over_mean"],
+            stats["row_chunks"],
         )
 
     return dense, moe
@@ -314,10 +316,11 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
     x, _ = jax.lax.scan(
         lambda h, lp: (dense(h, lp), None), x, params["dense_layers"]
     )
-    x, (assigned, load) = jax.lax.scan(moe, x, params["moe_layers"])
+    x, (assigned, load, chunks) = jax.lax.scan(moe, x, params["moe_layers"])
     return x, {
         "local_assignments": assigned.sum(),
         "load_max_over_mean": load.mean(),
+        "row_chunks": chunks.sum(),
     }
 
 

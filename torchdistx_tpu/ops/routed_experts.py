@@ -12,18 +12,43 @@ experts would add is left out; nothing stands in for them or their
 exchange.
 
 No token is dropped and there is no capacity: the ``T*k`` assignments are
-sorted by expert (absent experts last) and the expert FFNs run as grouped
-matrix products over the sorted rows (``jax.lax.ragged_dot``; XLA:TPU
-lowers it to its own grouped-matmul kernel, ``ragged-dot-*`` in a trace,
-which only visits rows that belong to a group).  Gathers both ways: the
-dispatch's and the combine's backward passes are gathers by the inverse
-permutation, never a scatter-add.
+sorted by expert (absent experts last), so the rows the held experts work
+on are the first ``M = group_sizes.sum()`` of the sorted order, and the
+expert FFNs run as grouped matrix products over them
+(``jax.lax.ragged_dot``; XLA:TPU lowers it to its own grouped-matmul
+kernel, ``ragged-dot-*`` in a trace, which only visits rows that belong to
+a group).  Only those rows are touched.  Everything on the sorted side runs
+over a CHUNK of ``R`` rows, ``ceil(M / R)`` chunks in a loop whose trip
+count is read on the device from the routing the step just made:
+
+* ``R`` comes from shapes alone (``_row_bound``): the held share of the
+  assignments, ``T*k*Eh/E``, with a third of room over it, rounded up to
+  the grouped product's row tile of 512, at most ``T*k``.  A layer that
+  holds every expert runs ONE chunk of ``T*k`` rows; a share of a quarter
+  runs a third of the rows while its routing stays within the room, and
+  one more chunk whenever it does not.  For ANY routing the chunks cover
+  all ``M`` rows: nothing is dropped or approximated, an uneven routing
+  costs chunks (``stats["row_chunks"]`` counts them).
+* A chunk gathers its rows of ``h``, clips the group sizes to its range,
+  runs the three grouped products on ``R`` rows, and every token adds, in
+  float32, the rows of the chunk that hold one of its choices (gathers by
+  the inverse permutation, choice by choice; never a scatter-add, which
+  measured 1.5 ms where the six gathers take 0.7, PERF.md section 6).
+* The loop's trip count is dynamic, and such a loop has no transpose, so
+  the sorted side is ONE ``jax.custom_vjp``: its residuals are its inputs
+  and its backward is the same loop by hand, with ``jax.vjp`` of the
+  chunk's products inside the body, accumulating the gradients of ``h``
+  and the weights in float32 and of the expert matrices chunk by chunk.
+  The first chunk runs outside the loop, so with one chunk nothing is
+  carried through a loop.
 
 Scopes (HLO metadata only): ``router``, ``dispatch``, ``experts``,
 ``combine``, to be entered under the caller's ``moe`` scope.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,57 +57,135 @@ __all__ = ["routed_experts"]
 
 
 # The T*k assignments are kept CHOICE-major (row ``c * T + t`` is token
-# ``t``'s ``c``-th choice): reshaped to ``(k, T, D)`` the sum over a token's
-# choices runs over the major axis, whole (T, D) slabs added, where ``(T, k,
-# D)`` would pad ``k`` up to the sublane tile.
+# ``t``'s ``c``-th choice), so a sorted row's token is ``perm % T``, and the
+# sum over a token's choices adds whole (T, D) slabs.
+
+# A chunk holds the held share of the T*k assignments with this much room
+# over it, rounded up to the grouped product's row tile.
+_HEADROOM = (4, 3)
+_ROW_TILE = 512
 
 
-@jax.custom_vjp
-def _dispatch(h, perm, inv, held):
-    """Rows of ``h (T, D)`` in sorted-assignment order ``(k*T, D)``."""
-    return jnp.take(h, perm % h.shape[0], axis=0)
+def _row_bound(n_rows: int, n_held: int, n_experts: int) -> int:
+    """``R``, the rows of one chunk of the sorted order, from shapes alone.
+    With every expert held it is ``n_rows``: one chunk, the whole order."""
+    num, den = _HEADROOM
+    share = -(-n_rows * n_held * num // (n_experts * den))
+    return min(n_rows, -(-share // _ROW_TILE) * _ROW_TILE)
 
 
-def _dispatch_fwd(h, perm, inv, held):
-    return _dispatch(h, perm, inv, held), (inv, held)
+def _n_chunks(group_sizes, rows):
+    return (group_sizes.sum() + rows - 1) // rows
 
 
-def _dispatch_bwd(res, dxs):
-    inv, held = res
-    # Back in (choice, token) order; rows of absent experts were never
-    # computed by the grouped product, so they are masked, not trusted.
-    d = jnp.take(dxs, inv, axis=0).reshape(*held.shape, dxs.shape[-1])
-    d = jnp.where(held[..., None], d.astype(jnp.float32), 0.0).sum(axis=0)
-    return d.astype(dxs.dtype), None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _combine(y, w, held, perm, inv):
-    """``out[t] = sum_c w[c, t] * y[row of (c, t)]`` over held choices, in
-    float32; ``y (k*T, D)`` is in sorted order, ``w``/``held`` ``(k, T)``."""
-    yk = jnp.take(y, inv, axis=0).reshape(*w.shape, -1).astype(jnp.float32)
-    return jnp.where(held[..., None], yk * w[..., None], 0.0).sum(axis=0)
-
-
-def _combine_fwd(y, w, held, perm, inv):
-    return _combine(y, w, held, perm, inv), (y, w, held, perm, inv)
-
-
-def _combine_bwd(res, dout):
-    y, w, held, perm, inv = res
-    yk = jnp.take(y, inv, axis=0).reshape(*w.shape, -1).astype(jnp.float32)
-    dw = jnp.where(held, (yk * dout[None]).sum(axis=-1), 0.0)
-    dyk = jnp.where(held[..., None], dout[None] * w[..., None], 0.0)
-    dy = jnp.take(
-        dyk.reshape(-1, dyk.shape[-1]).astype(y.dtype), perm, axis=0
+def _chunk(i, rows, perm, inv, group_sizes):
+    """Chunk ``i`` of the sorted order, its rows ``[i * rows, (i + 1) *
+    rows)``: the assignment each row holds (``perm``'s slice), the group
+    sizes clipped to the chunk, and for every (choice, token) the row of
+    the chunk that holds it (``at``, clamped) and whether one does."""
+    lo = i * rows
+    ends = jnp.cumsum(group_sizes)
+    sizes = jnp.clip(
+        jnp.minimum(ends, lo + rows) - jnp.maximum(ends - group_sizes, lo), 0
     )
-    return dy, dw.astype(w.dtype), None, None, None
+    pos = inv - lo
+    # The held rows are the first ``ends[-1]`` of the order; the grouped
+    # products never compute the others, so no sum may read them.
+    ok = (pos >= 0) & (pos < rows) & (inv < ends[-1])
+    return (
+        jax.lax.dynamic_slice(perm, (lo,), (rows,)), sizes,
+        jnp.clip(pos, 0, rows - 1), ok,
+    )
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+def _experts(xs, e_gate, e_up, e_down, sizes):
+    with jax.named_scope("experts"):
+        gated = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, sizes))
+        up = jax.lax.ragged_dot(xs, e_up, sizes)
+        return jax.lax.ragged_dot(gated * up, e_down, sizes)
+
+
+def _per_token(table, at, ok, w=None):
+    """``sum_c table[at[c, t]]`` (times ``w[c, t]``) over the choices with
+    ``ok[c, t]``: ``(T, D)`` float32 from a chunk's ``(R, D)`` rows."""
+    out = 0.0
+    for c in range(at.shape[0]):
+        picked = jnp.take(table, at[c], axis=0, mode="clip")
+        picked = picked.astype(jnp.float32)
+        if w is not None:
+            picked = picked * w[c][:, None]
+        out = out + jnp.where(ok[c][:, None], picked, 0.0)
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sorted_side(rows, h, e_gate, e_up, e_down, w, perm, inv, group_sizes):
+    """``out[t] = sum_c w[c, t] * FFN_{expert of (c, t)}(h[t])`` over the
+    held choices, ``(T, D)`` float32, in ``ceil(M / rows)`` chunks of the
+    sorted order (``M = group_sizes.sum()``, read on the device).  ``w``,
+    ``inv`` ``(k, T)``; ``perm`` padded by ``rows``."""
+    t = h.shape[0]
+
+    def step(i, out):
+        flat, sizes, at, ok = _chunk(i, rows, perm, inv, group_sizes)
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(h, flat % t, axis=0, mode="clip")
+        y = _experts(xs, e_gate, e_up, e_down, sizes)
+        with jax.named_scope("combine"):
+            return out + _per_token(y, at, ok, w)
+
+    # The first chunk outside the loop: with one chunk, the usual case,
+    # nothing is carried through a loop that does not run.
+    return jax.lax.fori_loop(
+        1, _n_chunks(group_sizes, rows), step,
+        step(0, jnp.zeros(h.shape, jnp.float32)),
+    )
+
+
+def _sorted_side_fwd(rows, *args):
+    return _sorted_side(rows, *args), args
+
+
+def _sorted_side_bwd(rows, res, dout):
+    # The trip count is read from the routing, and a loop of unknown
+    # length has no transpose: the backward is the same loop, written out,
+    # with each chunk's own backward (jax.vjp) inside.
+    h, e_gate, e_up, e_down, w, perm, inv, group_sizes = res
+    t = h.shape[0]
+
+    def step(i, dh, dw):
+        flat, sizes, at, ok = _chunk(i, rows, perm, inv, group_sizes)
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(h, flat % t, axis=0, mode="clip")
+        y, pull = jax.vjp(
+            lambda *a: _experts(*a, sizes), xs, e_gate, e_up, e_down
+        )
+        with jax.named_scope("combine"):
+            g = jnp.take(dout, flat % t, axis=0, mode="clip")
+            w_rows = jnp.take(w.reshape(-1), flat, mode="clip")
+            dy = (g * w_rows[:, None]).astype(y.dtype)
+            dw_rows = (y.astype(jnp.float32) * g).sum(axis=-1)
+            dw = dw + jnp.where(ok, jnp.take(dw_rows, at, mode="clip"), 0.0)
+        dxs, *d_experts = pull(dy)
+        with jax.named_scope("dispatch"):
+            dh = dh + _per_token(dxs, at, ok)
+        return dh, tuple(d_experts), dw
+
+    def body(i, carry):
+        dh, d_experts, dw = carry
+        dh, more, dw = step(i, dh, dw)
+        return dh, jax.tree.map(jnp.add, d_experts, more), dw
+
+    zeros = jnp.zeros(h.shape, jnp.float32), jnp.zeros(w.shape, jnp.float32)
+    dh, d_experts, dw = jax.lax.fori_loop(
+        1, _n_chunks(group_sizes, rows), body, step(0, *zeros)
+    )
+    return (
+        dh.astype(h.dtype), *d_experts, dw.astype(w.dtype), None, None, None
+    )
+
+
+_sorted_side.defvjp(_sorted_side_fwd, _sorted_side_bwd)
 
 
 def routed_experts(
@@ -99,10 +202,16 @@ def routed_experts(
     scores are normalised over all ``top_k`` (held or not) and multiplied
     by ``scale``.
 
+    The sorted side runs in ``ceil(M / R)`` row chunks, ``M`` the
+    assignments to held experts and ``R`` a bound from the shapes (module
+    docstring): the cost follows the rows held, not ``T * top_k``.
+
     ``stats``: ``scores (T, E)`` and ``selected (T, top_k)`` for a
     family's balance loss, ``group_sizes (Eh,)`` the assignments each held
-    expert received, ``local_assignments`` their sum, and
-    ``load_max_over_mean``, the busiest held expert over the held mean.
+    expert received, ``local_assignments`` their sum ``M``,
+    ``load_max_over_mean``, the busiest held expert over the held mean,
+    and ``row_chunks``, ``ceil(M / R)``: the chunks that held a row (one
+    while the routing stays within the room ``R`` leaves).
     """
     t, n_held = h.shape[0], e_gate.shape[0]
     with jax.named_scope("router"):
@@ -133,20 +242,18 @@ def routed_experts(
         group_sizes = (
             key[:, None] == jnp.arange(n_held, dtype=key.dtype)[None, :]
         ).sum(axis=0, dtype=jnp.int32)
-        xs = _dispatch(h, perm, inv, held)
+        rows = _row_bound(t * top_k, n_held, router_w.shape[1])
 
-    with jax.named_scope("experts"):
-        gated = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, group_sizes))
-        up = jax.lax.ragged_dot(xs, e_up, group_sizes)
-        y = jax.lax.ragged_dot(gated * up, e_down, group_sizes)
-
-    with jax.named_scope("combine"):
-        out = _combine(y, w.T, held, perm, inv).astype(h.dtype)
+    out = _sorted_side(
+        rows, h, e_gate, e_up, e_down, w.T, jnp.pad(perm, (0, rows)),
+        inv.reshape(top_k, t), group_sizes,
+    ).astype(h.dtype)
 
     sizes = group_sizes.astype(jnp.float32)
     stats = {
         "scores": scores, "selected": selected, "group_sizes": group_sizes,
         "local_assignments": sizes.sum(),
         "load_max_over_mean": sizes.max() / jnp.maximum(sizes.mean(), 1e-9),
+        "row_chunks": _n_chunks(group_sizes, rows),
     }
     return out, stats
