@@ -70,3 +70,47 @@ def test_backward_compiles_for_a_v5e(
     )
     assert kernels == backward
     assert "flash_fwd" in text
+
+
+def test_group_of_twenty_on_one_head_takes_the_streamed_pair(one_chip):
+    """AI21-Jamba2-3B's attention layer at the cell's 4,096 positions: 20
+    query heads on ONE key/value head of 128; the group's f32 dq is 40 MiB,
+    past the one kernel's budget, so the backward is the streamed pair."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct(
+            (1, 4096, heads, 128), jnp.bfloat16, sharding=one_chip
+        )
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(spec(20), spec(1), spec(1)).compile().as_text()
+    )
+    assert "flash_fwd" in text and "flash_bwd_fused" not in text
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+@pytest.mark.parametrize("seq,chunk", [(4096, 128), (8192, 128), (4096, 256)])
+def test_selective_scan_kernels_compile_for_a_v5e(one_chip, seq, chunk):
+    """``ssm_scan_fwd`` and ``ssm_scan_bwd`` at AI21-Jamba2-3B's widths
+    (5,120 channels, 16 states, bfloat16): the blocks tile, and the
+    backward's recomputed states fit the VMEM the kernels ask for."""
+    from torchdistx_tpu.ops.pallas import selective_scan as kernels
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide, narrow = spec((1, seq, 5120)), spec((1, seq, 16))
+    a, d = spec((5120, 16), jnp.float32), spec((5120,))
+    forward = jax.jit(
+        lambda *x: kernels.forward(*x, chunk=chunk, interpret=False)
+    ).lower(wide, wide, a, narrow, narrow, d).compile()
+    assert "ssm_scan_fwd" in forward.as_text()
+    starts = spec((1, seq // chunk, 16, 5120), jnp.float32)
+    backward = jax.jit(
+        lambda *x: kernels.backward(*x, chunk=chunk, interpret=False)
+    ).lower(wide, wide, a, narrow, narrow, d, starts, wide).compile()
+    assert "ssm_scan_bwd" in backward.as_text()

@@ -7,8 +7,12 @@ ships JAX-native model families designed for the TPU training stack:
 
 * :mod:`torchdistx_tpu.models.llama` — Llama-2-family decoder (flagship).
 * :mod:`torchdistx_tpu.models.gpt2` — GPT-2 family.
+* :mod:`torchdistx_tpu.models.jamba` — state-space (Mamba-1) layers with
+  an attention layer a period.
 * :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
   — routed-expert families (imported where used).
 """
 
-from . import gpt2, llama  # noqa: F401
+from . import gpt2, jamba, llama  # noqa: F401
+
+__all__ = ["gpt2", "jamba", "llama"]

@@ -33,6 +33,7 @@ __all__ = [
     "gpt2_params_from_hf",
     "llama_params_from_hf",
     "deepseek_v3_params_from_hf",
+    "jamba_params_from_hf",
 ]
 
 
@@ -227,6 +228,64 @@ def deepseek_v3_params_from_hf(arrays: Dict[str, Any], cfg):
         },
         "norm": {"weight": _get(arrays, "norm.weight")},
         "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
+    }
+
+
+def jamba_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat HF Jamba param dict (one expert: ``feed_forward`` is a plain
+    MLP) -> the period stacks of :mod:`~torchdistx_tpu.models.jamba`.
+    Linears are transposed to ``(in, out)``; the depthwise convolution's
+    ``(C, 1, K)`` weight becomes taps-major ``(K, C)``; ``A_log`` and ``D``
+    keep their shapes.  The head is tied: ``lm_head.weight`` is the
+    embedding and is not read."""
+    mlp = {
+        "mlp_norm": "pre_ff_layernorm", "w_gate": "feed_forward.gate_proj",
+        "w_up": "feed_forward.up_proj", "w_down": "feed_forward.down_proj",
+    }
+    mamba = {
+        "mixer_norm": "input_layernorm", "w_in": "mamba.in_proj",
+        "w_x": "mamba.x_proj", "dt_norm": "mamba.dt_layernorm",
+        "b_norm": "mamba.b_layernorm", "c_norm": "mamba.c_layernorm",
+        "w_dt": "mamba.dt_proj", "w_out": "mamba.out_proj", **mlp,
+    }
+    attn = {
+        "mixer_norm": "input_layernorm", "wq": "self_attn.q_proj",
+        "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+        "wo": "self_attn.o_proj", **mlp,
+    }
+
+    def layer(i, table):
+        out = {}
+        for k, name in table.items():
+            a = _get(arrays, f"layers.{i}.{name}.weight")
+            out[k] = a.T if a.ndim == 2 else a
+        if table is mamba:
+            pre = f"layers.{i}.mamba."
+            out["conv_w"] = _get(arrays, pre + "conv1d.weight")[:, 0, :].T
+            out["conv_b"] = _get(arrays, pre + "conv1d.bias")
+            out["b_dt"] = _get(arrays, pre + "dt_proj.bias")
+            out["a_log"] = _get(arrays, pre + "A_log")
+            out["d"] = _get(arrays, pre + "D")
+        return out
+
+    def stack(trees):
+        return {k: jnp.stack([t[k] for t in trees]) for k in trees[0]}
+
+    period, offset = cfg.attn_period, cfg.attn_offset
+    starts = range(0, cfg.n_layers, period)
+    periods = {"attn": stack([layer(s + offset, attn) for s in starts])}
+    for name, first, n in (
+        ("mamba_a", 0, offset), ("mamba_b", offset + 1, period - offset - 1),
+    ):
+        if n:
+            periods[name] = stack([
+                stack([layer(s + first + j, mamba) for j in range(n)])
+                for s in starts
+            ])
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "periods": periods,
+        "norm": {"weight": _get(arrays, "final_layernorm.weight")},
     }
 
 
