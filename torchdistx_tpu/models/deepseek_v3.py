@@ -25,7 +25,7 @@ by default).  What absent experts would add is left out.
 
 Two stacks, each under one scan: ``dense_layers`` and ``moe_layers``
 (experts ``(L, Eh, D, F)``).  Blocks are rematerialised with
-``flash_attention.REMAT_POLICY``.  ``loss_fn`` returns ``(loss, aux)``
+``ops.remat.REMAT_POLICY``.  ``loss_fn`` returns ``(loss, aux)``
 (``LOSS_HAS_AUX``): ``aux["moe"]`` holds the step's routing counts as
 device scalars, which ``make_train_step`` hands on in its metrics.
 """
@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from ..ops.attention import attention
-from ..ops.pallas.flash_attention import REMAT_POLICY
+from ..ops.remat import REMAT_POLICY
 from ..ops.routed_experts import routed_experts
 from . import llama as llama_mod
 

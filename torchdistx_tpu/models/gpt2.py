@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
-from ..ops.pallas.flash_attention import REMAT_POLICY
+from ..ops.remat import REMAT_POLICY
 
 __all__ = [
     "GPT2Config",

@@ -27,10 +27,14 @@ Parameters are stacked by PERIOD (``n_layers = P * attn_period``):
 ``periods = {mamba_a (P, attn_offset, ...), attn (P, ...), mamba_b (P,
 attn_period - attn_offset - 1, ...)}``, each layer's feed-forward and norms
 beside its mixer; one scan over the periods, and inside it a scan over each
-Mamba stack.  Blocks are rematerialised with
-``flash_attention.REMAT_POLICY`` (a block's input and the flash kernel's
-``out``/``lse`` are kept; a Mamba block keeps its input only, so its
-backward runs ``ssm_scan_fwd`` again before ``ssm_scan_bwd``).
+Mamba stack.  Blocks are rematerialised with ``ops.remat.REMAT_POLICY``:
+beside its input a block keeps what the kernels it ran name — the
+attention block the flash kernel's ``out``/``lse``, a Mamba block the
+scan's ``y`` and chunk-start states (``ssm_out`` ``(B, T, d_inner)`` in
+the model's dtype, ``ssm_starts`` ``(B, T/chunk, N, d_inner)`` float32;
+52.4 MB a layer at 4,096 x 5,120 x 16) — so the backward recomputes the
+projections, the convolution and the feed-forward, and runs each forward
+kernel once a layer.
 
 Scopes: ``mamba`` (the whole mixer) with ``in_proj``, ``conv``,
 ``ssm_params``, ``scan``, ``out_proj`` under it; ``attn``, ``mlp``,
@@ -57,7 +61,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from ..ops.attention import attention
-from ..ops.pallas.flash_attention import REMAT_POLICY
+from ..ops.remat import REMAT_POLICY
 from ..ops.selective_scan import resolve_impl, selective_scan
 from . import llama as llama_mod
 

@@ -15,7 +15,7 @@ partitioner, not a port of any torch module:
   ``PartitionSpec`` pytree (Megatron-style TP + ZeRO-style FSDP dims);
   the forward is sharding-agnostic and XLA inserts the collectives.
 * **Selective remat**: ``cfg.remat`` wraps the scanned block in
-  ``jax.checkpoint`` with ``flash_attention.REMAT_POLICY``.  Saved per
+  ``jax.checkpoint`` with ``ops.remat.REMAT_POLICY``.  Saved per
   layer: the block's input and, where the Pallas flash kernel runs, its
   output ``flash_out`` (B, S, H*D) and log-sum-exp ``flash_lse`` (B, H, S)
   — B*S*H*(D*itemsize + 4) bytes.  Recomputed in the backward pass: the
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention
-from ..ops.pallas.flash_attention import REMAT_POLICY
+from ..ops.remat import REMAT_POLICY
 
 __all__ = [
     "LlamaConfig",

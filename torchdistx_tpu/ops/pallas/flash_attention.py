@@ -58,7 +58,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ... import telemetry as _telemetry
 
 __all__ = [
-    "REMAT_POLICY", "flash_attention", "flash_attention_sharded", "shardable",
+    "flash_attention", "flash_attention_sharded", "shardable",
 ]
 
 # Finite "minus infinity": keeps the online-softmax recurrences NaN-free for
@@ -969,16 +969,16 @@ def _fa_backward_streamed(
 # the result in the model's (B, S_pad, Hq*D)
 
 
-# What a rematerialised block keeps of this kernel: ``jax.checkpoint(block,
-# policy=REMAT_POLICY)`` saves the forward kernel's two results beside the
-# block's input, so the backward pass recomputes q, k and v (the backward
-# kernels read them) and never ``flash_fwd``.  Per layer that is
+# What a rematerialised block keeps of this kernel: ``_fa_fwd`` names the
+# forward kernel's two results ``flash_out`` and ``flash_lse``, and
+# ``jax.checkpoint(block, policy=ops.remat.REMAT_POLICY)`` saves both
+# beside the block's input, so the backward pass recomputes q, k and v (the
+# backward kernels read them) and never ``flash_fwd``.  Per layer that is
 # B*S_pad*Hq*(D*itemsize + 4) bytes: the output in the compute dtype and
 # one float32 log-sum-exp per query row.  Where the names never appear
-# (jnp or ring attention, serving) the policy saves nothing.
-REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    "flash_out", "flash_lse"
-)
+# (jnp or ring attention, serving) the policy saves nothing.  The policy
+# lives in ``ops/remat.py`` because it is every kernel's: the selective
+# scan names its own two results there too.
 
 
 def _rows(x):

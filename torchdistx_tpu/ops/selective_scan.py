@@ -40,7 +40,19 @@ forward keeps the state at each chunk's start, ``(Bsz, T/chunk, N, C)``
 float32 — 21 MB a layer at 8,192 x 5,120 x 16 in chunks of 128, where all
 states would be 2.7 GB — and the backward walks the chunks in reverse,
 recomputes a chunk's states from its start and carries ``dh`` across.
-ONE ``jax.custom_vjp`` for both implementations:
+
+What the forward keeps, and for whom.  For its own backward: the six
+arguments and the chunk-start states.  For a rematerialised block around
+it: the VJP's forward rule names its two results, ``ssm_out`` (``y``, the
+model's dtype) and ``ssm_starts`` (the chunk-start states, float32), and
+:data:`torchdistx_tpu.ops.remat.REMAT_POLICY` saves both, so the block's
+backward recomputes the scan's ARGUMENTS (the projections, convolution and
+norms before it) and never the scan: the forward runs once a layer.  (The
+backward rule itself never reads ``y``; the gate and ``out_proj`` after
+the scan do.)  The bytes that costs a layer are in ``ops/remat.py``.
+
+ONE ``jax.custom_vjp`` for both implementations, so both keep the same two
+arrays:
 
 * ``impl="jnp"``: a ``lax.scan`` over chunks of a ``lax.scan`` over
   positions, the backward ``jax.vjp`` of the chunk's function.  The CPU
@@ -60,6 +72,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry as _telemetry
 
@@ -150,6 +163,9 @@ def _forward(u, dt, a, b, c, d, chunk, impl, interpret):
     return y.astype(u.dtype), starts
 
 
+# The primal: runs only where nothing differentiates the call.  Under
+# ``jax.grad``/``jax.vjp`` JAX traces ``_scan_fwd`` in its place, so a name
+# given here would never reach a remat policy.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _scan(u, dt, a, b, c, d, chunk, impl, interpret):
     return _forward(u, dt, a, b, c, d, chunk, impl, interpret)[0]
@@ -157,6 +173,12 @@ def _scan(u, dt, a, b, c, d, chunk, impl, interpret):
 
 def _scan_fwd(u, dt, a, b, c, d, chunk, impl, interpret):
     y, starts = _forward(u, dt, a, b, c, d, chunk, impl, interpret)
+    # The forward's two results carry the names ``ops.remat.REMAT_POLICY``
+    # saves.  The named ``y`` is the VJP's result and the named ``starts``
+    # the residual: were either another variable than the saved one, the
+    # recompute would keep the forward kernel alive to produce it.
+    y = checkpoint_name(y, "ssm_out")
+    starts = checkpoint_name(starts, "ssm_starts")
     return y, (u, dt, a, b, c, d, starts)
 
 
