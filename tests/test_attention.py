@@ -783,3 +783,179 @@ def test_noncausal_padded_grads_finite():
     )(q, k, v)
     for arr in g:
         assert bool(jnp.isfinite(arr).all())
+
+
+class TestFlashWindow:
+    """``window=W``: key ``j`` is visible to query ``t`` iff ``0 <= t - j <
+    W``.  The banded kernels (``flash_win_*``) against the masked ``jnp``
+    attention, the band's extent, and the plain kernels left as they were."""
+
+    BACKWARDS = {
+        # name: (q block, kv block, room for dq in VMEM) -> the counter
+        "nk1": (None, None, None, "win_fused_nk1"),
+        "fused": (64, 64, None, "win_fused"),
+        "pair": (64, 64, 0, "win_pair"),
+        "fused_tall": (128, 64, None, "win_fused"),
+        "pair_wide": (64, 128, 0, "win_pair"),
+    }
+
+    def _blocks(self, monkeypatch, backward):
+        from torchdistx_tpu.ops.pallas import flash_attention as fa
+
+        bq, bkv, dq_vmem, counted = self.BACKWARDS[backward]
+        for name in ("_FWD_BLOCK_Q", "_BWD_BLOCK_Q"):
+            monkeypatch.setattr(fa, name, bq)
+        for name in ("_FWD_BLOCK_KV", "_BWD_BLOCK_KV"):
+            monkeypatch.setattr(fa, name, bkv)
+        if dq_vmem is not None:
+            monkeypatch.setattr(fa, "_FUSED_BWD_DQ_VMEM", dq_vmem)
+        return counted
+
+    # a multiple of the 64-blocks, not a multiple, smaller than a block
+    @pytest.mark.parametrize("window", [128, 80, 20])
+    @pytest.mark.parametrize("backward", list(BACKWARDS))
+    @pytest.mark.parametrize("groups,seq", [(1, 200), (8, 256)])  # 200: padded
+    def test_window_kernels_match_the_mask(
+        self, window, backward, groups, seq, monkeypatch
+    ):
+        """Forward, dq, dk and dv of every backward kernel, over several q
+        and kv blocks (``nk1``: the one kv block), against the explicit
+        ``(T, T)`` mask."""
+        counted = self._blocks(monkeypatch, backward)
+        ks = jax.random.split(jax.random.PRNGKey(seq + window), 4)
+        q = jax.random.normal(ks[0], (1, seq, groups, 16))
+        k = jax.random.normal(ks[1], (1, seq, 1, 16))
+        v = jax.random.normal(ks[2], (1, seq, 1, 16))
+        w = jax.random.normal(ks[3], (1, seq, groups, 16))
+        flash = functools.partial(flash_attention, interpret=True, window=window)
+        masked = functools.partial(mha_reference, window=window)
+        assert jnp.allclose(flash(q, k, v), masked(q, k, v), atol=2e-5)
+        got, built = _flash_bwd_built(lambda: _grads(flash, q, k, v, w))
+        assert built == {counted: 1}
+        for name, g, r in zip("qkv", got, _grads(masked, q, k, v, w)):
+            assert jnp.allclose(g, r, atol=1e-4), name
+        # ... and the mask is not the plain triangle's
+        assert not jnp.allclose(masked(q, k, v), mha_reference(q, k, v), atol=1e-3)
+
+    @pytest.mark.parametrize("window", [200, 1000])
+    def test_a_window_that_holds_the_sequence_is_plain_causal(
+        self, window, monkeypatch
+    ):
+        """``window >= T``: bit for bit the plain kernels' results, and no
+        banded kernel is built."""
+        self._blocks(monkeypatch, "fused")
+        q, k, v = _qkv(b=1, s=200, hq=4, hkv=2)
+        w = jnp.ones_like(q)
+        plain = functools.partial(flash_attention, interpret=True)
+        wide = functools.partial(plain, window=window)
+        assert (plain(q, k, v) == wide(q, k, v)).all()
+        got, built = _flash_bwd_built(lambda: _grads(wide, q, k, v, w))
+        assert built == {"fused": 1}
+        for g, r in zip(got, _grads(plain, q, k, v, w)):
+            assert (g == r).all()
+
+    def test_window_needs_causal_and_ring_knows_none(self):
+        q, k, v = _qkv()
+        for fn in (flash_attention, mha_reference, attention):
+            kw = {"interpret": True} if fn is flash_attention else {}
+            if fn is not mha_reference:
+                with pytest.raises(ValueError, match="window"):
+                    fn(q, k, v, causal=False, window=8, **kw)
+        with pytest.raises(ValueError, match="window"):
+            attention(q, k, v, window=0, impl="jnp")
+        mesh = make_mesh(MeshSpec(sp=8))
+        with pytest.raises(NotImplementedError, match="window"):
+            attention(q, k, v, window=8, impl="ring", mesh=mesh, seq_axis="sp")
+        assert jnp.allclose(
+            attention(q, k, v, window=8, impl="jnp"),
+            mha_reference(q, k, v, window=8),
+        )
+
+    def test_window_under_a_mesh_runs_per_shard(self):
+        """The shard_map wrapper hands the window on (batch over dp, heads
+        over tp): the band is each shard's own."""
+        from torchdistx_tpu.ops.pallas.flash_attention import (
+            flash_attention_sharded,
+        )
+
+        mesh = make_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])
+        q, k, v = _qkv(b=2, s=96, hq=4, hkv=2)
+        out = flash_attention_sharded(
+            q, k, v, mesh=mesh, interpret=True, window=24
+        )
+        assert jnp.allclose(out, mha_reference(q, k, v, window=24), atol=2e-5)
+
+    @pytest.mark.parametrize("backward", ["fused", "pair"])
+    def test_blocks_outside_the_band_never_run(self, backward, monkeypatch):
+        """8 blocks of 64 positions under a window of 2 blocks: every
+        kernel's streamed grid axis spans the band, at most 3 blocks (the
+        diagonal's, one inside, the lower edge's), not the sequence's 8,
+        forward and backward; the histogram says so too."""
+        from torchdistx_tpu import telemetry
+
+        self._blocks(monkeypatch, backward)
+        groups, seq, window = 2, 512, 128
+        q = jax.ShapeDtypeStruct((1, seq, groups, 16), jnp.float32)
+        kv = jax.ShapeDtypeStruct((1, seq, 1, 16), jnp.float32)
+        h0 = telemetry.histograms().get("attention.window_kv_blocks", {})
+
+        def grids_of(**kw):
+            jaxpr = jax.make_jaxpr(
+                jax.grad(
+                    lambda q, k, v: flash_attention(
+                        q, k, v, interpret=True, **kw
+                    ).sum(),
+                    argnums=(0, 1, 2),
+                )
+            )(q, kv, kv)
+            return {
+                eqn.params["name"]: eqn.params["grid_mapping"].grid
+                for eqn in jaxpr.jaxpr.eqns
+                if eqn.primitive.name == "pallas_call"
+            }
+
+        grids = grids_of(window=window)
+        h1 = telemetry.histograms()["attention.window_kv_blocks"]
+        assert h1["count"] == h0.get("count", 0) + 1 and h1["max"] >= 3
+        # q-major: (batch, q heads, q blocks, kv blocks of the band)
+        assert grids["flash_win_fwd"] == (1, groups, 8, 3)
+        if backward == "pair":
+            assert grids["flash_win_bwd_dq"] == (1, groups, 8, 3)
+            # kv-major: (batch, kv heads, kv blocks, groups x q blocks of
+            # the band)
+            assert grids["flash_win_bwd_dkv"] == (1, 1, 8, groups * 3)
+        else:
+            assert grids["flash_win_bwd_fused"] == (1, 1, 8, groups * 3)
+        # the plain kernels span the sequence
+        assert grids_of()["flash_fwd"] == (1, groups, 8, 8)
+
+    @pytest.mark.parametrize(
+        "seq,hq,hkv,d,pinned",
+        [
+            # the full layer of a 32-on-4 window/full model at 8k: the
+            # streamed pair, as commit 80945ec (PR 32) lowers it
+            (8192, 32, 4, 128, "4b1bf048e1b8d1ca0ce5a187ef079f020c45c3392c3fc1928fb468c1595931c6"),
+        ],
+    )
+    def test_window_none_lowers_as_before(
+        self, seq, hq, hkv, d, pinned, monkeypatch
+    ):
+        """Without a window the kernels are the ones the parent had: the
+        gradient's StableHLO lowered for the TPU, each Mosaic body without
+        its source locations, hashes to what the parent commit gives (the
+        hashes of ``test_equal_widths_lower_to_the_program_of_pr_26`` hold
+        the other two backward kernels), no banded kernel is named, and
+        the three calls take the operands they took."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q = jax.ShapeDtypeStruct((1, seq, hq, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, seq, hkv, d), jnp.bfloat16)
+        grad = jax.grad(
+            lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )
+        text = jax.jit(grad).trace(q, kv, kv).lower(
+            lowering_platforms=("tpu",)
+        ).as_text()
+        assert "flash_win_" not in text
+        assert text.count("tpu_custom_call") == 3
+        assert hashlib.sha256(_without_locations(text).encode()).hexdigest() == pinned

@@ -115,3 +115,51 @@ class TestLlama:
                 do_sample=False,
             )[0, 4:].numpy()
         assert np.array_equal(np.asarray(out)[0], hf_out)
+
+
+class TestAfmoe:
+    """The published AFMoE names (``models/afmoe_torch.py``) -> the stacked
+    layout -> the published names: every key accounted for, an absent
+    expert's key never created."""
+
+    def _module(self, first, held):
+        from torchdistx_tpu.models import afmoe_torch
+
+        torch.manual_seed(0)
+        config = afmoe_torch.AfmoeConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=48,
+            moe_intermediate_size=16, num_hidden_layers=4, num_dense_layers=1,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+            num_experts=8, num_experts_per_tok=2, sliding_window=8,
+        )
+        module = afmoe_torch.AfmoeForCausalLM(config)
+        for layer in module.model.layers[config.num_dense_layers:]:
+            del layer.mlp.experts[first + held:]
+            del layer.mlp.experts[:first]
+        return module, config
+
+    @pytest.mark.parametrize("first,held", [(0, 8), (2, 4)])
+    def test_round_trip_accounts_for_every_published_key(self, first, held):
+        from torchdistx_tpu.models import afmoe
+
+        module, config = self._module(first, held)
+        state = _np_state_dict(module)
+        cfg = afmoe.AfmoeConfig(
+            vocab_size=64, dim=32, n_dense_layers=1, n_moe_layers=3, n_heads=4,
+            n_kv_heads=2, head_dim=8, ffn_dim=48, expert_dim=16, shared_dim=16,
+            n_experts=8, experts_per_token=2, n_experts_held=held,
+            first_expert_held=first, window=8, dtype=jnp.float32,
+        )
+        assert cfg.layer_types == config.layer_types
+        params = convert.afmoe_params_from_hf(state, cfg)
+        assert {
+            k: v.shape for k, v in params["moe_layers"].items()
+        }.items() >= {"e_gate": (3, held, 32, 16), "wg": (3, 32, 32)}.items()
+        back = convert.afmoe_params_to_hf(params, cfg)
+        assert set(back) == set(state)
+        for name, a in state.items():
+            assert np.array_equal(np.asarray(back[name]), a), name
+        assert not any(f"experts.{held}." in name for name in back)
+        # every leaf of the stacked layout came from some published key
+        n = sum(a.size for a in state.values())
+        assert n == afmoe.num_params(cfg)

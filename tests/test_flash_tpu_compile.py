@@ -93,6 +93,46 @@ def test_group_of_twenty_on_one_head_takes_the_streamed_pair(one_chip):
     assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
 
 
+@pytest.mark.parametrize(
+    "window,hq,hkv,kernels",
+    [
+        # Trinity-Mini's window layers at the cell's shape: 32 query heads
+        # on 4 key/value heads of 128, window 2,048 at 8,192 positions; the
+        # group's f32 dq is 32 MiB, so the backward is the streamed pair
+        (2048, 32, 4, ["flash_win_bwd_dkv", "flash_win_bwd_dq", "flash_win_fwd"]),
+        # ... its full layer at the same shape: the plain kernels
+        (None, 32, 4, ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
+        # one head a group: the one-kernel backward through a band
+        (2048, 4, 4, ["flash_win_bwd_fused", "flash_win_fwd"]),
+        # a window off the blocks' grain
+        (1500, 8, 4, ["flash_win_bwd_fused", "flash_win_fwd"]),
+    ],
+)
+def test_window_kernels_compile_for_a_v5e(one_chip, window, hq, hkv, kernels):
+    def spec(heads):
+        return jax.ShapeDtypeStruct(
+            (1, 8192, heads, 128), jnp.bfloat16, sharding=one_chip
+        )
+
+    def loss(q, k, v):
+        out = fa.flash_attention(
+            q, k, v, causal=True, interpret=False, window=window
+        )
+        return out.astype(jnp.float32).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(spec(hq), spec(hkv), spec(hkv)).compile().as_text()
+    )
+    names = (
+        "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv",
+        "flash_win_fwd", "flash_win_bwd_fused", "flash_win_bwd_dq",
+        "flash_win_bwd_dkv",
+    )
+    # no plain name is a part of a window kernel's, nor the other way
+    assert sorted(n for n in names if n in text) == kernels
+
+
 @pytest.mark.parametrize("seq,chunk", [(4096, 128), (8192, 128), (4096, 256)])
 def test_selective_scan_kernels_compile_for_a_v5e(one_chip, seq, chunk):
     """``ssm_scan_fwd`` and ``ssm_scan_bwd`` at AI21-Jamba2-3B's widths

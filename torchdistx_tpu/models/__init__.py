@@ -9,10 +9,14 @@ ships JAX-native model families designed for the TPU training stack:
 * :mod:`torchdistx_tpu.models.gpt2` — GPT-2 family.
 * :mod:`torchdistx_tpu.models.jamba` — state-space (Mamba-1) layers with
   an attention layer a period.
+* :mod:`torchdistx_tpu.models.afmoe` — window and full attention layers
+  mixed, gated attention output, sandwich norms, routed experts beside a
+  shared one (:mod:`torchdistx_tpu.models.afmoe_torch` is the published
+  architecture as a torch module, for the deferred-init path).
 * :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
   — routed-expert families (imported where used).
 """
 
-from . import gpt2, jamba, llama  # noqa: F401
+from . import afmoe, gpt2, jamba, llama  # noqa: F401
 
-__all__ = ["gpt2", "jamba", "llama"]
+__all__ = ["afmoe", "gpt2", "jamba", "llama"]

@@ -34,6 +34,8 @@ __all__ = [
     "llama_params_from_hf",
     "deepseek_v3_params_from_hf",
     "jamba_params_from_hf",
+    "afmoe_params_from_hf",
+    "afmoe_params_to_hf",
 ]
 
 
@@ -287,6 +289,99 @@ def jamba_params_from_hf(arrays: Dict[str, Any], cfg):
         "periods": periods,
         "norm": {"weight": _get(arrays, "final_layernorm.weight")},
     }
+
+
+# AFMoE: leaf of the stacked layout -> published name inside a layer.
+_AFMOE_ATTN = {
+    "attn_norm": "input_layernorm", "wq": "self_attn.q_proj",
+    "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+    "wg": "self_attn.gate_proj", "wo": "self_attn.o_proj",
+    "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm",
+    "post_attn_norm": "post_attention_layernorm",
+    "mlp_norm": "pre_mlp_layernorm", "post_mlp_norm": "post_mlp_layernorm",
+}
+_AFMOE_MLP = {"gate": "gate_proj", "up": "up_proj", "down": "down_proj"}
+_AFMOE_DENSE = {
+    **_AFMOE_ATTN, **{f"w_{k}": f"mlp.{v}" for k, v in _AFMOE_MLP.items()},
+}
+_AFMOE_MOE = {
+    **_AFMOE_ATTN, "router": "mlp.router.gate",
+    **{f"s_{k}": f"mlp.shared_experts.{v}" for k, v in _AFMOE_MLP.items()},
+}
+
+
+def afmoe_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat AFMoE param dict (the published names, as
+    :mod:`~torchdistx_tpu.models.afmoe_torch` has them) -> the two stacks
+    of :mod:`~torchdistx_tpu.models.afmoe` (linears transposed to ``(in,
+    out)``; the experts HELD, ``experts.0 .. experts.{held-1}`` of the
+    module as it stands after the others were dropped, stacked on an expert
+    axis; ``expert_bias`` as ``router_bias``)."""
+
+    def leaf(i, name):
+        a = _get(arrays, f"layers.{i}.{name}.weight")
+        return a.T if a.ndim == 2 else a
+
+    def stack(layers, table):
+        return {
+            k: jnp.stack([leaf(i, name) for i in layers])
+            for k, name in table.items()
+        }
+
+    dense = range(cfg.n_dense_layers)
+    moe = range(cfg.n_dense_layers, cfg.n_layers)
+    dtype = _get(arrays, "norm.weight").dtype
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "dense_layers": stack(dense, _AFMOE_DENSE),
+        "moe_layers": {
+            **stack(moe, _AFMOE_MOE),
+            "router_bias": jnp.stack([
+                _get(arrays, f"layers.{i}.mlp.expert_bias").astype(dtype)
+                for i in moe
+            ]),
+            **{
+                f"e_{k}": jnp.stack([
+                    jnp.stack([
+                        leaf(i, f"mlp.experts.{e}.{v}")
+                        for e in range(cfg.held)
+                    ])
+                    for i in moe
+                ])
+                for k, v in _AFMOE_MLP.items()
+            },
+        },
+        "norm": {"weight": _get(arrays, "norm.weight")},
+        "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
+    }
+
+
+def afmoe_params_to_hf(params, cfg) -> Dict[str, Any]:
+    """The inverse of :func:`afmoe_params_from_hf`: the stacked layout ->
+    ``{published name: array}`` (linears back to ``(out, in)``), the held
+    experts numbered from 0 as in the module after the drop.  An expert
+    that is not held gets no key."""
+    out = {
+        "model.embed_tokens.weight": params["embed"]["weight"],
+        "model.norm.weight": params["norm"]["weight"],
+        "lm_head.weight": params["lm_head"]["weight"].T,
+    }
+
+    def put(name, a):
+        out[f"model.layers.{name}.weight"] = a.T if a.ndim == 2 else a
+
+    for j in range(cfg.n_dense_layers):
+        for k, name in _AFMOE_DENSE.items():
+            put(f"{j}.{name}", params["dense_layers"][k][j])
+    for j in range(cfg.n_moe_layers):
+        i, lp = cfg.n_dense_layers + j, params["moe_layers"]
+        for k, name in _AFMOE_MOE.items():
+            put(f"{i}.{name}", lp[k][j])
+        out[f"model.layers.{i}.mlp.expert_bias"] = lp["router_bias"][j]
+        for k, v in _AFMOE_MLP.items():
+            for e in range(cfg.held):
+                put(f"{i}.mlp.experts.{e}.{v}", lp[f"e_{k}"][j, e])
+    return out
 
 
 def _count_layers(arrays, fmt: str) -> int:

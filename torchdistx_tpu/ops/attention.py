@@ -40,8 +40,12 @@ def _neg_inf(dtype):
     return jnp.finfo(dtype).min
 
 
-def mha_reference(q, k, v, *, causal: bool = True, segment_ids=None):
+def mha_reference(q, k, v, *, causal: bool = True, segment_ids=None,
+                  window: Optional[int] = None):
     """Reference multi-head attention (GQA-aware) in plain jax.numpy.
+
+    ``window=W`` (with ``causal``): key ``j`` is visible to query ``t`` iff
+    ``0 <= t - j < W``, by an explicit ``(Sq, Sk)`` mask.
 
     Shapes: q ``(B, Sq, Hq, D)``; k ``(B, Sk, Hkv, D)``; v ``(B, Sk, Hkv,
     Dv)`` (``Dv`` = ``D`` everywhere but in latent attention) with
@@ -63,6 +67,8 @@ def mha_reference(q, k, v, *, causal: bool = True, segment_ids=None):
         qi = jnp.arange(sq)[:, None]
         ki = jnp.arange(sk)[None, :]
         mask = qi >= ki
+        if window is not None:
+            mask &= qi - ki < window
         logits = jnp.where(mask[None, None, None], logits, _neg_inf(jnp.float32))
     if segment_ids is not None:
         q_seg, k_seg = segment_ids
@@ -210,8 +216,14 @@ def attention(
     mesh=None,
     seq_axis: Optional[str] = None,
     pre_permuted: bool = False,
+    window: Optional[int] = None,
 ):
     """Dispatching attention entry point used by the model stack.
+
+    ``window=W`` (static; needs ``causal``): key ``j`` is visible to query
+    ``t`` iff ``0 <= t - j < W``.  ``jnp`` masks it; ``pallas`` runs the
+    banded kernels (``flash_win_*``), which compute and fetch the band's
+    blocks only; the ring implementations know no window and raise.
 
     ``impl``: ``"auto" | "jnp" | "pallas" | "ring" | "ring_zigzag"``.
     ``auto`` = ring iff ``seq_axis`` is set (sequence/context parallelism);
@@ -226,6 +238,9 @@ def attention(
     select ``"pallas"`` with a mesh — the model forwards pin ``"jnp"``
     under ``pp_axis``.
     """
+    from .pallas.flash_attention import _check_window
+
+    _check_window(window, causal)
     impl = _select_impl(impl, mesh, seq_axis, q.shape, k.shape)
     # The resolved choice, counted per trace: a quiet downgrade of "auto"
     # (jnp where the kernel was expected) shows in the counters.
@@ -235,6 +250,10 @@ def attention(
 
         if mesh is None or seq_axis is None:
             raise ValueError("ring attention needs mesh= and seq_axis=")
+        if window is not None:
+            raise NotImplementedError(
+                "ring attention knows no window; use impl='pallas' or 'jnp'"
+            )
         return ring_attention(
             q, k, v, mesh=mesh, axis=seq_axis, causal=causal,
             schedule="zigzag" if impl == "ring_zigzag" else "contiguous",
@@ -250,17 +269,19 @@ def attention(
         )
 
         if mesh is not None and shardable(mesh, q.shape, k.shape):
-            return flash_attention_sharded(q, k, v, causal=causal, mesh=mesh)
+            return flash_attention_sharded(
+                q, k, v, causal=causal, mesh=mesh, window=window
+            )
         # mesh=None, or an explicit "pallas" opt-in whose shapes don't divide
         # over the mesh: the bare kernel (replicated per chip under a mesh —
         # the long-documented escape hatch for replicated heads/batch).
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window)
     if impl != "jnp":
         raise ValueError(
             f"unknown attention impl: {impl!r} "
             "(expected auto|jnp|pallas|ring|ring_zigzag)"
         )
-    return mha_reference(q, k, v, causal=causal)
+    return mha_reference(q, k, v, causal=causal, window=window)
 
 
 # Mesh axes the shard_map wrapper understands: dp/fsdp shard batch, tp

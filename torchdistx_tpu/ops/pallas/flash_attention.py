@@ -35,6 +35,31 @@ shapes alone choose its kernels (``_fa_backward``; counted per trace as
 
 dk/dv always sum the GQA group reduction in-kernel.
 
+``window=W`` (static, with ``causal``): key ``j`` is visible to query ``t``
+iff ``0 <= t - j < W``.  The kernels are the same bodies with one more
+bound, under names of their own (``flash_win_fwd``, ``flash_win_bwd_fused``,
+``flash_win_bwd_dq``, ``flash_win_bwd_dkv``; counted as
+``attention.flash_window{window=}`` and ``attention.flash_bwd{kernel=
+win_*}``):
+
+* what RUNS: a (q block, kv block) pair that holds a visible pair, i.e.
+  the kv block starts at or before the q block's last row and ends after
+  the first row's lower edge.  Those are an interval of kv blocks a q
+  block (and of q blocks a kv block), and the streamed grid axis of a
+  window call spans that interval, not the sequence: ``ceil(W / block) +
+  1`` steps at square blocks, 3 at 1,024-blocks and ``W`` = 2,048
+  whatever the length (histogram ``attention.window_kv_blocks``);
+* what is MASKED: only a block that crosses the diagonal, the band's
+  lower edge or the padding; a block wholly inside the band keeps the
+  mask-free body;
+* what is FETCHED: the band's blocks; a step past the band's end (the
+  first q blocks meet fewer kv blocks than the axis has steps) is clamped
+  to the last block, so nothing is copied for it.
+
+With ``window=None`` every kernel is traced to the operations it had
+before windows existed; a window that holds the whole sequence is plain
+causal attention and takes the plain kernels.
+
 Sequence lengths are padded to the TPU tile grain (128, or 8 below one
 block); padded keys/queries are masked in-kernel, so any length is accepted.
 The log-sum-exp/delta tensors are carried as ``(B, H, S_pad, 1)`` so their
@@ -167,38 +192,121 @@ def _diag_clamp(causal: bool, bq: int, bkv: int, clamp):
     return lambda qi, ki: jnp.maximum(qi, (ki * bkv) // bq)
 
 
+def _stream_kv(causal, bq, bkv, window):
+    """The kv block a streamed kv axis fetches at ``(step, qi)``: the
+    diagonal's clamp, and with a window the band's first block plus the
+    step, clamped to its last."""
+    if window is None:
+        return _diag_clamp(causal, bq, bkv, jnp.minimum)
+
+    def index(step, qi):
+        first, last = _kv_band(qi, bq=bq, bkv=bkv, window=window)
+        return jnp.minimum(first + step, last)
+
+    return index
+
+
+# Bounds of ``attention.window_kv_blocks``: kv blocks ONE q block of a
+# window forward runs (its streamed grid axis).
+_BAND_BOUNDS = tuple(float(i) for i in range(1, 33))
+
+
+# ---------------------------------------------------------------------------
+# The band of a window call (module docstring).  A (q block, kv block) pair
+# holds a visible pair iff ``q_start + bq - 1 >= k_start`` (the diagonal)
+# AND ``k_start + bkv - 1 > q_start - window`` (the lower edge).  Both are
+# monotone, so the blocks that run are an interval along either axis.
+
+
+def _kv_band(qi, *, bq, bkv, window, mx=jnp.maximum):
+    """``(first, last)`` kv block q block ``qi`` meets (``mx=max`` on
+    Python ints)."""
+    return mx(qi * bq - window + 1, 0) // bkv, (qi * bq + bq - 1) // bkv
+
+
+def _q_band(ki, *, bq, bkv, window, nq, mn=jnp.minimum):
+    """``(first, last)`` q block kv block ``ki`` meets (``mn=min`` on
+    Python ints)."""
+    return (ki * bkv) // bq, mn((ki * bkv + bkv + window - 2) // bq, nq - 1)
+
+
+def _kv_band_steps(nq, **blocks) -> int:
+    """Steps of a streamed kv axis that spans the band."""
+    return max(
+        last - first + 1
+        for first, last in (_kv_band(qi, mx=max, **blocks) for qi in range(nq))
+    )
+
+
+def _q_band_steps(nk, nq, **blocks) -> int:
+    """Steps of a streamed q axis that spans the band."""
+    return max(
+        last - first + 1
+        for first, last in (
+            _q_band(ki, nq=nq, mn=min, **blocks) for ki in range(nk)
+        )
+    )
+
+
+def _band_q_block(idx, ki, *, bq, bkv, nq, window, nqb):
+    """The q block of step ``idx`` of a kv-major grid's inner axis, which
+    runs over (gqa group, q block) pairs: ``(qi, in_range)``.  With a
+    window the axis holds ``nqb`` q blocks a group, counted from the first
+    of kv block ``ki``'s band, and a step past the sequence's last q block
+    is out of range (``in_range`` is None where every step is in it)."""
+    if window is None:
+        return idx % nq, None
+    qi = _q_band(ki, bq=bq, bkv=bkv, window=window, nq=nq)[0] + idx % nqb
+    return qi, qi < nq
+
+
+def _runs(causal, window, q_start, k_start, bq, bkv):
+    """Whether a block pair holds a visible (query, key) pair."""
+    if not causal:
+        return True
+    run = q_start + bq - 1 >= k_start
+    if window is not None:
+        run &= k_start + bkv - 1 + window > q_start
+    return run
+
+
 # ---------------------------------------------------------------------------
 # Forward
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale, causal, bq, bkv, s,
+    *, scale, causal, bq, bkv, s, window=None,
 ):
     import jax.experimental.pallas as pl
 
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     nk = pl.num_programs(3)
+    # With a window the streamed axis counts from the band's first block.
+    ki = step
+    if window is not None:
+        ki = _kv_band(qi, bq=bq, bkv=bkv, window=window)[0] + step
     q_start = qi * bq
     k_start = ki * bkv
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _MASK)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Causal: skip kv blocks entirely above the diagonal.
-    run = (q_start + bq - 1 >= k_start) if causal else True
+    # Causal: skip kv blocks entirely above the diagonal (and, with a
+    # window, entirely below the band).
+    run = _runs(causal, window, q_start, k_start, bq, bkv)
     # Masking is needed only where correctness demands it: the one kv
     # step whose block intersects the causal diagonal (bq ≤ bkv ⇒ at most
-    # one per q block) or carries padded cols.  Everything below runs the
-    # mask-free body — the iota/compare/select passes are ~1/3 of the
+    # one per q block), the band's lower edge, or carries padded cols.
+    # Everything below runs the mask-free body — the iota/compare/select passes are ~1/3 of the
     # per-step VPU element work and ~90% of steps don't need them.  The
     # two bodies are scalar-branched with pl.when (a real Mosaic branch;
     # a lax.cond variant measured slower).
-    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s, bq, window)
 
     def _body(apply_mask):
         # Matmul inputs keep their storage dtype (bf16 on TPU → full MXU
@@ -219,10 +327,17 @@ def _fwd_kernel(
             # not enter l), the causal triangle when the block touches
             # the diagonal.  Padded q ROWS need no mask: their logits are
             # finite (zero-padded q) and their outputs are sliced off.
+            # A row whose part of the band's first block is all masked
+            # leaves ``l`` and ``acc`` wrong there (``exp2(_MASK - _MASK)``
+            # is 1); its own diagonal block, which comes later, rescales
+            # both by ``exp2(_MASK - m)`` = 0.
             kpos = k_start + _iota((bq, bkv), 1)
             keep = kpos < s
             if causal:
-                keep &= (q_start + _iota((bq, bkv), 0)) >= kpos
+                qpos = q_start + _iota((bq, bkv), 0)
+                keep &= qpos >= kpos
+                if window is not None:
+                    keep &= qpos - kpos < window
             logits = jnp.where(keep, logits, _MASK)
 
         # Row statistics computed on (bq, 1) slices: the scratch tiles are
@@ -260,7 +375,7 @@ def _fwd_kernel(
     def _body_plain():
         _body(False)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         l = l_ref[...][:, :1]  # (bq, 1)
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked (padded) rows
@@ -270,7 +385,9 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[...][:, :1] / _LOG2E + jnp.log(l_safe)
 
 
-def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
+def _fa_forward_padded(
+    q, k, v, s, *, causal: bool, interpret: bool, window=None
+):
     """q: (B, Hq, S_pad, D); k: (B, Hkv, S_pad, D); v: (B, Hkv, S_pad, Dv);
     ``s`` = valid length.  ``Dv`` may differ from ``D`` (latent attention:
     192-wide q and k against 128-wide v); the scale is ``D**-0.5``.
@@ -291,10 +408,16 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
     scale = 1.0 / (d**0.5)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv, s=s
+        _fwd_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv, s=s,
+        window=window,
     )
 
-    kv_clamp = _diag_clamp(causal, bq, bkv, jnp.minimum)
+    kv_clamp = _stream_kv(causal, bq, bkv, window)
+    if window is not None:
+        nk = _kv_band_steps(nq, bq=bq, bkv=bkv, window=window)
+        _telemetry.histogram(
+            "attention.window_kv_blocks", _BAND_BOUNDS
+        ).observe(nk)
 
     def kv_index(bi, hi, qi, ki, g=groups):
         return (bi, hi // g, kv_clamp(ki, qi), 0)
@@ -326,7 +449,7 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_win_fwd",
     )(q, k, v)
     return out, lse
 
@@ -337,7 +460,7 @@ def _fa_forward_padded(q, k, v, s, *, causal: bool, interpret: bool):
 
 def _recompute_p(
     q, k, lse, q_start, k_start, *, scale, causal, bq, bkv, s, s_pad,
-    apply_mask=True,
+    apply_mask=True, window=None,
 ):
     """Recompute the softmax block from the saved (natural-log) lse.
 
@@ -365,7 +488,14 @@ def _recompute_p(
         return p
     if causal:
         kpos = k_start + _iota((bq, bkv), 1)
-        keep = (q_start + _iota((bq, bkv), 0)) >= kpos
+        qpos = q_start + _iota((bq, bkv), 0)
+        keep = qpos >= kpos
+        if window is not None:
+            # A padded q row that sees no key at all has an lse near
+            # ``_MASK`` and p = inf here; it only ever meets masked
+            # blocks (a mask-free block is visible to all its rows), and
+            # the select drops it.
+            keep &= qpos - kpos < window
         p = jnp.where(keep, p, 0.0)
     elif s_pad > s:
         kpos = k_start + _iota((bq, bkv), 1)
@@ -373,19 +503,23 @@ def _recompute_p(
     return p
 
 
-def _needs_mask(causal, q_start, k_start, bkv, s):
+def _needs_mask(causal, q_start, k_start, bkv, s, bq=None, window=None):
     """Block-level mask condition shared by fwd and bwd kernels: the kv
     block crosses the causal diagonal for this q block, or carries padded
-    cols.  (Worst causal pair: first q row vs last kv col.)"""
+    cols, or (with a window) crosses the band's lower edge.  (Worst causal
+    pair: first q row vs last kv col; worst pair of the band: last q row
+    vs first kv col.)"""
     needs = k_start + bkv > s
     if causal:
         needs |= k_start + bkv - 1 > q_start
+        if window is not None:
+            needs |= q_start + bq - 1 - k_start >= window
     return needs
 
 
 def _p_ds(
     q, k, v, do, lse, delta, q_start, k_start,
-    *, scale, causal, bq, bkv, s, s_pad, apply_mask,
+    *, scale, causal, bq, bkv, s, s_pad, apply_mask, window=None,
 ):
     """The shared backward block chain: recomputed softmax ``p`` and the
     logit gradient ``ds = p ∘ (do·vᵀ − Δ)·scale`` (cast to the matmul
@@ -396,7 +530,7 @@ def _p_ds(
     p = _recompute_p(
         q, k, lse, q_start, k_start,
         scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
-        apply_mask=apply_mask,
+        apply_mask=apply_mask, window=window,
     )
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -408,22 +542,25 @@ def _p_ds(
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, scale, causal, bq, bkv, s, s_pad,
+    *, scale, causal, bq, bkv, s, s_pad, window=None,
 ):
     import jax.experimental.pallas as pl
 
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     nk = pl.num_programs(3)
+    ki = step  # with a window: counted from the band's first block
+    if window is not None:
+        ki = _kv_band(qi, bq=bq, bkv=bkv, window=window)[0] + step
     q_start = qi * bq
     k_start = ki * bkv
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = (q_start + bq - 1 >= k_start) if causal else True
-    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+    run = _runs(causal, window, q_start, k_start, bq, bkv)
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s, bq, window)
 
     def _body(apply_mask):
         # bf16 matmul inputs + f32 accumulation (see _fwd_kernel note).
@@ -431,7 +568,7 @@ def _dq_kernel(
             q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], q_start, k_start,
             scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
-            apply_mask=apply_mask,
+            apply_mask=apply_mask, window=window,
         )
         acc_ref[...] += jax.lax.dot_general(
             ds, k_ref[0, 0], (((1,), (0,)), ((), ())),
@@ -446,21 +583,24 @@ def _dq_kernel(
     def _body_plain():
         _body(False)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, causal, bq, bkv, s, s_pad, nq,
+    dk_acc, dv_acc, *, scale, causal, bq, bkv, s, s_pad, nq, window=None,
+    nqb=None,
 ):
     import jax.experimental.pallas as pl
 
     ki = pl.program_id(2)
     idx = pl.program_id(3)  # (gqa group, q block) pairs
     n_idx = pl.num_programs(3)
-    qi = idx % nq
+    qi, in_range = _band_q_block(
+        idx, ki, bq=bq, bkv=bkv, nq=nq, window=window, nqb=nqb
+    )
     q_start = qi * bq
     k_start = ki * bkv
 
@@ -469,8 +609,10 @@ def _dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (q_start + bq - 1 >= k_start) if causal else True
-    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+    run = _runs(causal, window, q_start, k_start, bq, bkv)
+    if in_range is not None:
+        run &= in_range
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s, bq, window)
 
     def _body(apply_mask):
         # bf16 matmul inputs + f32 accumulation (see _fwd_kernel note).
@@ -480,7 +622,7 @@ def _dkv_kernel(
             q, k_ref[0, 0], v_ref[0, 0], do, lse_ref[0, 0],
             delta_ref[0, 0], q_start, k_start,
             scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
-            apply_mask=apply_mask,
+            apply_mask=apply_mask, window=window,
         )
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -508,7 +650,7 @@ def _dkv_kernel(
 def _dqkv_fused_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
     dv_ref, dk_acc, dv_acc,
-    *, scale, causal, bq, bkv, s, s_pad, nq,
+    *, scale, causal, bq, bkv, s, s_pad, nq, window=None,
 ):
     """Single-kv-block backward (``nk == 1`` — the training regime, where
     S fits one kv block): dq, dk, dv in ONE kernel.
@@ -535,8 +677,9 @@ def _dqkv_fused_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    # The one kv block is the whole sequence: it reaches into every band.
     run = (q_start + bq - 1 >= k_start) if causal else True
-    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s, bq, window)
 
     def _body(apply_mask):
         q = q_ref[0, 0]
@@ -546,7 +689,7 @@ def _dqkv_fused_kernel(
             q, k, v_ref[0, 0], do, lse_ref[0, 0], delta_ref[0, 0],
             q_start, k_start,
             scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
-            apply_mask=apply_mask,
+            apply_mask=apply_mask, window=window,
         )
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -584,7 +727,7 @@ def _dqkv_fused_kernel(
 def _dqkv_stream_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
     dv_ref, dk_acc, dv_acc, dq_acc,
-    *, scale, causal, bq, bkv, s, s_pad, nq,
+    *, scale, causal, bq, bkv, s, s_pad, nq, window=None, nqb=None,
 ):
     """Several kv blocks, still ONE kernel: the grid is ``_dkv_kernel``'s
     (kv block outer, (gqa group, q block) pairs inner), and the whole
@@ -593,6 +736,10 @@ def _dqkv_stream_kernel(
     where the streamed pair runs seven, half its exp2s, and q, do, lse
     and delta read from HBM once.  Each q block's dq sums over the kv
     blocks in f32 in the order ``_dq_kernel`` sums them.
+
+    With a window the inner axis spans the q blocks of this kv block's
+    band: a q block's dq rows are zeroed at the first kv block of ITS band
+    and leave the accumulator at the last.
     """
     import jax.experimental.pallas as pl
 
@@ -600,23 +747,37 @@ def _dqkv_stream_kernel(
     nk = pl.num_programs(2)
     idx = pl.program_id(3)  # (gqa group, q block) pairs
     n_idx = pl.num_programs(3)
-    g = idx // nq
-    qi = idx % nq
+    g = idx // (nq if window is None else nqb)
+    qi, in_range = _band_q_block(
+        idx, ki, bq=bq, bkv=bkv, nq=nq, window=window, nqb=nqb
+    )
     q_start = qi * bq
     k_start = ki * bkv
-    rows = pl.ds(pl.multiple_of(q_start, bq), bq)
+    # When a q block's dq rows are zeroed and when they leave the
+    # accumulator (thunks: traced where the plain kernel traced them).
+    if window is None:
+        rows = pl.ds(pl.multiple_of(q_start, bq), bq)
+        first_kv, last_kv = (lambda: ki == 0), (lambda: ki == nk - 1)
+    else:
+        # A step past the sequence's last q block touches no row.
+        rows = pl.ds(pl.multiple_of(jnp.minimum(qi, nq - 1) * bq, bq), bq)
+        first, last = _kv_band(qi, bq=bq, bkv=bkv, window=window)
+        first_kv = lambda: (ki == first) & in_range  # noqa: E731
+        last_kv = lambda: (ki == last) & in_range  # noqa: E731
 
     @pl.when(idx == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(ki == 0)
+    @pl.when(first_kv())
     def _init_dq():
         dq_acc[g, rows, :] = jnp.zeros((bq, dq_acc.shape[-1]), dq_acc.dtype)
 
-    run = (q_start + bq - 1 >= k_start) if causal else True
-    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s)
+    run = _runs(causal, window, q_start, k_start, bq, bkv)
+    if in_range is not None:
+        run &= in_range
+    needs_mask = _needs_mask(causal, q_start, k_start, bkv, s, bq, window)
 
     def _body(apply_mask):
         q = q_ref[0, 0]
@@ -626,7 +787,7 @@ def _dqkv_stream_kernel(
             q, k, v_ref[0, 0], do, lse_ref[0, 0], delta_ref[0, 0],
             q_start, k_start,
             scale=scale, causal=causal, bq=bq, bkv=bkv, s=s, s_pad=s_pad,
-            apply_mask=apply_mask,
+            apply_mask=apply_mask, window=window,
         )
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -651,7 +812,7 @@ def _dqkv_stream_kernel(
 
     # The dq block is the whole (groups, s_pad) extent of this kv head:
     # it leaves VMEM once, after the last kv block filled it in.
-    @pl.when(ki == nk - 1)
+    @pl.when(last_kv())
     def _finish_dq():
         dq_ref[0, g, rows, :] = dq_acc[g, rows, :].astype(dq_ref.dtype)
 
@@ -661,7 +822,9 @@ def _dqkv_stream_kernel(
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
+def _fa_backward_fused_nk1(
+    q, k, v, delta, lse, do, s, *, causal, interpret, window=None
+):
     """One-kernel backward for ``s_pad <= bkv`` (single kv block)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
@@ -685,9 +848,9 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
         # a 128-row handoff would just run 8× more dq grid iterations.
         return _fa_backward_streamed(
             q, k, v, delta, lse, do, s, causal=causal, interpret=interpret,
-            bkv=_block_for(s_pad),
+            bkv=_block_for(s_pad), window=window,
         )
-    _count_bwd("fused_nk1")
+    _count_bwd("fused_nk1", window)
     nq = s_pad // bq
     scale = 1.0 / (d**0.5)
 
@@ -707,7 +870,7 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _dqkv_fused_kernel, scale=scale, causal=causal, bq=bq,
-            bkv=bkv, s=s, s_pad=s_pad, nq=nq,
+            bkv=bkv, s=s, s_pad=s_pad, nq=nq, window=window,
         ),
         grid=(b, hkv, groups * nq),
         in_specs=[
@@ -728,7 +891,7 @@ def _fa_backward_fused_nk1(q, k, v, delta, lse, do, s, *, causal, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_fused",
+        name=_bwd_name("fused", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -746,17 +909,29 @@ def _vmem_bytes(rows: int, cols: int, dtype) -> int:
     return -(-rows // sub) * sub * _lanes(cols) * item
 
 
-def _count_bwd(kernel: str):
+def _count_bwd(kernel: str, window=None):
     # Counted per trace, where the kernel is built: which backward a shape
-    # took (``fused_nk1``, ``fused`` or ``pair``) shows in the counters.
+    # took (``fused_nk1``, ``fused`` or ``pair``; ``win_fused_nk1``,
+    # ``win_fused`` or ``win_pair`` with a window) shows in the counters.
+    if window is not None:
+        kernel = "win_" + kernel
     _telemetry.counter("attention.flash_bwd", kernel=kernel).add()
 
 
-def _fa_backward(q, k, v, delta, lse, do, s, *, causal, interpret):
+def _bwd_name(kernel: str, window) -> str:
+    """A backward kernel's name in the program and the trace; the window
+    kernels carry a prefix of their own."""
+    return ("flash_bwd_" if window is None else "flash_win_bwd_") + kernel
+
+
+def _fa_backward(
+    q, k, v, delta, lse, do, s, *, causal, interpret, window=None
+):
     """The backward for these shapes, chosen from the shapes alone: one kv
     block -> ``_fa_backward_fused_nk1``; several, and the sequence's f32 dq
     within ``_FUSED_BWD_DQ_VMEM`` -> ``_fa_backward_fused``; past that the
-    streamed pair."""
+    streamed pair.  A window changes what the chosen kernels run, not the
+    choice."""
     _, hq, s_pad, d = q.shape
     # Whole kv extent in one block → the single-block kernel.  An explicit
     # smaller kv-block override (sweeps/tests) asks for several blocks.
@@ -770,23 +945,34 @@ def _fa_backward(q, k, v, delta, lse, do, s, *, causal, interpret):
     else:
         backward = _fa_backward_streamed
     return backward(
-        q, k, v, delta, lse, do, s, causal=causal, interpret=interpret
+        q, k, v, delta, lse, do, s, causal=causal, interpret=interpret,
+        window=window,
     )
 
 
-def _kv_major_specs(*, bq, bkv, nq, groups, causal):
+def _kv_major_specs(*, bq, bkv, nq, groups, causal, window=None, nqb=None):
     """Block specs of a grid ``(b, hkv, kv block, (gqa group, q block))``:
     ``gq_spec(width)`` for what a query row carries (q, do, lse, delta),
     its q axis clamped to the causal diagonal, and ``kv_spec(width)`` for
-    k, v, dk and dv."""
+    k, v, dk and dv.  With a window the inner axis holds ``nqb`` q blocks
+    a group, counted from the first of the kv block's band and clamped to
+    its last."""
     import jax.experimental.pallas as pl
 
-    q_block = _diag_clamp(causal, bq, bkv, jnp.maximum)
+    if window is None:
+        steps = nq  # the inner axis' q blocks a group
+        q_block = _diag_clamp(causal, bq, bkv, jnp.maximum)
+    else:
+        steps = nqb
+
+        def q_block(step, ki):
+            first, last = _q_band(ki, bq=bq, bkv=bkv, window=window, nq=nq)
+            return jnp.minimum(first + step, last)
 
     def gq_spec(width):
         return pl.BlockSpec(
             (1, 1, bq, width),
-            lambda bi, hkvi, ki, idx, g=groups, n=nq: (
+            lambda bi, hkvi, ki, idx, g=groups, n=steps: (
                 bi, hkvi * g + idx // n, q_block(idx % n, ki), 0
             ),
         )
@@ -799,12 +985,14 @@ def _kv_major_specs(*, bq, bkv, nq, groups, causal):
     return gq_spec, kv_spec
 
 
-def _fa_backward_fused(q, k, v, delta, lse, do, s, *, causal, interpret):
+def _fa_backward_fused(
+    q, k, v, delta, lse, do, s, *, causal, interpret, window=None
+):
     """One-kernel backward for several kv blocks (``_dqkv_stream_kernel``)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    _count_bwd("fused")
+    _count_bwd("fused", window)
     b, hq, s_pad, d = q.shape
     dv = v.shape[-1]
     hkv = k.shape[1]
@@ -812,8 +1000,9 @@ def _fa_backward_fused(q, k, v, delta, lse, do, s, *, causal, interpret):
     bq = _pick_block(s_pad, _BWD_BLOCK_Q, _BWD_BLOCK_Q_DEFAULT)
     bkv = _pick_block(s_pad, _BWD_BLOCK_KV, _BWD_BLOCK_KV_DEFAULT)
     nq, nk = s_pad // bq, s_pad // bkv
+    band = _q_band_args(nk, nq, bq, bkv, window)
     gq_spec, kv_spec = _kv_major_specs(
-        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal
+        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal, **band
     )
     dq_spec = pl.BlockSpec(
         (1, groups, s_pad, d), lambda bi, hkvi, ki, idx: (bi, hkvi, 0, 0)
@@ -840,9 +1029,9 @@ def _fa_backward_fused(q, k, v, delta, lse, do, s, *, causal, interpret):
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _dqkv_stream_kernel, scale=1.0 / (d**0.5), causal=causal, bq=bq,
-            bkv=bkv, s=s, s_pad=s_pad, nq=nq,
+            bkv=bkv, s=s, s_pad=s_pad, nq=nq, **band,
         ),
-        grid=(b, hkv, nk, groups * nq),
+        grid=(b, hkv, nk, groups * band.get("nqb", nq)),
         in_specs=[
             gq_spec(d), kv_spec(d), kv_spec(dv), gq_spec(dv), gq_spec(1),
             gq_spec(1),
@@ -867,13 +1056,26 @@ def _fa_backward_fused(q, k, v, delta, lse, do, s, *, causal, interpret):
             vmem_limit_bytes=2 * blocks + scratch + temporaries,
         ),
         interpret=interpret,
-        name="flash_bwd_fused",
+        name=_bwd_name("fused", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
+def _q_band_args(nk, nq, bq, bkv, window) -> dict:
+    """What a kv-major kernel and its specs are told of a window: nothing
+    without one, else the window and the q blocks a group its inner axis
+    holds."""
+    if window is None:
+        return {}
+    return {
+        "window": window,
+        "nqb": _q_band_steps(nk, nq, bq=bq, bkv=bkv, window=window),
+    }
+
+
 def _fa_backward_streamed(
-    q, k, v, delta, lse, do, s, *, causal, interpret, bq=None, bkv=None
+    q, k, v, delta, lse, do, s, *, causal, interpret, bq=None, bkv=None,
+    window=None,
 ):
     """The streamed two-kernel backward (dq kernel + dk/dv kernel), kv as
     a grid axis.  ``bq``/``bkv`` are normally derived from the sweep
@@ -882,7 +1084,7 @@ def _fa_backward_streamed(
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    _count_bwd("pair")
+    _count_bwd("pair", window)
     b, hq, s_pad, d = q.shape
     dv = v.shape[-1]
     hkv = k.shape[1]
@@ -899,7 +1101,11 @@ def _fa_backward_streamed(
             (1, 1, bq, width), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
         )
 
-    kv_clamp = _diag_clamp(causal, bq, bkv, jnp.minimum)
+    kv_clamp = _stream_kv(causal, bq, bkv, window)
+    # With a window the dq kernel's streamed axis spans the band.
+    kv_steps = nk if window is None else _kv_band_steps(
+        nq, bq=bq, bkv=bkv, window=window
+    )
 
     def kv_spec(width):
         return pl.BlockSpec(
@@ -913,9 +1119,9 @@ def _fa_backward_streamed(
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv, s=s,
-            s_pad=s_pad,
+            s_pad=s_pad, window=window,
         ),
-        grid=(b, hq, nq, nk),
+        grid=(b, hq, nq, kv_steps),
         in_specs=[
             q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv), q_spec(1),
             q_spec(1),
@@ -927,21 +1133,22 @@ def _fa_backward_streamed(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_bwd_name("dq", window),
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid over kv blocks with the (group, q-block) reduction as the
     # innermost axis — the GQA head-group sum happens in the accumulator.
+    band = _q_band_args(nk, nq, bq, bkv, window)
     gq_spec, kv_out_spec = _kv_major_specs(
-        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal
+        bq=bq, bkv=bkv, nq=nq, groups=groups, causal=causal, **band
     )
 
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv, s=s,
-            s_pad=s_pad, nq=nq,
+            s_pad=s_pad, nq=nq, **band,
         ),
-        grid=(b, hkv, nk, groups * nq),
+        grid=(b, hkv, nk, groups * band.get("nqb", nq)),
         in_specs=[
             gq_spec(d), kv_out_spec(d), kv_out_spec(dv), gq_spec(dv),
             gq_spec(1), gq_spec(1),
@@ -959,7 +1166,7 @@ def _fa_backward_streamed(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_bwd_name("dkv", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -993,15 +1200,17 @@ def _rows(x):
 # The primal: runs only where nothing differentiates the call (inference,
 # ``jax.eval_shape``).  Under ``jax.grad``/``jax.vjp`` JAX traces ``_fa_fwd``
 # in its place, so a name given here would never reach a remat policy.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _fa(q, k, v, s, causal, interpret):
-    out, _ = _fa_forward_padded(q, k, v, s, causal=causal, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fa(q, k, v, s, causal, interpret, window):
+    out, _ = _fa_forward_padded(
+        q, k, v, s, causal=causal, interpret=interpret, window=window
+    )
     return _rows(out)
 
 
-def _fa_fwd(q, k, v, s, causal, interpret):
+def _fa_fwd(q, k, v, s, causal, interpret, window):
     out, lse = _fa_forward_padded(
-        q, k, v, s, causal=causal, interpret=interpret
+        q, k, v, s, causal=causal, interpret=interpret, window=window
     )
     # The named ``out`` is BOTH the result and the residual: were the
     # residual another array than the one returned, the saved value and
@@ -1014,7 +1223,7 @@ def _fa_fwd(q, k, v, s, causal, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(s, causal, interpret, res, do):
+def _fa_bwd(s, causal, interpret, window, res, do):
     q, k, v, out, lse = res
     b, hq, s_pad, _ = q.shape
     d = v.shape[-1]
@@ -1028,25 +1237,41 @@ def _fa_bwd(s, causal, interpret, res, do):
     ).transpose(0, 2, 1)[..., None]  # (B, Hq, S_pad, 1)
     return _fa_backward(
         q, k, v, delta, lse[..., None], do.transpose(0, 2, 1, 3), s,
-        causal=causal, interpret=interpret,
+        causal=causal, interpret=interpret, window=window,
     )
 
 
 _fa.defvjp(_fa_fwd, _fa_bwd)
 
 
+def _check_window(window, causal):
+    if window is None:
+        return
+    if not causal:
+        raise ValueError("attention: window= needs causal=True")
+    if int(window) < 1:
+        raise ValueError(f"attention: window must be at least 1, got {window}")
+
+
 def flash_attention(
-    q, k, v, *, causal: bool = True, interpret: Optional[bool] = None
+    q, k, v, *, causal: bool = True, interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ):
     """Fused attention.  Layout matches the model stack: ``(B, S, H, D)``.
     ``v`` may have a head width of its own (``(B, S, Hkv, Dv)``, as in
     latent attention): the result is then ``(B, S, Hq, Dv)``.
+
+    ``window=W`` (static, with ``causal``): key ``j`` is visible to query
+    ``t`` iff ``0 <= t - j < W``; the kernels run the band's blocks only
+    (``flash_win_*``).  A window that holds the whole sequence is plain
+    causal attention and runs the plain kernels.
 
     Any sequence length is accepted (padded to the TPU tile grain and masked
     in-kernel).  ``interpret``: force the Pallas interpreter (None = auto:
     interpret on non-TPU backends so the kernel is testable on the CPU mesh
     rig).
     """
+    _check_window(window, causal)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     # Counted per trace: a kernel that ran through the interpreter (and
@@ -1055,6 +1280,10 @@ def flash_attention(
         "attention.flash", interpret=str(bool(interpret)).lower()
     ).add()
     b, s, hq, _ = q.shape
+    if window is not None:
+        window = None if window >= s else int(window)
+    if window is not None:
+        _telemetry.counter("attention.flash_window", window=window).add()
     d = v.shape[-1]
     s_pad = _pad_len(s)
     # Kernel layout is (B, H, S, D).
@@ -1064,7 +1293,7 @@ def flash_attention(
     if s_pad != s:
         pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
         qt, kt, vt = (jnp.pad(t, pad) for t in (qt, kt, vt))
-    out = _fa(qt, kt, vt, s, causal, interpret)  # (B, S_pad, Hq*D)
+    out = _fa(qt, kt, vt, s, causal, interpret, window)  # (B, S_pad, Hq*D)
     return out[:, :s].reshape(b, s, hq, d)
 
 
@@ -1119,6 +1348,7 @@ def flash_attention_sharded(
     batch_axes=("dp", "fsdp"),
     head_axis: str = "tp",
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ):
     """:func:`flash_attention` under a mesh: batch sharded over
     ``batch_axes``, heads over ``head_axis``, sequence replicated.
@@ -1139,11 +1369,15 @@ def flash_attention_sharded(
         )
     batch, head = _mesh_split(mesh, batch_axes, head_axis)
     if not batch and head is None:
-        return flash_attention(q, k, v, causal=causal, interpret=interpret)
+        return flash_attention(
+            q, k, v, causal=causal, interpret=interpret, window=window
+        )
     spec = P(batch if batch else None, None, head, None)
 
     def local(ql, kl, vl):
-        return flash_attention(ql, kl, vl, causal=causal, interpret=interpret)
+        return flash_attention(
+            ql, kl, vl, causal=causal, interpret=interpret, window=window
+        )
 
     return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -22,6 +22,9 @@ Bytes a layer: attention ``B*S*Hq*(D*itemsize + 4)``; a state-space mixer
 chunks of 128 in bfloat16: 41.9 MB + 10.5 MB = 52.4 MB, for which
 ``ssm_scan_fwd`` runs once a layer instead of twice.
 
+The banded flash kernels of a window call (``flash_win_*``) name the same
+two arrays, so a window layer's forward kernel runs once a layer too.
+
 ONE policy for every family: ``save_only_these_names`` saves a name only
 where the kernel that gives it was traced, so a block without a scan (or
 with ``jnp``/ring attention, or in serving) saves nothing for it and
