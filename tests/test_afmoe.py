@@ -363,6 +363,8 @@ def test_scopes_and_counters():
     held, total = rose("moe.experts_held"), rose("moe.experts_total")
     assert held > 0 and total == 2 * held
     assert sizes["num_experts_total"] == 2 * sizes["num_experts"]
+    # a trace of the routed layer's backward rule an expert layer
+    assert rose("moe.first_chunk{forward=kept}") == 2
     # two window layers and one full: three flash calls, two of them banded
     assert rose("attention.flash_window{window=48}") == 2
     assert rose("attention.flash{interpret=true}") == 3

@@ -238,8 +238,9 @@ CHUNK_CASES = {
 def test_row_chunks_cover_every_held_row(case, monkeypatch):
     """The sorted side runs ``ceil(M / R)`` chunks of ``R`` rows, counted
     from the routing, and whatever the count the layer and its gradients
-    (h, router, the three expert matrices) are the dense per-token sum
-    over the held choices; ``stats["row_chunks"]`` says how many ran."""
+    (h, router, the three expert matrices, and the routing weights
+    themselves through a scale a token) are the dense per-token sum over
+    the held choices; ``stats["row_chunks"]`` says how many ran."""
     top_k, picked, bound, m, chunks = CHUNK_CASES[case]
     t, d, f, e, first = 96, 16, 8, 8, 2  # held: experts 2..5
     ks = jax.random.split(jax.random.PRNGKey(6), 6)
@@ -252,32 +253,83 @@ def test_row_chunks_cover_every_held_row(case, monkeypatch):
     if picked is not None:
         bias = bias.at[jnp.asarray(picked)].set(50.0)
     monkeypatch.setattr(routed_mod, "_row_bound", lambda *shape: bound)
-    kw = dict(top_k=top_k, bias=bias, scale=1.5)
+    kw = dict(top_k=top_k, bias=bias)
 
-    def layer(*args):
+    # ``scale`` a token: its gradient is the routing weights' own, summed
+    # over a token's choices, with no router behind it.
+    def layer(*args, scale):
         out, stats = routed_experts(
-            *args, gates="sigmoid", first_held=first, **kw
+            *args, gates="sigmoid", first_held=first, scale=scale, **kw
         )
         return (out * cot).sum(), (out, stats)
 
-    def dense(*args):
-        out = _dense_held_sum(*args, first=first, **kw)
+    def dense(*args, scale):
+        out = _dense_held_sum(*args, first=first, scale=scale, **kw)
         return (out * cot).sum(), out
 
-    args = (h, router, eg, eu, ed)
-    grads, (out, stats) = jax.jit(
-        jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
-    )(*args)
-    want_grads, want = jax.grad(dense, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    def grad(fn):
+        return jax.grad(
+            lambda *args: fn(*args[:-1], scale=args[-1]),
+            argnums=(0, 1, 2, 3, 4, 5), has_aux=True,
+        )
+
+    args = (h, router, eg, eu, ed, jnp.full((t, 1), 1.5))
+    grads, (out, stats) = jax.jit(grad(layer))(*args)
+    want_grads, want = grad(dense)(*args)
     assert int(stats["local_assignments"]) == m
     assert int(stats["row_chunks"]) == chunks == -(-m // bound)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=0)
     for name, g, g_want in zip(
-        ("h", "router", "e_gate", "e_up", "e_down"), grads, want_grads
+        ("h", "router", "e_gate", "e_up", "e_down", "routing weights"),
+        grads, want_grads,
     ):
         np.testing.assert_allclose(g, g_want, atol=2e-5, rtol=1e-5, err_msg=name)
     if not m:
         assert not any(np.asarray(g).any() for g in grads)
+
+
+def test_a_chunks_backward_is_the_vjp_of_its_three_products():
+    """bfloat16: the written-out backward of a chunk (six grouped
+    products from the kept gate and up products, the routing weights'
+    gradient from the UNWEIGHTED transposed product) against ``jax.vjp``
+    of the three forward products on the same rows, the form the layer
+    had; within 4 ulp of bfloat16 of each result's largest entry (the two
+    round ``g * w`` at different places), rows outside every group apart."""
+    r, d, f, n = 96, 64, 32, 4
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    bf = jnp.bfloat16
+    xs = jax.random.normal(ks[0], (r, d)).astype(bf)
+    eg, eu = (
+        (0.3 * jax.random.normal(k, (n, d, f))).astype(bf) for k in ks[1:3]
+    )
+    ed = (0.3 * jax.random.normal(ks[3], (n, f, d))).astype(bf)
+    g = jax.random.normal(ks[4], (r, d))
+    w_rows = jax.random.uniform(ks[5], (r,), minval=0.1, maxval=1.0)
+    sizes = jnp.asarray([40, 0, 17, 23], jnp.int32)  # 80 of 96 rows held
+    m = int(sizes.sum())
+
+    def experts(xs, eg, eu, ed):
+        return routed_mod._down(
+            *routed_mod._gate_up(xs, eg, eu, sizes), ed, sizes
+        )
+
+    y, pull = jax.vjp(experts, xs, eg, eu, ed)
+    want_dxs, *want_experts = pull((g * w_rows[:, None]).astype(bf))
+    want_dw = (y.astype(jnp.float32) * g).sum(-1)
+
+    gate, up = routed_mod._gate_up(xs, eg, eu, sizes)
+    dxs, d_experts, dw = jax.jit(routed_mod._chunk_bwd)(
+        xs, gate, up, eg, eu, ed, sizes, g, w_rows
+    )
+    assert dxs.dtype == bf and dw.dtype == jnp.float32
+    assert all(a.dtype == bf for a in d_experts)
+    for name, got, want in zip(
+        ("xs", "e_gate", "e_up", "e_down", "routing weights"),
+        (dxs[:m], *d_experts, dw[:m]), (want_dxs[:m], *want_experts, want_dw[:m]),
+    ):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.abs(want).max() > 0.1, name
+        assert np.abs(got - want).max() <= 2.0 ** -6 * np.abs(want).max(), name
 
 
 def _avals(jaxpr):
@@ -386,6 +438,9 @@ def test_scopes_and_counters():
     total = c1["moe.experts_total"] - c0.get("moe.experts_total", 0)
     assert held > 0 and total == 2 * held
     assert sizes["n_routed_experts_total"] == 2 * sizes["n_routed_experts"]
+    # one trace of the routed layer's backward rule: the scanned block's
+    kept = "moe.first_chunk{forward=kept}"
+    assert c1[kept] - c0.get(kept, 0) == 1
 
 
 def test_train_step_carries_the_counts_out_and_fit_records_them():

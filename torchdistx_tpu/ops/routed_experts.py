@@ -35,12 +35,29 @@ count is read on the device from the routing the step just made:
   the inverse permutation, choice by choice; never a scatter-add, which
   measured 1.5 ms where the six gathers take 0.7, PERF.md section 6).
 * The loop's trip count is dynamic, and such a loop has no transpose, so
-  the sorted side is ONE ``jax.custom_vjp``: its residuals are its inputs
-  and its backward is the same loop by hand, with ``jax.vjp`` of the
-  chunk's products inside the body, accumulating the gradients of ``h``
-  and the weights in float32 and of the expert matrices chunk by chunk.
-  The first chunk runs outside the loop, so with one chunk nothing is
-  carried through a loop.
+  the sorted side is ONE ``jax.custom_vjp`` whose backward is the same
+  loop by hand.  A chunk's backward is written out (``_chunk_bwd``): from
+  the chunk's gate and up products it takes SIX grouped products (the
+  down product transposed, three weight gradients, the gate's and up's
+  inputs) and no forward product; the routing weights' gradient is
+  ``sum_f act * (g @ e_down^T)``, so the down product is not run again
+  for it.  Gradients of ``h`` and the weights accumulate in float32, the
+  expert matrices' chunk by chunk.
+* The forward runs ONCE for the first chunk, which always runs and runs
+  outside the loop (with one chunk, the usual case, nothing is carried
+  through a loop): the forward rule returns its gate and up products
+  ``(R, F)`` beside the inputs, named ``moe_gate`` and ``moe_up``, and the
+  layer's result is named ``moe_out``, so a rematerialised block
+  (``ops/remat.py``) keeps them and replays neither the gather, the
+  products nor the sum; the counter ``moe.first_chunk{forward=kept}``
+  counts the backward rule's traces.  The kept products are rows of the
+  order the forward's choice sorted, so the choice is named too
+  (``moe_selected``) and a replay sorts by it: its own scores round
+  otherwise in bfloat16, a near tie flips, and every row behind the
+  flipped one would meet another token's products.  Chunks past the
+  first recompute their gate and up products in the backward loop: their
+  number is read on the device, so nothing of theirs can be kept without
+  a worst-case buffer.
 
 Scopes (HLO metadata only): ``router``, ``dispatch``, ``experts``,
 ``combine``, to be entered under the caller's ``moe`` scope.
@@ -52,6 +69,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from .. import telemetry as _telemetry
 
 __all__ = ["routed_experts"]
 
@@ -98,11 +118,60 @@ def _chunk(i, rows, perm, inv, group_sizes):
     )
 
 
-def _experts(xs, e_gate, e_up, e_down, sizes):
+def _gate_up(xs, e_gate, e_up, sizes):
     with jax.named_scope("experts"):
-        gated = jax.nn.silu(jax.lax.ragged_dot(xs, e_gate, sizes))
-        up = jax.lax.ragged_dot(xs, e_up, sizes)
-        return jax.lax.ragged_dot(gated * up, e_down, sizes)
+        return (
+            jax.lax.ragged_dot(xs, e_gate, sizes),
+            jax.lax.ragged_dot(xs, e_up, sizes),
+        )
+
+
+def _act(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def _down(gate, up, e_down, sizes):
+    with jax.named_scope("experts"):
+        return jax.lax.ragged_dot(_act(gate, up), e_down, sizes)
+
+
+# The two transposes of ``ragged_dot(x (R, K), w (Eh, K, N), sizes)``, as
+# its own transpose rule writes them.
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+)
+
+
+def _dot_t(dy, w, sizes):
+    """``dy (R, N)`` -> the gradient of ``x``, ``(R, K)``."""
+    return jax.lax.ragged_dot(dy, jnp.swapaxes(w, 1, 2), sizes)
+
+
+def _dot_w(x, dy, sizes):
+    """``x (R, K)``, ``dy (R, N)`` -> the gradient of ``w``, ``(Eh, K, N)``."""
+    return jax.lax.ragged_dot_general(x, dy, sizes, _ROWS_CONTRACTED)
+
+
+def _chunk_bwd(xs, gate, up, e_gate, e_up, e_down, sizes, g, w_rows):
+    """The backward of ``y = w_rows * down(act(gate, up))`` over a chunk's
+    rows from its gate and up products, ``g (R, D)`` float32 the
+    UNWEIGHTED gradient of each row's result: six grouped products, none
+    of them a forward one.  -> ``(dxs, (d e_gate, d e_up, d e_down),
+    dw_rows (R,) float32)``."""
+    with jax.named_scope("experts"):
+        act, pull = jax.vjp(_act, gate, up)
+        # dw[r] = sum_d y[r, d] g[r, d] = sum_f act[r, f] (g @ e_down^T)[r, f]
+        gd = _dot_t(g.astype(act.dtype), e_down, sizes).astype(jnp.float32)
+        dw_rows = (act.astype(jnp.float32) * gd).sum(axis=-1)
+        d_gate, d_up = pull((gd * w_rows[:, None]).astype(act.dtype))
+        dy = (g * w_rows[:, None]).astype(act.dtype)
+        d_experts = (
+            _dot_w(xs, d_gate, sizes), _dot_w(xs, d_up, sizes),
+            _dot_w(act, dy, sizes),
+        )
+        dxs = _dot_t(d_gate, e_gate, sizes) + _dot_t(d_up, e_up, sizes)
+    return dxs, d_experts, dw_rows
 
 
 def _per_token(table, at, ok, w=None):
@@ -118,58 +187,75 @@ def _per_token(table, at, ok, w=None):
     return out
 
 
+def _forward(rows, h, e_gate, e_up, e_down, w, perm, inv, group_sizes):
+    """``(out, (gate, up))``: the sorted side's result and the first
+    chunk's two products, named for a remat policy."""
+    t = h.shape[0]
+
+    def products(i):
+        flat, sizes, at, ok = _chunk(i, rows, perm, inv, group_sizes)
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(h, flat % t, axis=0, mode="clip")
+        return (sizes, at, ok), _gate_up(xs, e_gate, e_up, sizes)
+
+    def add(out, chunk, gate_up):
+        sizes, at, ok = chunk
+        y = _down(*gate_up, e_down, sizes)
+        with jax.named_scope("combine"):
+            return out + _per_token(y, at, ok, w)
+
+    # The first chunk outside the loop: with one chunk, the usual case,
+    # nothing is carried through a loop that does not run.
+    chunk, (gate, up) = products(0)
+    kept = checkpoint_name(gate, "moe_gate"), checkpoint_name(up, "moe_up")
+    out = add(jnp.zeros(h.shape, jnp.float32), chunk, kept)
+    out = jax.lax.fori_loop(
+        1, _n_chunks(group_sizes, rows),
+        lambda i, out: add(out, *products(i)), out,
+    )
+    return out, kept
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _sorted_side(rows, h, e_gate, e_up, e_down, w, perm, inv, group_sizes):
     """``out[t] = sum_c w[c, t] * FFN_{expert of (c, t)}(h[t])`` over the
     held choices, ``(T, D)`` float32, in ``ceil(M / rows)`` chunks of the
     sorted order (``M = group_sizes.sum()``, read on the device).  ``w``,
     ``inv`` ``(k, T)``; ``perm`` padded by ``rows``."""
-    t = h.shape[0]
-
-    def step(i, out):
-        flat, sizes, at, ok = _chunk(i, rows, perm, inv, group_sizes)
-        with jax.named_scope("dispatch"):
-            xs = jnp.take(h, flat % t, axis=0, mode="clip")
-        y = _experts(xs, e_gate, e_up, e_down, sizes)
-        with jax.named_scope("combine"):
-            return out + _per_token(y, at, ok, w)
-
-    # The first chunk outside the loop: with one chunk, the usual case,
-    # nothing is carried through a loop that does not run.
-    return jax.lax.fori_loop(
-        1, _n_chunks(group_sizes, rows), step,
-        step(0, jnp.zeros(h.shape, jnp.float32)),
-    )
+    return _forward(
+        rows, h, e_gate, e_up, e_down, w, perm, inv, group_sizes
+    )[0]
 
 
 def _sorted_side_fwd(rows, *args):
-    return _sorted_side(rows, *args), args
+    out, kept = _forward(rows, *args)
+    return out, (args, kept)
 
 
 def _sorted_side_bwd(rows, res, dout):
     # The trip count is read from the routing, and a loop of unknown
-    # length has no transpose: the backward is the same loop, written out,
-    # with each chunk's own backward (jax.vjp) inside.
-    h, e_gate, e_up, e_down, w, perm, inv, group_sizes = res
+    # length has no transpose: the backward is the same loop, written out.
+    (h, e_gate, e_up, e_down, w, perm, inv, group_sizes), kept = res
     t = h.shape[0]
+    _telemetry.counter("moe.first_chunk", forward="kept").add()
 
-    def step(i, dh, dw):
+    def step(i, dh, dw, products=None):
         flat, sizes, at, ok = _chunk(i, rows, perm, inv, group_sizes)
         with jax.named_scope("dispatch"):
             xs = jnp.take(h, flat % t, axis=0, mode="clip")
-        y, pull = jax.vjp(
-            lambda *a: _experts(*a, sizes), xs, e_gate, e_up, e_down
-        )
+        if products is None:
+            products = _gate_up(xs, e_gate, e_up, sizes)
         with jax.named_scope("combine"):
             g = jnp.take(dout, flat % t, axis=0, mode="clip")
             w_rows = jnp.take(w.reshape(-1), flat, mode="clip")
-            dy = (g * w_rows[:, None]).astype(y.dtype)
-            dw_rows = (y.astype(jnp.float32) * g).sum(axis=-1)
+        dxs, d_experts, dw_rows = _chunk_bwd(
+            xs, *products, e_gate, e_up, e_down, sizes, g, w_rows
+        )
+        with jax.named_scope("combine"):
             dw = dw + jnp.where(ok, jnp.take(dw_rows, at, mode="clip"), 0.0)
-        dxs, *d_experts = pull(dy)
         with jax.named_scope("dispatch"):
             dh = dh + _per_token(dxs, at, ok)
-        return dh, tuple(d_experts), dw
+        return dh, d_experts, dw
 
     def body(i, carry):
         dh, d_experts, dw = carry
@@ -178,7 +264,7 @@ def _sorted_side_bwd(rows, res, dout):
 
     zeros = jnp.zeros(h.shape, jnp.float32), jnp.zeros(w.shape, jnp.float32)
     dh, d_experts, dw = jax.lax.fori_loop(
-        1, _n_chunks(group_sizes, rows), body, step(0, *zeros)
+        1, _n_chunks(group_sizes, rows), body, step(0, *zeros, kept)
     )
     return (
         dh.astype(h.dtype), *d_experts, dw.astype(w.dtype), None, None, None
@@ -227,6 +313,10 @@ def routed_experts(
             raise ValueError(f"unknown gates: {gates!r} (softmax|sigmoid)")
         choice = scores if bias is None else scores + bias.astype(jnp.float32)
         _, selected = jax.lax.top_k(choice, top_k)  # (T, K)
+        # Kept by a rematerialised block beside the products: they are
+        # rows of the order THIS choice sorts, and a replay whose scores
+        # round otherwise flips a near tie and sorts another.
+        selected = checkpoint_name(selected, "moe_selected")
         w = jnp.take_along_axis(scores, selected, axis=-1)
         w = w / (w.sum(axis=-1, keepdims=True) + 1e-20) * scale
 
@@ -248,6 +338,7 @@ def routed_experts(
         rows, h, e_gate, e_up, e_down, w.T, jnp.pad(perm, (0, rows)),
         inv.reshape(top_k, t), group_sizes,
     ).astype(h.dtype)
+    out = checkpoint_name(out, "moe_out")
 
     sizes = group_sizes.astype(jnp.float32)
     stats = {
