@@ -62,6 +62,141 @@ def test_jit_program_counts_compiles_exactly():
     assert hist.get("count", 0) >= 2
 
 
+def _hist(name):
+    return telemetry.histograms().get(name) or {"count": 0, "sum": 0.0}
+
+
+_PHASE_FAMILIES = ("trace_s", "lower_s", "cache_load_s", "time_s")
+
+
+def _phases(label):
+    return {
+        k: _hist(f"compile.{k}{{program={label}}}") for k in _PHASE_FAMILIES
+    }
+
+
+def _new_jit():
+    """A new jitted function each call, of one and the same program."""
+
+    def tdx_test_body(x):
+        return jax.nn.silu(x) @ x.T + 35.0
+
+    return jax.jit(tdx_test_body)
+
+
+def test_a_compile_records_its_trace_and_lowering_under_its_label():
+    """ISSUE 35: tracing and lowering land beside ``compile.time_s``,
+    under the JitProgram's label and not under ``other``; a warm call
+    records nothing."""
+    import time
+
+    perf.install_monitoring()
+    f = _new_jit()
+    jp = perf.JitProgram(lambda: f, "tdx_test_phases")
+    x = jax.numpy.ones((4, 4))
+    other = _phases("other")
+    t0 = time.perf_counter()
+    jp.call(None, None, x)
+    wall = time.perf_counter() - t0
+    mine = _phases("tdx_test_phases")
+    assert mine["trace_s"]["count"] >= 1 and mine["trace_s"]["sum"] > 0
+    assert mine["lower_s"]["count"] == 1 and mine["lower_s"]["sum"] > 0
+    assert mine["time_s"]["count"] == 1
+    # each second counted once: the phases fit inside the call
+    assert sum(h["sum"] for h in mine.values()) <= wall
+    assert _phases("other") == other
+    jp.call(None, None, x)
+    assert _phases("tdx_test_phases") == mine
+
+
+def test_a_nested_trace_counts_its_seconds_once():
+    """JAX reports a jit traced inside another's trace first and then
+    again inside its caller's duration: the caller keeps the rest."""
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    perf._tls.traces = []  # this thread's earlier traces began after -100 s
+    with perf.program("tdx_test_nested"):
+        perf._on_duration_event(event, 0.25)  # the callee, just ended
+        perf._on_duration_event(event, 100.0)  # its caller, ending now
+    hist = _hist("compile.trace_s{program=tdx_test_nested}")
+    assert hist["count"] == 2
+    assert abs(hist["sum"] - 100.0) < 1e-6
+    # a step's trace holds thousands of callees, one after another
+    with perf.program("tdx_test_siblings"):
+        for _ in range(5000):
+            perf._on_duration_event(event, 1e-6)
+        perf._on_duration_event(event, 50.0)
+    hist = _hist("compile.trace_s{program=tdx_test_siblings}")
+    assert hist["count"] == 5001
+    assert abs(hist["sum"] - 50.0) < 1e-6
+
+
+def test_a_program_served_by_the_persistent_cache_counts_its_load(tmp_path):
+    """What a second process sees: the executable comes out of the
+    persistent cache, which counts as a hit with its load time, and as
+    that program's first compile, not as a recompile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    perf.install_monitoring()
+    flags = {
+        "jax_compilation_cache_dir": str(tmp_path),
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+    }
+    saved = {k: getattr(jax.config, k) for k in flags}
+    hits = "compile.cache_hits{program=tdx_test_cached}"
+    recompiles = "compile.recompiles{program=tdx_test_cached}"
+    x = jax.numpy.ones((6, 6))
+    try:
+        for k, v in flags.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        first = _new_jit()
+        perf.JitProgram(lambda: first, "tdx_test_cached")(x)
+        assert counter_value(hits) == 0
+        assert _phases("tdx_test_cached")["cache_load_s"]["count"] == 0
+        # nothing of the first function is in memory for the second: a
+        # new function object is a new jit cache, as in a new process
+        second = _new_jit()
+        perf.JitProgram(lambda: second, "tdx_test_cached")(x)
+        mine = _phases("tdx_test_cached")
+        assert counter_value(hits) == 1
+        assert mine["cache_load_s"]["count"] == 1
+        assert mine["cache_load_s"]["sum"] > 0
+        # JAX times the backend compile around the lookup
+        assert mine["time_s"]["count"] == 2
+        assert mine["time_s"]["max"] >= mine["cache_load_s"]["sum"]
+        assert counter_value("compile.count{program=tdx_test_cached}") == 2
+        assert counter_value(recompiles) == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_a_broken_event_does_not_fail_the_compile():
+    """Whatever arrives at the listeners, the compile they watch runs."""
+    from jax import monitoring
+
+    perf.install_monitoring()
+    for event in (
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/compilation_cache/cache_retrieval_time_sec",
+        "/jax/core/compile/backend_compile_duration",
+    ):
+        with perf.program("tdx_test_broken"):
+            monitoring.record_event_duration_secs(event, "not a number")
+            monitoring.record_event_duration_secs(event, None)
+            perf._on_duration_event(event, object(), fun_name=object())
+    monitoring.record_event("/jax/compilation_cache/cache_hits", who=object())
+    perf._on_event(None)
+    f = _new_jit()
+    jp = perf.JitProgram(lambda: f, "tdx_test_broken")
+    out = jp.call(None, None, jax.numpy.ones((5, 5)))
+    assert out.shape == (5, 5)
+    assert counter_value("compile.count{program=tdx_test_broken}") >= 1
+
+
 def test_monkeypatched_stand_in_passes_through():
     """A plain function swapped in for the jitted one (the chaos tests'
     flaky decode) is not instrumented — and not broken."""

@@ -3,10 +3,15 @@
 ``guard`` reach the optimized program's ``op_name`` metadata and change
 nothing else; the flash kernels carry fixed names; the step is the tracked
 program ``train_step``; with a span sink its compile records a scope map,
-and without one nothing extra is lowered or compiled."""
+and without one nothing extra is lowered or compiled.  ISSUE 35: in all
+five families; every scan over layers is under ``stack``, what a scan's
+transpose generates has that name and no block's; ``attn`` is split into
+``norm`` / ``proj_in`` / ``rope`` / ``concat`` / ``proj_out``, and the
+flash kernels' doors are ``attn/relayout`` and ``attn/delta``."""
 
 import contextlib
 import dataclasses
+import functools
 import re
 
 import jax
@@ -15,7 +20,7 @@ import optax
 import pytest
 
 from torchdistx_tpu import telemetry
-from torchdistx_tpu.models import gpt2, llama
+from torchdistx_tpu.models import afmoe, deepseek_v3, gpt2, jamba, llama
 from torchdistx_tpu.ops.pallas.flash_attention import flash_attention
 from torchdistx_tpu.parallel import train_step as ts
 from torchdistx_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -23,9 +28,35 @@ from torchdistx_tpu.parallel.slowmo import SlowMomentumOptimizer
 from torchdistx_tpu.telemetry import perf
 
 SCOPES = ("embed", "attn", "mlp", "head", "optimizer", "guard")
+# A block's own scopes: what the scans' own work must NOT carry.
+BLOCKS = {"embed", "attn", "mlp", "moe", "mamba", "head"}
+
+
+def _afmoe_two_periods():
+    """``afmoe_test`` with its expert stack two whole periods long, so
+    that the stack's scan is a loop."""
+    return dataclasses.replace(
+        afmoe.afmoe_test(), n_moe_layers=4,
+        layer_types=(
+            afmoe.WINDOW, afmoe.WINDOW, afmoe.FULL, afmoe.WINDOW, afmoe.FULL
+        ),
+    )
+
+
 FAMILIES = {
     "gpt2": (gpt2, gpt2.gpt2_test),
     "llama": (llama, llama.llama_test),
+    "deepseek_v3": (deepseek_v3, deepseek_v3.deepseek_v3_test),
+    "jamba": (jamba, jamba.jamba_test),
+    "afmoe": (afmoe, _afmoe_two_periods),
+}
+# The sub-scopes of ``attn`` each family's block opens itself.
+ATTN = {
+    "gpt2": {"norm", "proj_in", "proj_out"},
+    "llama": {"norm", "proj_in", "rope", "proj_out"},
+    "deepseek_v3": {"norm", "proj_in", "rope", "concat", "proj_out"},
+    "jamba": {"norm", "proj_in", "proj_out"},
+    "afmoe": {"norm", "proj_in", "qk_norm", "rope", "gate", "proj_out"},
 }
 
 
@@ -33,16 +64,30 @@ def _scanned(family):
     """The family's test config with its layers under ``lax.scan`` and
     remat, as at full depth."""
     model, make = FAMILIES[family]
-    return model, dataclasses.replace(make(), layer_unroll=1, remat=True)
+    cfg = make()
+    unroll = {"layer_unroll": 1} if hasattr(cfg, "layer_unroll") else {}
+    return model, dataclasses.replace(cfg, remat=True, **unroll)
 
 
-def _step(family, tx=None):
+def _step(family, tx=None, **kw):
     model, cfg = _scanned(family)
     mesh = make_mesh(MeshSpec(fsdp=2), devices=jax.devices()[:2])
     init_fn, step_fn = ts.make_train_step(
-        cfg, mesh, tx or optax.adamw(1e-3), model=model
+        cfg, mesh, tx or optax.adamw(1e-3), model=model, **kw
     )
     return cfg, mesh, init_fn, step_fn
+
+
+@functools.lru_cache(maxsize=None)
+def _paths(family, attn_impl="auto"):
+    """Every ``op_name`` path of the family's optimized train step (one
+    compile a family for the tests that only read paths)."""
+    cfg, mesh, init_fn, step_fn = _step(family, attn_impl=attn_impl)
+    state = init_fn(jax.random.PRNGKey(0))
+    text = step_fn.lower(state, _batch(cfg, mesh)).compile().as_text()
+    return frozenset(
+        p for ps in perf.hlo_scopes(text).values() for p in ps if p
+    )
 
 
 def _batch(cfg, mesh, seq=32):
@@ -71,12 +116,16 @@ def _grew(before):
     }
 
 
-def _words(path):
-    """The innermost word of each component: ``transpose(jvp(attn))`` ->
-    ``attn``."""
-    return {
+def _chain(path):
+    """The innermost word of each component, in order:
+    ``loss/transpose(jvp(attn))/mul`` -> ``[loss, attn, mul]``."""
+    return [
         (re.findall(r"[A-Za-z_]\w*", c) or [""])[-1] for c in path.split("/")
-    }
+    ]
+
+
+def _words(path):
+    return set(_chain(path))
 
 
 @contextlib.contextmanager
@@ -91,25 +140,32 @@ def _sink():
 _METADATA = re.compile(r",? ?metadata=\{[^}]*\}")
 
 
+_NAME = re.compile(r"%([^\s=(),{}]+?)(?:\.\d+)?(?=[\s=(),{}]|$)")
+
+
 def _bare(hlo_text):
-    """Optimized HLO without the tables of source locations at its head
-    and without any instruction's metadata."""
+    """Optimized HLO without the tables of source locations at its head,
+    without any instruction's metadata, and with every name's number that
+    of its first appearance among the names of its stem: XLA numbers the
+    instructions it names alike in an order that follows their metadata
+    (the ``deepseek_v3`` step's ``broadcast_in_dim.N`` shift by 19)."""
     body = hlo_text[hlo_text.index("\n\n%") if "\n\n%" in hlo_text else 0:]
-    return _METADATA.sub("", body)
+    seen, stems = {}, {}
+
+    def renumber(m):
+        if m.group(0) not in seen:
+            n = stems[m.group(1)] = stems.get(m.group(1), 0) + 1
+            seen[m.group(0)] = f"%{m.group(1)}.{n}"
+        return seen[m.group(0)]
+
+    return _NAME.sub(renumber, _METADATA.sub("", body))
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_compiled_step_carries_the_six_scopes(family):
-    cfg, mesh, init_fn, step_fn = _step(family)
-    state = init_fn(jax.random.PRNGKey(0))
-    text = step_fn.lower(state, _batch(cfg, mesh)).compile().as_text()
-    seen = set()
-    for paths in perf.hlo_scopes(text).values():
-        for p in paths:
-            seen |= _words(p) & set(SCOPES)
-    assert seen == set(SCOPES)
+    paths = _paths(family)
+    assert set().union(*map(_words, paths)) & set(SCOPES) == set(SCOPES)
     # the backward pass keeps the names, wrapped by autodiff
-    paths = [p for ps in perf.hlo_scopes(text).values() for p in ps]
     assert any("transpose(" in p and "mlp" in _words(p) for p in paths)
     assert any("rematted_computation" in p and "attn" in _words(p) for p in paths)
 
@@ -130,6 +186,68 @@ def test_scopes_are_metadata_only(family, monkeypatch):
     plain = plain_fn.lower(state, batch).compile().as_text()
     assert "/optimizer/" not in plain
     assert _bare(scoped) == _bare(plain)
+
+
+_SCAN_OWN = re.compile(r"/while/body/dynamic_(?:update_)?slice$")
+
+
+def _under_attn(paths, name):
+    """The paths that hold ``name`` somewhere under ``attn``."""
+    chains = ((p, _chain(p)) for p in paths)
+    return [p for p, c in chains if name in c[c.index("attn"):]]
+
+
+def _both_ways(paths):
+    """Forward and in the backward pass."""
+    return any("transpose(" not in p for p in paths) and any(
+        "transpose(" in p for p in paths
+    )
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_what_a_scans_transpose_generates_is_under_stack(family):
+    """A layer's weights and residuals sliced out of their stacks and the
+    stacked gradients updated in the backward loop: every such instruction
+    outside a block has ``stack`` as its innermost name."""
+    own = [
+        p for p in _paths(family)
+        if "transpose(" in p and _SCAN_OWN.search(p)
+        and not _words(p) & BLOCKS
+    ]
+    assert {p.rsplit("/", 1)[1] for p in own} == {
+        "dynamic_slice", "dynamic_update_slice"
+    }
+    for p in own:
+        assert _chain(p[: _SCAN_OWN.search(p).start()])[-1] == "stack", p
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_attn_is_split_forward_and_backward(family):
+    paths = _paths(family)
+    attn = [p for p in paths if "attn" in _words(p)]
+    for name in ATTN[family]:
+        assert _both_ways(_under_attn(attn, name)), name
+    # nothing of a block is left outside a block-level scope: a path of a
+    # loop's body with no block's name is the scan's own, and no scan
+    # multiplies matrices or takes a norm
+    for p in paths:
+        if "/while/body/" in p and not _words(p) & BLOCKS:
+            assert "stack" in _words(p), p
+            assert not re.search(r"/(dot_general|rsqrt|reduce_sum|exp)$", p), p
+
+
+def test_flash_step_has_relayout_and_delta_under_attn():
+    """The transposes at the kernels' doors and ``delta``'s row sum, in a
+    step whose attention is the flash kernels (interpreted here), beside
+    the five names latent attention's block opens itself."""
+    paths = [
+        p for p in _paths("deepseek_v3", "pallas")
+        if "attn" in _words(p)
+    ]
+    for name in ATTN["deepseek_v3"] | {"relayout"}:
+        assert _both_ways(_under_attn(paths, name)), name
+    delta = _under_attn(paths, "delta")
+    assert delta and all("transpose(" in p for p in delta)
 
 
 @pytest.mark.parametrize(
@@ -236,6 +354,16 @@ def test_sink_records_a_scope_map_of_every_instruction():
 def test_hlo_scopes_lists_what_a_fusion_holds():
     text = """HloModule jit_f, is_scheduled=true
 
+%inner (p1: f32[8]) -> f32[8] {
+  %p1 = f32[8]{0} parameter(0)
+  ROOT %add.7 = f32[8]{0} add(%p1, %p1), metadata={op_name="jit(f)/moe/router/add_any"}
+}
+
+%outer (p2: f32[8]) -> f32[8] {
+  %p2 = f32[8]{0} parameter(0)
+  ROOT %fusion.9 = f32[8]{0} fusion(%p2), kind=kCustom, calls=%inner
+}
+
 %fused_computation (p0: f32[8]) -> f32[8] {
   %p0 = f32[8]{0} parameter(0)
   %mul.1 = f32[8]{0} multiply(%p0, %p0), metadata={op_name="jit(f)/optimizer/mul"}
@@ -245,10 +373,17 @@ def test_hlo_scopes_lists_what_a_fusion_holds():
 ENTRY %main.5 (a: f32[8]) -> f32[8] {
   %a = f32[8]{0} parameter(0), metadata={op_name="a"}
   %copy.1 = f32[8]{0} copy(%a)
+  %fusion.8 = f32[8]{0} fusion(%copy.1), kind=kCustom, calls=%outer
   ROOT %select_fusion = f32[8]{0} fusion(%copy.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/guard/select_n"}
 }
 """
     assert perf.hlo_scopes(text) == {
+        # a fusion nested in a nameless fusion still says whose it is
+        "p1": ("",),
+        "add.7": ("jit(f)/moe/router/add_any",),
+        "p2": ("",),
+        "fusion.9": ("", "jit(f)/moe/router/add_any"),
+        "fusion.8": ("", "jit(f)/moe/router/add_any"),
         "p0": ("",),
         "mul.1": ("jit(f)/optimizer/mul",),
         "select.2": ("jit(f)/guard/select_n",),

@@ -268,10 +268,12 @@ def _attn(x, lp, cfg: AfmoeConfig, kind, *, mesh, attn_impl):
     b, s, _ = x.shape
     H, Hkv, hd, eps = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.norm_eps
     with jax.named_scope("attn"):
-        h = llama_mod._rmsnorm(x, lp["attn_norm"], eps)
-        q = (h @ lp["wq"]).reshape(b, s, H, hd)
-        k = (h @ lp["wk"]).reshape(b, s, Hkv, hd)
-        v = (h @ lp["wv"]).reshape(b, s, Hkv, hd)
+        with jax.named_scope("norm"):
+            h = llama_mod._rmsnorm(x, lp["attn_norm"], eps)
+        with jax.named_scope("proj_in"):
+            q = (h @ lp["wq"]).reshape(b, s, H, hd)
+            k = (h @ lp["wk"]).reshape(b, s, Hkv, hd)
+            v = (h @ lp["wv"]).reshape(b, s, Hkv, hd)
         with jax.named_scope("qk_norm"):
             q = llama_mod._rmsnorm(q, lp["q_norm"], eps)
             k = llama_mod._rmsnorm(k, lp["k_norm"], eps)
@@ -288,7 +290,11 @@ def _attn(x, lp, cfg: AfmoeConfig, kind, *, mesh, attn_impl):
         )
         with jax.named_scope("gate"):
             a = _gated(a.reshape(b, s, H * hd), h @ lp["wg"])
-        return x + llama_mod._rmsnorm(a @ lp["wo"], lp["post_attn_norm"], eps)
+        # with the sandwich's second norm, which sits before the add
+        with jax.named_scope("proj_out"):
+            return x + llama_mod._rmsnorm(
+                a @ lp["wo"], lp["post_attn_norm"], eps
+            )
 
 
 def _build_blocks(cfg: AfmoeConfig, *, mesh=None, attn_impl="auto"):
@@ -320,8 +326,8 @@ def _build_blocks(cfg: AfmoeConfig, *, mesh=None, attn_impl="auto"):
                 h = llama_mod._rmsnorm(x, lp["mlp_norm"], eps)
             m, stats = deepseek_mod.moe_block(h, lp, cfg)
             with jax.named_scope("moe"):
-                m = llama_mod._rmsnorm(m, lp["post_mlp_norm"], eps)
-            return x + m, (
+                x = x + llama_mod._rmsnorm(m, lp["post_mlp_norm"], eps)
+            return x, (
                 stats["local_assignments"], stats["load_max_over_mean"],
                 stats["row_chunks"],
             )
@@ -395,10 +401,14 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
             x = x * (cfg.dim ** 0.5)
     dense, moe = _build_blocks(cfg, mesh=mesh, attn_impl=attn_impl)
     kinds, nd = cfg.layer_types, cfg.n_dense_layers
-    x, _ = _run_stack(x, params["dense_layers"], kinds[:nd], dense)
-    x, (assigned, load, chunks) = _run_stack(
-        x, params["moe_layers"], kinds[nd:], moe
-    )
+    # ``stack``: the periods' reshape, the scans' own work and each
+    # layer's weights indexed out of its period; the blocks' scopes are
+    # innermost.
+    with jax.named_scope("stack"):
+        x, _ = _run_stack(x, params["dense_layers"], kinds[:nd], dense)
+        x, (assigned, load, chunks) = _run_stack(
+            x, params["moe_layers"], kinds[nd:], moe
+        )
     return x, {
         "local_assignments": assigned.sum(),
         "load_max_over_mean": load.mean(),

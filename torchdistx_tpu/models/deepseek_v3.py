@@ -236,32 +236,40 @@ def _swiglu(h, w_gate, w_up, w_down):
 
 
 def _attn(x, lp, cfg: DeepseekV3Config, *, mesh, attn_impl):
+    # The sub-scopes are the names every family gives the same work
+    # (docs/observability.md, "Scopes inside the train step"); ``concat``
+    # is latent attention's own.  What stays at ``attn`` is the kernels
+    # and, inside ``attention``, their ``relayout`` and ``delta``.
     b, s, _ = x.shape
     nope, rope, H = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.n_heads
     with jax.named_scope("attn"):
-        h = llama_mod._rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(b, s, H, cfg.qk_dim)
-        kva = h @ lp["wkv_a"]
-        c = llama_mod._rmsnorm(
-            kva[..., : cfg.kv_rank], lp["kv_norm"], cfg.norm_eps
-        )
-        kv = (c @ lp["wkv_b"]).reshape(b, s, H, nope + cfg.v_dim)
-        cos, sin = llama_mod._rope_tables(
-            jnp.arange(s)[None], cfg.rope_theta, rope // 2, x.dtype
-        )
-        q_rope = _rope_interleaved(q[..., nope:], cos, sin)
-        k_rope = _rope_interleaved(
-            kva[..., cfg.kv_rank:][:, :, None, :], cos, sin
-        )
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, H, rope))],
-            axis=-1,
-        )
-        a = attention(
-            q, k, kv[..., nope:], causal=True, impl=attn_impl, mesh=mesh
-        )
-        return x + a.reshape(b, s, H * cfg.v_dim) @ lp["wo"]
+        with jax.named_scope("norm"):
+            h = llama_mod._rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        with jax.named_scope("proj_in"):
+            q = (h @ lp["wq"]).reshape(b, s, H, cfg.qk_dim)
+            kva = h @ lp["wkv_a"]
+            c = llama_mod._rmsnorm(
+                kva[..., : cfg.kv_rank], lp["kv_norm"], cfg.norm_eps
+            )
+            kv = (c @ lp["wkv_b"]).reshape(b, s, H, nope + cfg.v_dim)
+        with jax.named_scope("rope"):
+            cos, sin = llama_mod._rope_tables(
+                jnp.arange(s)[None], cfg.rope_theta, rope // 2, x.dtype
+            )
+            q_rope = _rope_interleaved(q[..., nope:], cos, sin)
+            k_rope = _rope_interleaved(
+                kva[..., cfg.kv_rank:][:, :, None, :], cos, sin
+            )
+        with jax.named_scope("concat"):
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, H, rope))],
+                axis=-1,
+            )
+            v = kv[..., nope:]
+        a = attention(q, k, v, causal=True, impl=attn_impl, mesh=mesh)
+        with jax.named_scope("proj_out"):
+            return x + a.reshape(b, s, H * cfg.v_dim) @ lp["wo"]
 
 
 def moe_block(h, lp, cfg: DeepseekV3Config):
@@ -293,9 +301,12 @@ def _build_blocks(cfg: DeepseekV3Config, *, mesh=None, attn_impl="auto"):
 
     def moe(x, lp):
         x = _attn(x, lp, cfg, mesh=mesh, attn_impl=attn_impl)
-        h = llama_mod._rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        with jax.named_scope("moe"):
+            h = llama_mod._rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         out, stats = moe_block(h, lp, cfg)
-        return x + out, (
+        with jax.named_scope("moe"):
+            x = x + out
+        return x, (
             stats["local_assignments"], stats["load_max_over_mean"],
             stats["row_chunks"],
         )
@@ -313,10 +324,16 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
     if cfg.remat:
         dense = jax.checkpoint(dense, policy=REMAT_POLICY)
         moe = jax.checkpoint(moe, policy=REMAT_POLICY)
-    x, _ = jax.lax.scan(
-        lambda h, lp: (dense(h, lp), None), x, params["dense_layers"]
-    )
-    x, (assigned, load, chunks) = jax.lax.scan(moe, x, params["moe_layers"])
+    # ``stack``: what a scan over layers does itself, a layer's weights
+    # sliced out of the stack and, in its transpose, the stacked gradients
+    # and residuals written; the blocks' own scopes are innermost.
+    with jax.named_scope("stack"):
+        x, _ = jax.lax.scan(
+            lambda h, lp: (dense(h, lp), None), x, params["dense_layers"]
+        )
+        x, (assigned, load, chunks) = jax.lax.scan(
+            moe, x, params["moe_layers"]
+        )
     return x, {
         "local_assignments": assigned.sum(),
         "load_max_over_mean": load.mean(),

@@ -247,23 +247,26 @@ def _build_block(
     def block(x, lp):
         bb, s = x.shape[0], x.shape[1]
         with jax.named_scope("attn"):
-            h = _layernorm(
-                x, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.norm_eps
-            )
-            qkv = h @ lp["attn_qkv"]["weight"] + lp["attn_qkv"][
-                "bias"
-            ].astype(cfg.dtype)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(bb, s, cfg.n_heads, cfg.head_dim)
-            k = k.reshape(bb, s, cfg.n_heads, cfg.head_dim)
-            v = v.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+            with jax.named_scope("norm"):
+                h = _layernorm(
+                    x, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.norm_eps
+                )
+            with jax.named_scope("proj_in"):
+                qkv = h @ lp["attn_qkv"]["weight"] + lp["attn_qkv"][
+                    "bias"
+                ].astype(cfg.dtype)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+                k = k.reshape(bb, s, cfg.n_heads, cfg.head_dim)
+                v = v.reshape(bb, s, cfg.n_heads, cfg.head_dim)
             attn = attention(
                 q, k, v, causal=True, impl=attn_impl, mesh=mesh,
                 seq_axis=seq_axis,
             ).reshape(bb, s, -1)
-            x = x + attn @ lp["attn_proj"]["weight"] + lp["attn_proj"][
-                "bias"
-            ].astype(cfg.dtype)
+            with jax.named_scope("proj_out"):
+                x = x + attn @ lp["attn_proj"]["weight"] + lp["attn_proj"][
+                    "bias"
+                ].astype(cfg.dtype)
         with jax.named_scope("mlp"):
             h = _layernorm(
                 x, lp["ln_2"]["scale"], lp["ln_2"]["bias"], cfg.norm_eps
@@ -332,8 +335,11 @@ def _forward_hidden(
             n_microbatches=n_microbatches,
         )
     else:
-        x, _ = jax.lax.scan(lambda h, lp: (body(h, lp), None), x,
-                            params["layers"], unroll=cfg._unroll)
+        # ``stack``: the scan's own work (a layer's weights sliced out,
+        # the stacked gradients and residuals written in its transpose).
+        with jax.named_scope("stack"):
+            x, _ = jax.lax.scan(lambda h, lp: (body(h, lp), None), x,
+                                params["layers"], unroll=cfg._unroll)
     return x
 
 

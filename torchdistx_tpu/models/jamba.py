@@ -310,10 +310,12 @@ def _scan(u, delta, a, b, c, d, cfg: JambaConfig, mesh):
     )(u, delta, a, b, c, d)
 
 
-def _mamba(h, lp, cfg: JambaConfig, mesh):
-    """The Mamba mixer on normed ``h (B, T, D)``."""
+def _mamba(x, lp, cfg: JambaConfig, mesh):
+    """``x (B, T, D)`` plus its Mamba mixer's output."""
     C, N, R, eps = cfg.d_inner, cfg.d_state, cfg.dt_rank, cfg.norm_eps
     with jax.named_scope("mamba"):
+        with jax.named_scope("norm"):
+            h = llama_mod._rmsnorm(x, lp["mixer_norm"], eps)
         with jax.named_scope("in_proj"):
             uz = h @ lp["w_in"]
             u, z = uz[..., :C], uz[..., C:]
@@ -332,18 +334,23 @@ def _mamba(h, lp, cfg: JambaConfig, mesh):
         with jax.named_scope("scan"):
             y = _scan(u, delta, a, b, c, lp["d"], cfg, mesh)
         with jax.named_scope("out_proj"):
-            return (y * jax.nn.silu(z)) @ lp["w_out"]
+            return x + (y * jax.nn.silu(z)) @ lp["w_out"]
 
 
-def _attn(h, lp, cfg: JambaConfig, *, mesh, attn_impl):
-    b, s, _ = h.shape
+def _attn(x, lp, cfg: JambaConfig, *, mesh, attn_impl):
+    """``x (B, T, D)`` plus its attention mixer's output."""
+    b, s, _ = x.shape
     hd = cfg.head_dim
     with jax.named_scope("attn"):
-        q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
-        k = (h @ lp["wk"]).reshape(b, s, cfg.n_kv_heads, hd)
-        v = (h @ lp["wv"]).reshape(b, s, cfg.n_kv_heads, hd)
+        with jax.named_scope("norm"):
+            h = llama_mod._rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
+        with jax.named_scope("proj_in"):
+            q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, hd)
+            k = (h @ lp["wk"]).reshape(b, s, cfg.n_kv_heads, hd)
+            v = (h @ lp["wv"]).reshape(b, s, cfg.n_kv_heads, hd)
         a = attention(q, k, v, causal=True, impl=attn_impl, mesh=mesh)
-        return a.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
+        with jax.named_scope("proj_out"):
+            return x + a.reshape(b, s, cfg.n_heads * hd) @ lp["wo"]
 
 
 def _mlp(x, lp, cfg: JambaConfig):
@@ -353,16 +360,15 @@ def _mlp(x, lp, cfg: JambaConfig):
 
 
 def _build_blocks(cfg: JambaConfig, *, mesh=None, attn_impl="auto"):
-    """``(mamba_block, attn_block)``, each ``x, lp -> x``."""
+    """``(mamba_block, attn_block)``, each ``x, lp -> x``: the mixer with
+    its norm and residual add under the mixer's scope, then the MLP."""
 
     def mamba(x, lp):
-        h = llama_mod._rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
-        return _mlp(x + _mamba(h, lp, cfg, mesh), lp, cfg)
+        return _mlp(_mamba(x, lp, cfg, mesh), lp, cfg)
 
     def attn(x, lp):
-        h = llama_mod._rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
         return _mlp(
-            x + _attn(h, lp, cfg, mesh=mesh, attn_impl=attn_impl), lp, cfg
+            _attn(x, lp, cfg, mesh=mesh, attn_impl=attn_impl), lp, cfg
         )
 
     return mamba, attn
@@ -377,8 +383,14 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
         mamba = jax.checkpoint(mamba, policy=REMAT_POLICY)
         attn = jax.checkpoint(attn, policy=REMAT_POLICY)
 
+    # ``stack`` around both levels of scan: what the scans do themselves
+    # (weights sliced out of their stacks, the stacked gradients and
+    # residuals written in the transpose); the blocks' scopes are innermost.
     def stack(x, layers):
-        return jax.lax.scan(lambda h, lp: (mamba(h, lp), None), x, layers)[0]
+        with jax.named_scope("stack"):
+            return jax.lax.scan(
+                lambda h, lp: (mamba(h, lp), None), x, layers
+            )[0]
 
     def period(x, pp):
         if "mamba_a" in pp:
@@ -388,7 +400,8 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
             x = stack(x, pp["mamba_b"])
         return x, None
 
-    return jax.lax.scan(period, x, params["periods"])[0]
+    with jax.named_scope("stack"):
+        return jax.lax.scan(period, x, params["periods"])[0]
 
 
 def _tied_head(params):

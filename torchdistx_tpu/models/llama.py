@@ -376,20 +376,28 @@ def _build_block(
     def block(x, lp):
         bb, s = x.shape[0], x.shape[1]
         with jax.named_scope("attn"):
-            pos = (
-                jnp.arange(s)[None] if positions is None else positions
-            )
-            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-            q = (h @ lp["wq"]).reshape(bb, s, cfg.n_heads, cfg.head_dim)
-            k = (h @ lp["wk"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
-            v = (h @ lp["wv"]).reshape(bb, s, cfg.n_kv_heads, cfg.head_dim)
-            q = _rope(q, pos, cfg.rope_theta)
-            k = _rope(k, pos, cfg.rope_theta)
+            with jax.named_scope("norm"):
+                h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            with jax.named_scope("proj_in"):
+                q = (h @ lp["wq"]).reshape(bb, s, cfg.n_heads, cfg.head_dim)
+                k = (h @ lp["wk"]).reshape(
+                    bb, s, cfg.n_kv_heads, cfg.head_dim
+                )
+                v = (h @ lp["wv"]).reshape(
+                    bb, s, cfg.n_kv_heads, cfg.head_dim
+                )
+            with jax.named_scope("rope"):
+                pos = (
+                    jnp.arange(s)[None] if positions is None else positions
+                )
+                q = _rope(q, pos, cfg.rope_theta)
+                k = _rope(k, pos, cfg.rope_theta)
             attn = attention(
                 q, k, v, causal=True, impl=attn_impl, mesh=mesh,
                 seq_axis=seq_axis, pre_permuted=pre_permuted,
             )
-            x = x + attn.reshape(bb, s, -1) @ lp["wo"]
+            with jax.named_scope("proj_out"):
+                x = x + attn.reshape(bb, s, -1) @ lp["wo"]
         with jax.named_scope("mlp"):
             h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
             gated = jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])
@@ -503,8 +511,11 @@ def _forward_hidden(
             n_microbatches=n_microbatches,
         )
     else:
-        x, _ = jax.lax.scan(lambda h, lp: (body(h, lp), None), x,
-                            params["layers"], unroll=cfg._unroll)
+        # ``stack``: the scan's own work (a layer's weights sliced out,
+        # the stacked gradients and residuals written in its transpose).
+        with jax.named_scope("stack"):
+            x, _ = jax.lax.scan(lambda h, lp: (body(h, lp), None), x,
+                                params["layers"], unroll=cfg._unroll)
     return x
 
 

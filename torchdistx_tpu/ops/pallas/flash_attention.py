@@ -1194,7 +1194,8 @@ def _rows(x):
     remat policy saves: stacked over the layers of a scan it is lane-dense,
     where the kernel's 64-wide minor dimension is tiled out to 128."""
     b, h, s, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+    with jax.named_scope("relayout"):
+        return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
 # The primal: runs only where nothing differentiates the call (inference,
@@ -1219,7 +1220,9 @@ def _fa_fwd(q, k, v, s, causal, interpret, window):
     out = checkpoint_name(_rows(out), "flash_out")
     # Without the kernel's unit minor dimension, which a stacked residual
     # would carry tiled out to 128 lanes.
-    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    with jax.named_scope("relayout"):
+        lse = lse[..., 0]
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
@@ -1230,13 +1233,16 @@ def _fa_bwd(s, causal, interpret, window, res, do):
     do = do.reshape(b, s_pad, hq, d)
     # delta = rowsum(do * out), per query row and head: taken in the layout
     # both arrive in, so ``out`` is never carried back to the kernel's.
-    delta = jnp.sum(
-        do.astype(jnp.float32)
-        * out.reshape(b, s_pad, hq, d).astype(jnp.float32),
-        axis=-1,
-    ).transpose(0, 2, 1)[..., None]  # (B, Hq, S_pad, 1)
+    with jax.named_scope("delta"):
+        delta = jnp.sum(
+            do.astype(jnp.float32)
+            * out.reshape(b, s_pad, hq, d).astype(jnp.float32),
+            axis=-1,
+        ).transpose(0, 2, 1)[..., None]  # (B, Hq, S_pad, 1)
+    with jax.named_scope("relayout"):
+        lse, do = lse[..., None], do.transpose(0, 2, 1, 3)
     return _fa_backward(
-        q, k, v, delta, lse[..., None], do.transpose(0, 2, 1, 3), s,
+        q, k, v, delta, lse, do, s,
         causal=causal, interpret=interpret, window=window,
     )
 
@@ -1286,15 +1292,18 @@ def flash_attention(
         _telemetry.counter("attention.flash_window", window=window).add()
     d = v.shape[-1]
     s_pad = _pad_len(s)
-    # Kernel layout is (B, H, S, D).
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    if s_pad != s:
-        pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
-        qt, kt, vt = (jnp.pad(t, pad) for t in (qt, kt, vt))
+    # Kernel layout is (B, H, S, D).  ``relayout``: what the kernels' doors
+    # cost, here, in ``_rows`` and around ``lse`` and ``do`` in the VJP.
+    with jax.named_scope("relayout"):
+        qt = q.transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
+        if s_pad != s:
+            pad = ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
+            qt, kt, vt = (jnp.pad(t, pad) for t in (qt, kt, vt))
     out = _fa(qt, kt, vt, s, causal, interpret, window)  # (B, S_pad, Hq*D)
-    return out[:, :s].reshape(b, s, hq, d)
+    with jax.named_scope("relayout"):
+        return out[:, :s].reshape(b, s, hq, d)
 
 
 # ---------------------------------------------------------------------------

@@ -183,6 +183,14 @@ def _columns(x):
     return x.astype(jnp.float32)[..., None]
 
 
+def _doors(a, b, c, d):
+    """``A``, ``B``, ``C``, ``D`` as the kernels take them, under the scope
+    ``relayout`` (with ``backward``'s results on their way out: what the
+    kernels' doors cost beside the kernels)."""
+    with jax.named_scope("relayout"):
+        return a.T, _columns(b), _columns(c), d.astype(jnp.float32)[None]
+
+
 def _params(interpret):
     import jax.experimental.pallas.tpu as pltpu
 
@@ -225,7 +233,7 @@ def forward(u, dt, a, b, c, d, *, chunk, interpret):
         + [pltpu.VMEM((chunk, bc), jnp.float32)] * 3,
         name="ssm_scan_fwd",
         **_params(interpret),
-    )(u, dt, a.T, _columns(b), _columns(c), d.astype(jnp.float32)[None])
+    )(u, dt, *_doors(a, b, c, d))
 
 
 def backward(u, dt, a, b, c, d, starts, dy, *, chunk, interpret):
@@ -273,9 +281,9 @@ def backward(u, dt, a, b, c, d, starts, dy, *, chunk, interpret):
         + [pltpu.VMEM((chunk, n, width), jnp.float32)] * 2,
         name="ssm_scan_bwd",
         **_params(interpret),
-    )(u, dt, a.T, _columns(b), _columns(c), d.astype(jnp.float32)[None],
-      starts, dy)
-    return (
-        du, ddt, da.sum(0).transpose(1, 0, 2).reshape(n, ch).T,
-        db[..., 0], dc[..., 0], dd.sum(0).reshape(ch),
-    )
+    )(u, dt, *_doors(a, b, c, d), starts, dy)
+    with jax.named_scope("relayout"):
+        return (
+            du, ddt, da.sum(0).transpose(1, 0, 2).reshape(n, ch).T,
+            db[..., 0], dc[..., 0], dd.sum(0).reshape(ch),
+        )

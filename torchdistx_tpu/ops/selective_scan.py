@@ -197,10 +197,11 @@ def _scan_bwd(chunk, impl, interpret, res, dy):
         dyf, uf = dy.astype(jnp.float32), u.astype(jnp.float32)
         du = du + d.astype(jnp.float32) * dyf
         dd = (dyf * uf).sum((0, 1))
-    return (
-        du.astype(u.dtype), ddt.astype(dt.dtype), da.astype(a.dtype),
-        db.astype(b.dtype), dc.astype(c.dtype), dd.astype(d.dtype),
-    )
+    with jax.named_scope("relayout"):
+        return (
+            du.astype(u.dtype), ddt.astype(dt.dtype), da.astype(a.dtype),
+            db.astype(b.dtype), dc.astype(c.dtype), dd.astype(d.dtype),
+        )
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)
@@ -235,10 +236,14 @@ def selective_scan(u, delta, A, B, C, D, *, impl: str = "auto",
     if impl == "pallas":
         _telemetry.counter("ssm.scan", interpret=str(interpret).lower()).add()
     pad = n * chunk - t
-    if pad:
-        # delta = 0: the state passes unchanged, and the rows are cut off.
-        u, delta, B, C = (
-            jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (u, delta, B, C)
-        )
-    y = _scan(u, delta, A.astype(jnp.float32), B, C, D, chunk, impl, interpret)
-    return y[:, :t] if pad else y
+    with jax.named_scope("relayout"):
+        if pad:
+            # delta = 0: the state passes unchanged, and the rows are cut off.
+            u, delta, B, C = (
+                jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                for x in (u, delta, B, C)
+            )
+        A = A.astype(jnp.float32)
+    y = _scan(u, delta, A, B, C, D, chunk, impl, interpret)
+    with jax.named_scope("relayout"):
+        return y[:, :t] if pad else y

@@ -111,7 +111,8 @@ def pipeline_forward(
             def scan_block(h, lp):
                 return block_fn(h, lp), None
 
-            out, _ = jax.lax.scan(scan_block, act, params_local)
+            with jax.named_scope("stack"):  # the scan's own work
+                out, _ = jax.lax.scan(scan_block, act, params_local)
             return out
 
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -276,7 +277,8 @@ def pipeline_value_and_grad(
     last_stash_slots, last_n_ticks = n_slots, n_ticks
 
     def stage_fn(lp, h):
-        out, _ = jax.lax.scan(lambda c, l: (block_fn(c, l), None), h, lp)
+        with jax.named_scope("stack"):  # the scan's own work
+            out, _ = jax.lax.scan(lambda c, l: (block_fn(c, l), None), h, lp)
         return out
 
     f32 = jnp.float32

@@ -14,6 +14,17 @@ mysteriously cratered tok/s.  Three labeled families make both visible:
 * ``compile.time_s{program=}`` — compile-duration histogram,
 * ``compile.recompiles{program=}`` — compiles beyond a program's first.
 
+Four more say where a program's build time went before and around that
+compile, each from the JAX event of the same phase and attributed the
+same way (the compiling thread's ambient :func:`program` scope, else
+``other``): ``compile.trace_s{program=}`` (tracing to a jaxpr; a jit
+traced inside another counts its own time once, not again in its
+caller's), ``compile.lower_s{program=}`` (jaxpr to MLIR module),
+``compile.cache_load_s{program=}`` (reading an executable out of the
+persistent cache; ``compile.time_s`` of the same compile holds it too,
+for JAX times the backend compile around the cache lookup) and the
+counter ``compile.cache_hits{program=}``.
+
 Attribution is two-layered.  :class:`JitProgram` wraps a jitted callable
 under a stable label and detects (re)compiles exactly, via the jit
 cache-size delta around each call — donation, tracing, and monkeypatched
@@ -121,6 +132,14 @@ _tls = threading.local()
 # The jax.monitoring duration event that means "XLA compiled a program"
 # (a persistent-cache load fires it too: it wraps compile_or_get_cached).
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# The other phases of a program's build: duration event -> histogram family.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_PHASES = {
+    _TRACE_EVENT: "compile.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load_s",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _install_lock = threading.Lock()
 _monitoring = False  # listener registered successfully
@@ -187,15 +206,43 @@ def _current_scope() -> Optional[_Scope]:
     return stack[-1] if stack else None
 
 
+def _own_trace_s(duration_s: float) -> float:
+    """A trace's seconds less those of the traces nested in it.  JAX
+    times every jit's tracing, a jit called inside another's too, and
+    reports each at its END, so the nested ones are this thread's most
+    recent reports that began after this one did.  The sum over a
+    program's traces is then the wall time it spent tracing."""
+    now = time.perf_counter()
+    start = now - duration_s
+    done = getattr(_tls, "traces", None)
+    if done is None:
+        done = _tls.traces = []
+    nested = 0.0
+    while done and done[-1][0] >= start:
+        nested += done.pop()[1]
+    done.append((start, duration_s))
+    if len(done) > 1 << 15:  # top-level traces nothing will enclose
+        del done[: 1 << 14]
+    return duration_s - nested
+
+
 def _on_duration_event(name: str, duration_s: float, **kwargs) -> None:
     """The jax.monitoring listener: every backend compile lands here,
     on the compiling thread, and is attributed to that thread's ambient
-    scope (``other`` when none).  Never raises — telemetry must not
-    fail the compile it observes."""
+    scope (``other`` when none); so does every trace, lowering and
+    persistent-cache load (``_PHASES``).  Never raises — telemetry must
+    not fail the compile it observes."""
     try:
-        if name != _COMPILE_EVENT:
-            return
         scope = _current_scope()
+        if name != _COMPILE_EVENT:
+            family = _PHASES.get(name)
+            if family is not None:
+                if name == _TRACE_EVENT:
+                    duration_s = _own_trace_s(duration_s)
+                _core.histogram(
+                    family, program=scope.label if scope else "other"
+                ).observe(max(0.0, float(duration_s)))
+            return
         if scope is not None:
             scope.counted += 1
             record_compile(
@@ -208,8 +255,21 @@ def _on_duration_event(name: str, duration_s: float, **kwargs) -> None:
         pass
 
 
+def _on_event(name: str, **kwargs) -> None:
+    """The jax.monitoring listener of plain events: a program served by
+    the persistent cache counts under the ambient scope.  Never raises."""
+    try:
+        if name == _CACHE_HIT_EVENT:
+            scope = _current_scope()
+            _core.counter(
+                "compile.cache_hits", program=scope.label if scope else "other"
+            ).add()
+    except Exception:  # noqa: BLE001
+        pass
+
+
 def install_monitoring() -> bool:
-    """Register the compile-duration listener with ``jax.monitoring``
+    """Register the compile listeners with ``jax.monitoring``
     (idempotent; False when this JAX has no monitoring API).  Hooked by
     ``ensure_compilation_cache`` and the serving engine, so either
     entry point arms the observatory."""
@@ -223,6 +283,7 @@ def install_monitoring() -> bool:
             from jax import monitoring as _jm
 
             _jm.register_event_duration_secs_listener(_on_duration_event)
+            _jm.register_event_listener(_on_event)
             _monitoring = True
         except Exception:  # noqa: BLE001 — no jax / old jax: fallback timing
             return False
@@ -481,9 +542,12 @@ def hlo_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     the paths of the instructions fused into it — one fused operation
     may span scopes (XLA fuses AdamW's update into the non-finite
     guard's select and gives the fusion the select's path), so the
-    reader of the map decides whose time it is."""
+    reader of the map decides whose time it is.  A fusion fused into
+    another brings the paths it holds along: XLA:TPU wraps an expanded
+    scatter in two nameless levels, and only the innermost instructions
+    still say whose it is."""
     own: Dict[str, str] = {}
-    inside: Dict[str, list] = {}  # computation -> its instructions' paths
+    inside: Dict[str, list] = {}  # computation -> its instructions
     calls: Dict[str, str] = {}  # fusion -> the computation it calls
     comp = None
     for line in hlo_text.splitlines():
@@ -497,11 +561,19 @@ def hlo_scopes(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
         name = m.group(1)
         path = _HLO_OP_NAME.search(line, m.end())
         own[name] = path.group(1) if path else ""
-        inside.setdefault(comp, []).append(own[name])
+        inside.setdefault(comp, []).append(name)
         if _HLO_FUSION.search(line, m.end()):
             calls[name] = _HLO_CALLS.search(line, m.end()).group(1)
+
+    def held(fusion):
+        for name in inside.get(calls[fusion], ()):
+            if own[name]:
+                yield own[name]
+            if name in calls:
+                yield from held(name)
+
     return {
-        name: (path, *(p for p in inside.get(calls.get(name), ()) if p))
+        name: (path, *(held(name) if name in calls else ()))
         for name, path in own.items()
     }
 
