@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import telemetry
-from torchdistx_tpu.models import convert, deepseek_v3 as ds
+from torchdistx_tpu.models import convert, deepseek_v3 as ds, llama as llama_mod
 from torchdistx_tpu.ops import routed_experts as routed_mod
 from torchdistx_tpu.ops.routed_experts import routed_experts
 
@@ -371,6 +371,159 @@ def test_no_worst_case_buffer_when_a_share_is_held(program):
     assert not wide, wide
 
 
+def _rope_gathered(x, cos, sin):
+    """The oracle: the form the family had before the rotation was done in
+    place.  The pairs' first members gathered into the first half, the
+    second into the other, then llama's half-split rotation."""
+    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x = jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
+    return llama_mod._rope_apply(x, cos, sin)
+
+
+def _interleaved(x):
+    """``[first members | second members]`` -> the pairs where they lay."""
+    half = x.shape[-1] // 2
+    return jnp.stack([x[..., :half], x[..., half:]], axis=-1).reshape(x.shape)
+
+
+def _qkv_gathered(x, lp, cfg):
+    """The oracle: q, k and v as ``_attn`` assembled them before, by slices
+    and concatenations of the activations."""
+    b, s, _ = x.shape
+    nope, rope, H = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.n_heads
+    h = llama_mod._rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    q = (h @ lp["wq"]).reshape(b, s, H, cfg.qk_dim)
+    kva = h @ lp["wkv_a"]
+    c = llama_mod._rmsnorm(kva[..., : cfg.kv_rank], lp["kv_norm"], cfg.norm_eps)
+    kv = (c @ lp["wkv_b"]).reshape(b, s, H, nope + cfg.v_dim)
+    cos, sin = llama_mod._rope_tables(
+        jnp.arange(s)[None], cfg.rope_theta, rope // 2, x.dtype
+    )
+    q_rope = _rope_gathered(q[..., nope:], cos, sin)
+    k_rope = _rope_gathered(kva[..., cfg.kv_rank:][:, :, None, :], cos, sin)
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, H, rope))], axis=-1
+    )
+    return q, k, kv[..., nope:]
+
+
+both_dtypes = pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"]
+)
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+@both_dtypes
+def test_rope_in_place_is_the_gathered_rotation_bit_for_bit(dtype):
+    """q over its heads and the one-head k: every element the in-place
+    rotation gives is the gathered form's, in interleaved order; q k^T of
+    the two forms differs by the order of a float32 sum."""
+    rope, s = 64, 64
+    kq, kk = jax.random.split(jax.random.PRNGKey(3))
+    q = jax.random.normal(kq, (2, s, 4, rope), jnp.float32).astype(dtype)
+    k = jax.random.normal(kk, (2, s, 1, rope), jnp.float32).astype(dtype)
+    cos, sin = llama_mod._rope_tables(jnp.arange(s)[None], 1e4, rope // 2, dtype)
+    twice = [jnp.repeat(t, 2, axis=-1) for t in (cos, sin)]
+    swap = ds._pair_swap(rope, dtype)
+    got_q = ds._rope_in_place(q, *twice, swap)
+    got_k = ds._rope_in_place(k, *twice, swap)
+    want_q, want_k = _rope_gathered(q, cos, sin), _rope_gathered(k, cos, sin)
+    assert _same_bits(got_q, _interleaved(want_q))
+    assert _same_bits(got_k, _interleaved(want_k))
+    assert not _same_bits(got_q, q)
+
+    def scores(q, k):
+        return jnp.einsum(
+            "bqhd,bkhd->bhqk", q.astype(jnp.float32),
+            jnp.broadcast_to(k, q.shape).astype(jnp.float32),
+            precision="highest",
+        )
+
+    np.testing.assert_allclose(
+        np.asarray(scores(got_q, got_k)), np.asarray(scores(want_q, want_k)),
+        atol=2e-5, rtol=0,
+    )
+
+
+@both_dtypes
+def test_attn_hands_the_kernels_the_q_k_v_it_assembled_by_slices(
+    dtype, monkeypatch
+):
+    """What ``_attn`` passes to ``attention`` against the old assembly on
+    the same layer: q's no-rope columns pass the whole-width rotation
+    unchanged, the rope columns of q and k are the gathered form's,
+    re-interleaved, and k's no-rope half and v are the joint product's."""
+    cfg = dataclasses.replace(ds.deepseek_v3_test(), dtype=dtype)
+    nope = cfg.qk_nope_dim
+    params = ds.init_params(jax.random.PRNGKey(0), cfg)
+    lp = jax.tree.map(lambda a: a[1], params["moe_layers"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 48, cfg.dim), jnp.float32)
+    x = x.astype(dtype)
+    seen = {}
+
+    def attention(q, k, v, **kw):
+        seen.update(q=q, k=k, v=v)
+        return jnp.zeros(v.shape, v.dtype)
+
+    monkeypatch.setattr(ds, "attention", attention)
+    ds._attn(x, lp, cfg, mesh=None, attn_impl="jnp")
+    q, k, v = _qkv_gathered(x, lp, cfg)
+    assert _same_bits(seen["q"][..., :nope], q[..., :nope])
+    assert _same_bits(seen["q"][..., nope:], _interleaved(q[..., nope:]))
+    assert _same_bits(seen["k"][..., nope:], _interleaved(k[..., nope:]))
+    # The CPU's matrix product sums a column in another order when the
+    # weight has other columns beside it (the chip's does not): an ulp.
+    for got, want in ((seen["k"][..., :nope], k[..., :nope]), (seen["v"], v)):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert got.shape == want.shape
+        ulp = float(jnp.finfo(dtype).eps) * np.abs(want).max()
+        assert np.abs(got - want).max() <= 2 * ulp
+
+
+@both_dtypes
+def test_latent_cut_in_the_weights_gives_the_joint_products_halves(dtype):
+    """``c @ wkv_b`` with the weight cut at each head's column ``nope``
+    against the joint product cut in the activation, bit for bit: small
+    whole numbers, so every sum is exact in any order and only a wrong
+    column could differ."""
+    b, s, rank, heads, nope, v_dim = 2, 24, 16, 4, 24, 16
+    kc, kw = jax.random.split(jax.random.PRNGKey(5))
+    c = jax.random.randint(kc, (b, s, rank), -3, 4).astype(dtype)
+    w = jax.random.randint(kw, (rank, heads * (nope + v_dim)), -3, 4).astype(dtype)
+    kv = (c @ w).reshape(b, s, heads, nope + v_dim)
+    assert float(jnp.abs(kv.astype(jnp.float32)).max()) <= 256  # exact in bf16
+    k_nope, v = ds._latent_up(c, w, heads, nope)
+    assert _same_bits(k_nope, kv[..., :nope])
+    assert _same_bits(v, kv[..., nope:])
+    assert len(np.unique(np.asarray(kv, np.float32))) > 16
+
+
+def test_no_activation_is_cut_or_gathered():
+    """Structure: neither the loss nor its gradient holds an array whose
+    minor dimension is a pair (the interleave gather made ``(..., rope/2,
+    2)``) or the joint ``kv (B, S, H, nope + v_dim)`` activation that was
+    sliced twice."""
+    cfg = ds.deepseek_v3_test()
+    params = ds.abstract_params(cfg)
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        jax.value_and_grad(
+            lambda p, t: ds.loss_fn(p, t, t, cfg, attn_impl="jnp")[0]
+        )
+    )(params, tok)
+    shapes = {a.shape for a in _avals(jaxpr.jaxpr) if getattr(a, "shape", ())}
+    assert (2, 32, cfg.n_heads, cfg.qk_dim) in shapes  # q and k are there
+    pairs = [s for s in shapes if s[-2:] == (cfg.qk_rope_dim // 2, 2)]
+    assert not pairs, pairs
+    assert (2, 32, cfg.n_heads, cfg.qk_nope_dim + cfg.v_dim) not in shapes
+    assert (2, 32, cfg.n_heads * (cfg.qk_nope_dim + cfg.v_dim)) not in shapes
+
+
 def test_absent_experts_are_never_materialized():
     """(f) the paper's path: the layer is constructed with every expert,
     fake; the absent ones are dropped; materialization fills the share's
@@ -418,21 +571,29 @@ def test_absent_experts_are_never_materialized():
     )
 
 
-def test_scopes_and_counters():
+def test_scopes_and_counters(monkeypatch):
     """The names a trace is read by: ``attn`` with the kernels under it,
     ``mlp``, ``moe/router|dispatch|experts|combine|shared``; the host
-    counters of the share."""
+    counters of the share and of the rotation's form."""
     sizes, (_, cfg) = _sizes(), family.native(_sizes(), jnp.float32)
     params = jax.eval_shape(lambda: ds.init_params(jax.random.PRNGKey(0), cfg))
     tok = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    traced, attn = [], ds._attn
+    monkeypatch.setattr(
+        ds, "_attn", lambda *a, **kw: traced.append(1) or attn(*a, **kw)
+    )
     c0 = telemetry.counters()
     text = jax.jit(
         jax.grad(lambda p, t: ds.loss_fn(p, t, t, cfg, attn_impl="pallas")[0])
     ).lower(params, tok).as_text(debug_info=True)
     c1 = telemetry.counters()
     for scope in ("attn", "mlp", "moe/router", "moe/dispatch", "moe/experts",
-                  "moe/combine", "moe/shared"):
+                  "moe/combine", "moe/shared", "attn/proj_in", "attn/rope",
+                  "attn/concat"):
         assert f"{scope}/" in text, scope
+    # the form of the rotation a program holds: one count a traced ``_attn``
+    in_place = "attn.rope{form=in_place}"
+    assert c1[in_place] - c0.get(in_place, 0) == len(traced) == 2
     assert "attn/flash_fwd/" in text and "flash_bwd_fused/" in text
     held = c1["moe.experts_held"] - c0.get("moe.experts_held", 0)
     total = c1["moe.experts_total"] - c0.get("moe.experts_total", 0)

@@ -220,15 +220,49 @@ def num_params(cfg: DeepseekV3Config) -> int:
 # Forward
 
 
-def _rope_interleaved(x, cos, sin):
-    """RoPE on interleaved pairs ``(x0, x1), (x2, x3), ...`` as
-    ``apply_rotary_pos_emb_interleave`` has it: the pairs' first members
-    gathered into the first half, the second into the other, then the
-    half-split rotation (q and k come out in the same order, so their
-    products are those of the interleaved form)."""
-    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    x = jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
-    return llama_mod._rope_apply(x, cos, sin)
+def _pair_swap(width: int, dtype):
+    """``P (width, width)`` that turns each interleaved pair a quarter:
+    ``(x @ P)[2i] = -x[2i+1]``, ``(x @ P)[2i+1] = x[2i]``.  Every output is
+    one input times +-1, so the product is exact in any dtype.  Built from
+    an iota, so XLA folds it and a remat saves no constant for it."""
+    col = jnp.arange(width)
+    partner = col[:, None] == (col ^ 1)[None, :]
+    return (partner * jnp.where(col % 2 == 1, 1, -1)[None, :]).astype(dtype)
+
+
+def _each_twice(t):
+    """``(..., n) -> (..., 2n)``, each column twice side by side, by a
+    product with zeros and ones: exact, and no ``(..., n, 2)`` array on the
+    way, which ``jnp.repeat`` makes."""
+    n = t.shape[-1]
+    twice = jnp.arange(n)[:, None] == jnp.arange(2 * n) // 2
+    return jnp.matmul(
+        t, twice.astype(t.dtype), precision=jax.lax.Precision.HIGHEST
+    )
+
+
+def _rope_in_place(x, cos, sin, swap):
+    """RoPE on interleaved pairs ``(x0, x1), (x2, x3), ...`` where they lie
+    (``apply_rotary_pos_emb_interleave``'s rotation without its gather):
+    ``out[2i] = x[2i] cos_i - x[2i+1] sin_i``, ``out[2i+1] = x[2i+1] cos_i +
+    x[2i] sin_i``, with ``cos``, ``sin`` holding each angle twice and
+    ``swap`` from :func:`_pair_swap`.  q and k come out in the same order,
+    so their products are those of the interleaved form."""
+    turned = jnp.matmul(x, swap, precision=jax.lax.Precision.HIGHEST)
+    return x * cos + turned * sin
+
+
+def _latent_up(c, wkv_b, n_heads: int, nope: int):
+    """``(k_nope, v)`` per head from the normed latent ``c (B, S, rank)``,
+    each from a product of its own: the weight ``(rank, H * (nope + v))`` is
+    cut at each head's column ``nope`` (megabytes), never the activation
+    ``(B, S, H, nope + v)``.  Every output column is the dot product the
+    joint ``c @ wkv_b`` has in that place."""
+    w = wkv_b.reshape(wkv_b.shape[0], n_heads, -1)
+    return (
+        jnp.einsum("bsr,rhd->bshd", c, w[..., :nope]),
+        jnp.einsum("bsr,rhd->bshd", c, w[..., nope:]),
+    )
 
 
 def _swiglu(h, w_gate, w_up, w_down):
@@ -240,6 +274,10 @@ def _attn(x, lp, cfg: DeepseekV3Config, *, mesh, attn_impl):
     # (docs/observability.md, "Scopes inside the train step"); ``concat``
     # is latent attention's own.  What stays at ``attn`` is the kernels
     # and, inside ``attention``, their ``relayout`` and ``delta``.
+    # q, k and v are each written once, in the form the kernels take: what
+    # has to be cut apart is cut in a weight's columns, not in an
+    # activation (each slice or join of one is a pass in the forward, in
+    # the remat's forward and in the backward).
     b, s, _ = x.shape
     nope, rope, H = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.n_heads
     with jax.named_scope("attn"):
@@ -251,22 +289,29 @@ def _attn(x, lp, cfg: DeepseekV3Config, *, mesh, attn_impl):
             c = llama_mod._rmsnorm(
                 kva[..., : cfg.kv_rank], lp["kv_norm"], cfg.norm_eps
             )
-            kv = (c @ lp["wkv_b"]).reshape(b, s, H, nope + cfg.v_dim)
+            k_nope, v = _latent_up(c, lp["wkv_b"], H, nope)
         with jax.named_scope("rope"):
-            cos, sin = llama_mod._rope_tables(
+            _telemetry.counter("attn.rope", form="in_place").add()
+            # a pair's two members turn by the same angle
+            cos, sin = map(_each_twice, llama_mod._rope_tables(
                 jnp.arange(s)[None], cfg.rope_theta, rope // 2, x.dtype
+            ))
+            swap = _pair_swap(rope, x.dtype)
+            k_rope = _rope_in_place(
+                kva[..., cfg.kv_rank:][:, :, None, :], cos, sin, swap
             )
-            q_rope = _rope_interleaved(q[..., nope:], cos, sin)
-            k_rope = _rope_interleaved(
-                kva[..., cfg.kv_rank:][:, :, None, :], cos, sin
+            # q over its whole width in one expression: on the no-rope
+            # columns cos is 1, sin and the swap 0, and they come out as
+            # they went in.  Nothing activation-sized is cut or joined.
+            lead = ((0, 0), (0, 0), (0, 0), (nope, 0))
+            q = _rope_in_place(
+                q, jnp.pad(cos, lead, constant_values=1), jnp.pad(sin, lead),
+                jnp.pad(swap, ((nope, 0), (nope, 0))),
             )
         with jax.named_scope("concat"):
-            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
             k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, H, rope))],
-                axis=-1,
+                [k_nope, jnp.broadcast_to(k_rope, (b, s, H, rope))], axis=-1
             )
-            v = kv[..., nope:]
         a = attention(q, k, v, causal=True, impl=attn_impl, mesh=mesh)
         with jax.named_scope("proj_out"):
             return x + a.reshape(b, s, H * cfg.v_dim) @ lp["wo"]
