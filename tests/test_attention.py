@@ -837,6 +837,27 @@ class TestFlashWindow:
         # ... and the mask is not the plain triangle's
         assert not jnp.allclose(masked(q, k, v), mha_reference(q, k, v), atol=1e-3)
 
+    @pytest.mark.parametrize("window", [80, None])  # banded, full
+    @pytest.mark.parametrize("backward", ["fused", "pair"])
+    def test_a_group_of_seven(self, window, backward, monkeypatch):
+        """14 query heads on 2 key/value heads, a group that is no power
+        of two: the ``(group, q block)`` grid axis of the kv-major
+        kernels is ``7 * nq``.  Window and full kernels, forward and both
+        backward forms, over several blocks and a padded tail, against the
+        masked reference."""
+        counted = self._blocks(monkeypatch, backward)
+        if window is None:
+            counted = counted.removeprefix("win_")
+        q, k, v = _qkv(b=1, s=200, hq=14, hkv=2, d=16)
+        w = jax.random.normal(jax.random.PRNGKey(7), q.shape)
+        flash = functools.partial(flash_attention, interpret=True, window=window)
+        masked = functools.partial(mha_reference, window=window)
+        assert jnp.allclose(flash(q, k, v), masked(q, k, v), atol=2e-5)
+        got, built = _flash_bwd_built(lambda: _grads(flash, q, k, v, w))
+        assert built == {counted: 1}
+        for name, g, r in zip("qkv", got, _grads(masked, q, k, v, w)):
+            assert jnp.allclose(g, r, atol=1e-4), name
+
     @pytest.mark.parametrize("window", [200, 1000])
     def test_a_window_that_holds_the_sequence_is_plain_causal(
         self, window, monkeypatch

@@ -7,6 +7,7 @@ CPU, float32, seeded: values and counts only.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -225,23 +226,30 @@ def _dense_held_sum(h, router, eg, eu, ed, *, top_k, bias, scale, first):
 
 
 # name: (top_k, experts every token is sent to (None: the router's own
-# choice), the row bound forced, held assignments M, chunks = ceil(M / R))
+# choice), the row bound R forced, the row tile forced (an overflow chunk
+# holds R2 = an eighth of R up to the tile, at most R), held assignments M,
+# chunks = the first + ceil((M - R) / R2))
 CHUNK_CASES = {
-    "no_chunk_all_absent": (2, (0, 6), 64, 0, 0),
-    "one_chunk": (2, None, 128, 79, 1),
-    "two_chunks": (2, None, 64, 79, 2),
-    "every_token_to_one_held_expert": (1, (3,), 32, 96, 3),  # T*k / R
+    "no_chunk_all_absent": (2, (0, 6), 64, 512, 0, 0),
+    "one_chunk": (2, None, 128, 512, 79, 1),
+    "two_chunks": (2, None, 64, 512, 79, 2),  # R2 = R
+    "overflow_in_eighths": (2, None, 64, 8, 79, 3),  # R2 = 8: 64 + 8 + 7
+    "overflow_of_one_row": (2, None, 78, 2, 79, 2),  # R2 = 10
+    "every_token_to_one_held_expert": (1, (3,), 32, 512, 96, 3),  # T*k / R
+    "every_token_to_one_held_expert_in_eighths": (1, (3,), 32, 4, 96, 17),
 }
 
 
 @pytest.mark.parametrize("case", CHUNK_CASES)
 def test_row_chunks_cover_every_held_row(case, monkeypatch):
-    """The sorted side runs ``ceil(M / R)`` chunks of ``R`` rows, counted
+    """The sorted side runs a chunk of ``R`` rows and ``ceil((M - R) /
+    R2)`` of ``R2`` rows (which add their rows to their tokens, where the
+    first gathers every token's), counted
     from the routing, and whatever the count the layer and its gradients
     (h, router, the three expert matrices, and the routing weights
     themselves through a scale a token) are the dense per-token sum over
     the held choices; ``stats["row_chunks"]`` says how many ran."""
-    top_k, picked, bound, m, chunks = CHUNK_CASES[case]
+    top_k, picked, bound, tile, m, chunks = CHUNK_CASES[case]
     t, d, f, e, first = 96, 16, 8, 8, 2  # held: experts 2..5
     ks = jax.random.split(jax.random.PRNGKey(6), 6)
     h = jax.random.normal(ks[0], (t, d))
@@ -253,6 +261,8 @@ def test_row_chunks_cover_every_held_row(case, monkeypatch):
     if picked is not None:
         bias = bias.at[jnp.asarray(picked)].set(50.0)
     monkeypatch.setattr(routed_mod, "_row_bound", lambda *shape: bound)
+    monkeypatch.setattr(routed_mod, "_ROW_TILE", tile)
+    tail = routed_mod._tail_rows(bound)
     kw = dict(top_k=top_k, bias=bias)
 
     # ``scale`` a token: its gradient is the routing weights' own, summed
@@ -277,7 +287,8 @@ def test_row_chunks_cover_every_held_row(case, monkeypatch):
     grads, (out, stats) = jax.jit(grad(layer))(*args)
     want_grads, want = grad(dense)(*args)
     assert int(stats["local_assignments"]) == m
-    assert int(stats["row_chunks"]) == chunks == -(-m // bound)
+    assert int(stats["row_chunks"]) == chunks
+    assert chunks == min(m, 1) + -(-max(m - bound, 0) // tail)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=0)
     for name, g, g_want in zip(
         ("h", "router", "e_gate", "e_up", "e_down", "routing weights"),
@@ -288,8 +299,9 @@ def test_row_chunks_cover_every_held_row(case, monkeypatch):
         assert not any(np.asarray(g).any() for g in grads)
 
 
-def test_a_chunks_backward_is_the_vjp_of_its_three_products():
-    """bfloat16: the written-out backward of a chunk (six grouped
+@pytest.mark.parametrize("unit", ["silu", "relu"])
+def test_a_chunks_backward_is_the_vjp_of_its_three_products(unit):
+    """bfloat16, either gated unit: the written-out backward of a chunk (six grouped
     products from the kept gate and up products, the routing weights'
     gradient from the UNWEIGHTED transposed product) against ``jax.vjp``
     of the three forward products on the same rows, the form the layer
@@ -310,7 +322,7 @@ def test_a_chunks_backward_is_the_vjp_of_its_three_products():
 
     def experts(xs, eg, eu, ed):
         return routed_mod._down(
-            *routed_mod._gate_up(xs, eg, eu, sizes), ed, sizes
+            *routed_mod._gate_up(xs, eg, eu, sizes), ed, sizes, unit
         )
 
     y, pull = jax.vjp(experts, xs, eg, eu, ed)
@@ -318,9 +330,9 @@ def test_a_chunks_backward_is_the_vjp_of_its_three_products():
     want_dw = (y.astype(jnp.float32) * g).sum(-1)
 
     gate, up = routed_mod._gate_up(xs, eg, eu, sizes)
-    dxs, d_experts, dw = jax.jit(routed_mod._chunk_bwd)(
-        xs, gate, up, eg, eu, ed, sizes, g, w_rows
-    )
+    dxs, d_experts, dw = jax.jit(
+        functools.partial(routed_mod._chunk_bwd, unit=unit)
+    )(xs, gate, up, eg, eu, ed, sizes, g, w_rows)
     assert dxs.dtype == bf and dw.dtype == jnp.float32
     assert all(a.dtype == bf for a in d_experts)
     for name, got, want in zip(
@@ -330,6 +342,30 @@ def test_a_chunks_backward_is_the_vjp_of_its_three_products():
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         assert np.abs(want).max() > 0.1, name
         assert np.abs(got - want).max() <= 2.0 ** -6 * np.abs(want).max(), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [96, 1200])  # one token block, and three
+def test_an_overflow_chunk_adds_its_rows_to_their_tokens(t, dtype):
+    """``_add_rows``: the grouped one-hot product over the rows sorted by
+    token against a scatter-add, with tokens that own several rows of the
+    chunk, rows no held expert works on (left out, whatever they hold) and
+    a last token block that is not whole; the sums are float32 of the
+    rows' own dtype, so they are exact in both."""
+    r, d = 160, 24
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    rows = jax.random.normal(ks[0], (r, d)).astype(dtype)
+    at = jax.random.randint(ks[1], (r,), 0, t).at[:40].set(t - 1)
+    at = at.at[40:48].set(0)
+    held = jax.random.uniform(ks[2], (r,)) < 0.8
+    rows = jnp.where(held[:, None], rows, jnp.nan)  # never read
+    out = jax.random.normal(ks[3], (t, d))
+    got = jax.jit(routed_mod._add_rows)(out, at, rows, held)
+    want = out.at[at].add(
+        jnp.where(held[:, None], rows.astype(jnp.float32), 0.0)
+    )
+    assert got.dtype == jnp.float32 and got.shape == out.shape
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
 
 
 def _avals(jaxpr):
@@ -342,6 +378,62 @@ def _avals(jaxpr):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
                     yield from _avals(sub)
+
+
+@pytest.mark.parametrize(
+    "bound,tile,chunks", [(128, 512, 1), (32, 512, 3), (64, 4, 5)]
+)
+def test_relu_unit_and_a_router_input_apart_from_the_experts(
+    bound, tile, chunks, monkeypatch
+):
+    """float32, one chunk and several: the ``relu`` unit through the
+    hand-written backward, the tokens routed by ANOTHER tensor ``x`` than
+    the experts read (``route`` then ``routing=``), softmax over the six
+    selected logits; the layer and its gradients (``x``, ``h``, router,
+    the three expert matrices) against ``jax.grad`` of the plain layer."""
+    t, d, f, e, first, top_k = 96, 16, 8, 8, 2, 2  # held: experts 2..5
+    ks = jax.random.split(jax.random.PRNGKey(8), 7)
+    x, h, cot = (jax.random.normal(k, (t, d)) for k in ks[:3])
+    router = 0.3 * jax.random.normal(ks[3], (d, e))
+    eg, eu = (0.3 * jax.random.normal(k, (4, d, f)) for k in ks[4:6])
+    ed = 0.3 * jax.random.normal(ks[6], (4, f, d))
+    monkeypatch.setattr(routed_mod, "_row_bound", lambda *shape: bound)
+    monkeypatch.setattr(routed_mod, "_ROW_TILE", tile)
+
+    def layer(x, h, router, eg, eu, ed):
+        out, stats = routed_experts(
+            h, router, eg, eu, ed, top_k=top_k, first_held=first,
+            unit="relu", routing=routed_mod.route(x, router, top_k=top_k),
+        )
+        return (out * cot).sum(), (out, stats)
+
+    def plain(x, h, router, eg, eu, ed):
+        top, selected = jax.lax.top_k(x @ router, top_k)
+        w = jax.nn.softmax(top, axis=-1)  # over the SELECTED logits
+        out = jnp.zeros_like(h)
+        for i in range(eg.shape[0]):
+            wi = jnp.where(selected == first + i, w, 0.0).sum(-1, keepdims=True)
+            out += wi * ((jax.nn.relu(h @ eg[i]) * (h @ eu[i])) @ ed[i])
+        return (out * cot).sum(), out
+
+    args = (x, h, router, eg, eu, ed)
+    c0 = telemetry.counters()
+    grads, (out, stats) = jax.jit(
+        jax.grad(layer, argnums=tuple(range(6)), has_aux=True)
+    )(*args)
+    c1 = telemetry.counters()
+    want_grads, want = jax.grad(plain, argnums=tuple(range(6)), has_aux=True)(*args)
+    assert int(stats["row_chunks"]) == chunks
+    np.testing.assert_allclose(out, want, atol=2e-5, rtol=0)
+    for name, g, g_want in zip(
+        ("x", "h", "router", "e_gate", "e_up", "e_down"), grads, want_grads
+    ):
+        assert np.abs(np.asarray(g_want)).max() > 1e-3, name
+        np.testing.assert_allclose(g, g_want, atol=2e-5, rtol=1e-5, err_msg=name)
+    for name in ("moe.unit{kind=relu}", "moe.router_input{from=layer_input}"):
+        assert c1.get(name, 0) - c0.get(name, 0) == 1, name
+    with pytest.raises(ValueError, match="unknown unit"):
+        routed_experts(h, router, eg, eu, ed, top_k=top_k, unit="gelu")
 
 
 @pytest.mark.parametrize("program", ["layer", "gradient"])

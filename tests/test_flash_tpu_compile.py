@@ -133,6 +133,42 @@ def test_window_kernels_compile_for_a_v5e(one_chip, window, hq, hkv, kernels):
     assert sorted(n for n in names if n in text) == kernels
 
 
+@pytest.mark.parametrize(
+    "window,kernels",
+    [
+        (4096, ["flash_win_bwd_dkv", "flash_win_bwd_dq", "flash_win_fwd"]),
+        (None, ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
+    ],
+)
+def test_a_group_of_seven_at_16k_compiles_for_a_v5e(one_chip, window, kernels):
+    """SmallThinker-21BA3B's attention at its full 16,384 positions: 28
+    query heads on 4 key/value heads of 128, a group of SEVEN, the window
+    layers' band of 4,096 (5 kv blocks a q block) and the full layers'
+    triangle; a group's f32 dq is 7 x 16,384 x 128 x 4 = 58.7 MB, so both
+    backward passes are the streamed pair."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct(
+            (1, 16384, heads, 128), jnp.bfloat16, sharding=one_chip
+        )
+
+    def loss(q, k, v):
+        out = fa.flash_attention(
+            q, k, v, causal=True, interpret=False, window=window
+        )
+        return out.astype(jnp.float32).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(spec(28), spec(4), spec(4)).compile().as_text()
+    )
+    names = (
+        "flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv",
+        "flash_win_fwd", "flash_win_bwd_fused", "flash_win_bwd_dq",
+        "flash_win_bwd_dkv",
+    )
+    assert sorted(n for n in names if n in text) == kernels
+
+
 @pytest.mark.parametrize("seq,chunk", [(4096, 128), (8192, 128), (4096, 256)])
 def test_selective_scan_kernels_compile_for_a_v5e(one_chip, seq, chunk):
     """``ssm_scan_fwd`` and ``ssm_scan_bwd`` at AI21-Jamba2-3B's widths

@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
-from torchdistx_tpu.models import afmoe, deepseek_v3, gpt2, jamba, llama
+from torchdistx_tpu.models import (
+    afmoe, deepseek_v3, gpt2, jamba, llama, smallthinker,
+)
 from torchdistx_tpu.ops import routed_experts as routed_mod
 from torchdistx_tpu.ops.pallas.flash_attention import (
     _FUSED_BWD_DQ_VMEM,
@@ -38,6 +40,9 @@ FAMILIES = {
     "llama": (llama, llama.llama_test),
     "jamba": (jamba, jamba.jamba_test),
     **{name: entry[:2] for name, entry in ROUTED.items()},
+    # Every layer an expert layer whose router reads the layer's input:
+    # two periods of four layers under one scan.
+    "smallthinker": (smallthinker, smallthinker.smallthinker_test),
 }
 families = pytest.mark.parametrize("family", ["gpt2", "jamba", "llama"])
 routed = pytest.mark.parametrize("family", sorted(ROUTED))
@@ -130,7 +135,7 @@ def test_backward_holds_no_second_flash_fwd(family, seq, n_calls, monkeypatch):
 # family's three programs are fused apart the same way (up to 9.2e-7 of a
 # leaf's largest entry), the one that keeps the products from the one that
 # recomputes them too: op by op they agree bit for bit (the test below).
-FUSED_APART = dict.fromkeys(ROUTED, 2e-6)
+FUSED_APART = dict.fromkeys([*ROUTED, "smallthinker"], 2e-6)
 SUM_ORDER = {"jamba": 1e-6, **FUSED_APART}
 
 
@@ -321,9 +326,10 @@ def test_an_expert_layer_runs_no_grouped_product_twice(family, remat):
     """The gradient holds, an expert layer, the first chunk's 3 forward
     and 6 backward grouped products and no replay of the sorted side (12
     before the forward was kept, 15 where a norm follows the layer and the
-    remat replayed it whole); the loops over the chunks past the first
-    hold 3 forward and 2 recomputed + 6 backward.  Without remat the same
-    rule holds the two products as ordinary residuals."""
+    remat replayed it whole); the loops over the chunks of the overflow
+    hold 3 forward and 2 recomputed + 6 backward, and one more each way
+    that adds a chunk's rows to their tokens (``_add_rows``).  Without
+    remat the same rule holds the two products as ordinary residuals."""
     mod, make, layers = ROUTED[family]
     cfg = dataclasses.replace(make(), remat=remat)
     params = jax.eval_shape(lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
@@ -332,7 +338,7 @@ def test_an_expert_layer_runs_no_grouped_product_twice(family, remat):
         jax.grad(lambda p: mod.loss_fn(p, tok, tok, cfg, attn_impl="jnp")[0])
     )(params)
     assert _count(jaxpr.jaxpr, "ragged_dot_general") == {
-        "once": (3 + 6) * layers, "loop": (3 + 2 + 6) * layers,
+        "once": (3 + 6) * layers, "loop": (3 + 1 + 2 + 6 + 1) * layers,
     }
 
 
@@ -372,7 +378,7 @@ def test_an_expert_block_saves_its_products_and_its_result_where_needed(
     assert all("routed_experts.py" in why for _, why in kept)
 
 
-@routed
+@pytest.mark.parametrize("family", sorted([*ROUTED, "smallthinker"]))
 def test_kept_products_are_rows_of_the_order_the_forward_sorted(family):
     """bfloat16, where a replay's scores round otherwise than the
     forward's and a near-tied choice flips: the kept products are rows of

@@ -13,10 +13,15 @@ ships JAX-native model families designed for the TPU training stack:
   mixed, gated attention output, sandwich norms, routed experts beside a
   shared one (:mod:`torchdistx_tpu.models.afmoe_torch` is the published
   architecture as a torch module, for the deferred-init path).
+* :mod:`torchdistx_tpu.models.smallthinker` — every layer an expert
+  layer whose router reads the layer's input, before attention; ReGLU
+  experts; full and window attention layers 1 to 3
+  (:mod:`torchdistx_tpu.models.smallthinker_torch` for the deferred-init
+  path).
 * :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
   — routed-expert families (imported where used).
 """
 
-from . import afmoe, gpt2, jamba, llama  # noqa: F401
+from . import afmoe, gpt2, jamba, llama, smallthinker  # noqa: F401
 
-__all__ = ["afmoe", "gpt2", "jamba", "llama"]
+__all__ = ["afmoe", "gpt2", "jamba", "llama", "smallthinker"]

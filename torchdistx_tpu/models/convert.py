@@ -36,6 +36,7 @@ __all__ = [
     "jamba_params_from_hf",
     "afmoe_params_from_hf",
     "afmoe_params_to_hf",
+    "smallthinker_params_from_hf",
 ]
 
 
@@ -310,6 +311,37 @@ _AFMOE_MOE = {
 }
 
 
+def _layer_leaf(arrays, i, name):
+    """``layers.{i}.{name}.weight``, a linear transposed to ``(in, out)``."""
+    a = _get(arrays, f"layers.{i}.{name}.weight")
+    return a.T if a.ndim == 2 else a
+
+
+def _stack_layers(arrays, layers, table):
+    """``{leaf: (L, ...)}`` of the ``layers`` for a ``{leaf: published
+    name}`` table."""
+    return {
+        k: jnp.stack([_layer_leaf(arrays, i, name) for i in layers])
+        for k, name in table.items()
+    }
+
+
+def _stack_experts(arrays, layers, held, experts, table):
+    """``{e_<leaf>: (L, Eh, ...)}``: the ``held`` experts ``{experts}.0 ..
+    {experts}.{held-1}`` of each of ``layers``, as the module stands after
+    the others were dropped."""
+    return {
+        f"e_{k}": jnp.stack([
+            jnp.stack([
+                _layer_leaf(arrays, i, f"{experts}.{e}.{name}")
+                for e in range(held)
+            ])
+            for i in layers
+        ])
+        for k, name in table.items()
+    }
+
+
 def afmoe_params_from_hf(arrays: Dict[str, Any], cfg):
     """Flat AFMoE param dict (the published names, as
     :mod:`~torchdistx_tpu.models.afmoe_torch` has them) -> the two stacks
@@ -318,38 +350,19 @@ def afmoe_params_from_hf(arrays: Dict[str, Any], cfg):
     module as it stands after the others were dropped, stacked on an expert
     axis; ``expert_bias`` as ``router_bias``)."""
 
-    def leaf(i, name):
-        a = _get(arrays, f"layers.{i}.{name}.weight")
-        return a.T if a.ndim == 2 else a
-
-    def stack(layers, table):
-        return {
-            k: jnp.stack([leaf(i, name) for i in layers])
-            for k, name in table.items()
-        }
-
     dense = range(cfg.n_dense_layers)
     moe = range(cfg.n_dense_layers, cfg.n_layers)
     dtype = _get(arrays, "norm.weight").dtype
     return {
         "embed": {"weight": _get(arrays, "embed_tokens.weight")},
-        "dense_layers": stack(dense, _AFMOE_DENSE),
+        "dense_layers": _stack_layers(arrays, dense, _AFMOE_DENSE),
         "moe_layers": {
-            **stack(moe, _AFMOE_MOE),
+            **_stack_layers(arrays, moe, _AFMOE_MOE),
             "router_bias": jnp.stack([
                 _get(arrays, f"layers.{i}.mlp.expert_bias").astype(dtype)
                 for i in moe
             ]),
-            **{
-                f"e_{k}": jnp.stack([
-                    jnp.stack([
-                        leaf(i, f"mlp.experts.{e}.{v}")
-                        for e in range(cfg.held)
-                    ])
-                    for i in moe
-                ])
-                for k, v in _AFMOE_MLP.items()
-            },
+            **_stack_experts(arrays, moe, cfg.held, "mlp.experts", _AFMOE_MLP),
         },
         "norm": {"weight": _get(arrays, "norm.weight")},
         "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
@@ -382,6 +395,39 @@ def afmoe_params_to_hf(params, cfg) -> Dict[str, Any]:
             for e in range(cfg.held):
                 put(f"{i}.mlp.experts.{e}.{v}", lp[f"e_{k}"][j, e])
     return out
+
+
+# SmallThinker: leaf of the stacked layout -> published name in a layer.
+_SMALLTHINKER_LAYER = {
+    "attn_norm": "input_layernorm", "mlp_norm": "post_attention_layernorm",
+    "wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
+    "wv": "self_attn.v_proj", "wo": "self_attn.o_proj",
+    "router": "block_sparse_moe.primary_router",
+}
+
+
+def smallthinker_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat SmallThinker param dict (the published names, as
+    :mod:`~torchdistx_tpu.models.smallthinker_torch` has them) -> the one
+    stack of :mod:`~torchdistx_tpu.models.smallthinker` beside its empty
+    ``dense_layers`` (linears transposed to ``(in, out)``; the experts
+    HELD, ``experts.0 .. experts.{held-1}`` of the module as it stands
+    after the others were dropped, stacked on an expert axis)."""
+
+    layers = range(cfg.n_layers)
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "dense_layers": {},
+        "moe_layers": {
+            **_stack_layers(arrays, layers, _SMALLTHINKER_LAYER),
+            **_stack_experts(
+                arrays, layers, cfg.held, "block_sparse_moe.experts",
+                {k: k for k in ("gate", "up", "down")},
+            ),
+        },
+        "norm": {"weight": _get(arrays, "norm.weight")},
+        "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
+    }
 
 
 def _count_layers(arrays, fmt: str) -> int:

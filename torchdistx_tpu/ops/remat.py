@@ -16,11 +16,12 @@ name              array, per layer and device           named in
 ``ssm_starts``    chunk-start states ``(B, T/chunk,     the same
                   N, C)`` float32
 ``moe_selected``  the experts each token chose          ``routed_experts.
-                  ``(T, k)`` int32: the kept products   routed_experts``
+                  ``(T, k)`` int32: the kept products   route``
                   are rows of the order THIS choice
                   sorts, so the replay sorts by it
 ``moe_gate``      the first row chunk's gate product    ``routed_experts.
-                  ``(R, F)``, before the silu, the      _forward``
+                  ``(R, F)``, before the unit's         _forward``
+                  activation (silu or relu), the
                   compute dtype
 ``moe_up``        its up product ``(R, F)``             the same
 ``moe_out``       the routed layer's result ``(T, D)``  ``routed_experts.
@@ -40,7 +41,9 @@ the result is read: at 8,192 tokens, 6 of 128 experts 768 wide, a quarter held (
 16,384) 50.3 MB with no result stored (the block ends ``x + out``); at 8
 of 128 experts 1,024 wide (``R`` 22,016) 90.2 MB + 33.6 MB, for which the
 layer's first chunk runs 9 grouped products, not 12 and 15: none of the
-forward's three runs again, in the remat's replay or in the backward.
+forward's three runs again, in the remat's replay or in the backward; at
+16,384 tokens, 6 of 64 experts 768 wide, a quarter held (``R`` 32,768)
+100.7 MB, no result stored.
 The four names go together: a policy that keeps the products and lets the
 replay choose again pairs them with another order's rows wherever a near
 tie flips (bfloat16 scores round otherwise in the replay's program).
