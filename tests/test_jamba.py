@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import telemetry
-from torchdistx_tpu.models import convert, jamba
+from torchdistx_tpu.models import _common, convert, jamba
 from torchdistx_tpu.ops.attention import mha_reference
 from torchdistx_tpu.ops.pallas.flash_attention import flash_attention
 from torchdistx_tpu.ops.selective_scan import selective_scan
@@ -161,14 +161,14 @@ def test_loss_and_gradients_match_the_reference(tiny, impl, remat, seq):
 
 
 def test_row_blocked_head_is_the_whole_head(tiny, monkeypatch):
-    """The loss in row blocks under remat is ``llama._head_ce``'s loss
+    """The loss in row blocks is ``llama._head_ce``'s loss
     and gradient (the tied embedding's included)."""
     sizes, cfg, params = tiny
     tokens, targets = _tokens(sizes, (2, 64))
     whole = jax.value_and_grad(
         lambda p: jamba.loss_fn(p, tokens, targets, cfg, attn_impl="jnp")
     )(params)
-    monkeypatch.setattr(jamba, "_HEAD_ROWS", 32)  # four blocks
+    monkeypatch.setattr(_common, "_HEAD_ROWS", 32)  # four blocks
     blocked = jax.value_and_grad(
         lambda p: jamba.loss_fn(p, tokens, targets, cfg, attn_impl="jnp")
     )(params)

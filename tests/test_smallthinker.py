@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from torchdistx_tpu import telemetry
-from torchdistx_tpu.models import convert, smallthinker
+from torchdistx_tpu.models import _common, convert, smallthinker
 from torchdistx_tpu.models import llama as llama_mod
 from torchdistx_tpu.ops import routed_experts as routed_mod
 
@@ -167,7 +167,7 @@ def test_the_head_goes_in_row_blocks(monkeypatch):
         )(params)
 
     whole, whole_grads = run()
-    monkeypatch.setattr(smallthinker, "_HEAD_ROWS", 32)  # four blocks
+    monkeypatch.setattr(_common, "_HEAD_ROWS", 32)  # four blocks
     blocked, blocked_grads = run()
     assert abs(float(whole) - float(blocked)) < 1e-6
     for a, b in zip(

@@ -21,7 +21,8 @@ but the convolution's and ``dt_proj``'s::
   heads, NO rotary or any other position term (the state-space layers carry
   the order), causal softmax at ``head_dim**-0.5``, through
   :func:`~torchdistx_tpu.ops.attention.attention` like every family.
-* After the last layer the final norm; the head is the embedding, tied.
+* After the last layer the final norm; the head is the embedding, tied,
+  in blocks of rows (``_common.blocked_head_ce``).
 
 Parameters are stacked by PERIOD (``n_layers = P * attn_period``):
 ``periods = {mamba_a (P, attn_offset, ...), attn (P, ...), mamba_b (P,
@@ -39,7 +40,8 @@ kernel once a layer.
 Scopes: ``mamba`` (the whole mixer) with ``in_proj``, ``conv``,
 ``ssm_params``, ``scan``, ``out_proj`` under it; ``attn``, ``mlp``,
 ``embed``, ``head``.  Counters: ``ssm.layers`` and the scan's own
-(``ssm.scan{impl=}``, ``ssm.scan{interpret=}``, ``ssm.scan_chunks``).
+(``ssm.scan{impl=}``, ``ssm.scan{interpret=}``, ``ssm.scan_chunks``),
+the head's ``head.ce{grad=forward}`` and ``head.row_blocks``.
 
 Not here yet: a cache (``init_cache`` / ``forward_cached``: recurrent state
 beside pages), packed documents (the scan, the convolution and the flash
@@ -64,6 +66,7 @@ from ..ops.attention import attention
 from ..ops.remat import REMAT_POLICY
 from ..ops.selective_scan import resolve_impl, selective_scan
 from . import llama as llama_mod
+from ._common import blocked_head_ce
 
 __all__ = [
     "JambaConfig",
@@ -413,38 +416,20 @@ def _tied_head(params):
     }
 
 
-# Rows of the flattened batch the loss takes at a time.
-_HEAD_ROWS = 4096
-
-
 def _head_ce(params, x, targets, cfg: JambaConfig):
-    """Final norm, tied head and mean cross-entropy, ``_HEAD_ROWS`` rows at
-    a time under remat: ``llama._head_ce``'s numbers (``cfg.dtype`` logits,
-    float32 log-sum-exp) without keeping the ``(B * S, V)`` logits for the
-    backward pass (537 MB at 4,096 x 65,536 in bfloat16), and past
-    ``_HEAD_ROWS`` rows without ever holding them whole.  At the
-    benchmark's 4,096 tokens it is one block."""
+    """Final norm, then the tied head and mean cross-entropy in blocks of
+    rows (:func:`~torchdistx_tpu.models._common.blocked_head_ce`, reading
+    the embedding ``(V, D)`` where it lies): nothing the size of the
+    ``(B * S, V)`` logits is kept for the backward pass (537 MB at 4,096 x
+    65,536 in bfloat16).  At the benchmark's 4,096 tokens it is one
+    block."""
     with jax.named_scope("head"):
         h = llama_mod._rmsnorm(x, params["norm"]["weight"], cfg.norm_eps)
-        h = h.reshape(-1, h.shape[-1])
-        flat = targets.reshape(-1)
-        n = h.shape[0]
-        size = _HEAD_ROWS if n % _HEAD_ROWS == 0 else n
-        w = params["embed"]["weight"].astype(cfg.dtype)
-
-        @jax.checkpoint
-        def block(total, rows):
-            hb, tb = rows
-            logits = jnp.einsum("rd,vd->rv", hb, w)
-            lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-            tgt = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
-            return total + (lse - tgt.astype(jnp.float32)).sum(), None
-
-        total, _ = jax.lax.scan(
-            block, jnp.zeros((), jnp.float32),
-            (h.reshape(-1, size, h.shape[-1]), flat.reshape(-1, size)),
+        return blocked_head_ce(
+            h.reshape(-1, h.shape[-1]),
+            params["embed"]["weight"].astype(cfg.dtype),
+            targets.reshape(-1), vocab_major=True,
         )
-        return total / n
 
 
 def forward(params, tokens, cfg: JambaConfig, *, mesh=None,

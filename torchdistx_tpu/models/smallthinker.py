@@ -37,8 +37,9 @@ kinds, the period's layers one after another in the body
 (:func:`~torchdistx_tpu.models.afmoe._run_stack`), each rematerialised with
 ``ops.remat.REMAT_POLICY``: a block keeps its input, ``flash_out``,
 ``flash_lse``, ``moe_selected``, ``moe_gate``, ``moe_up``.  The head and
-the loss go in blocks of rows (``_head_ce``): at 16,384 positions the
-float32 logits of a 37,984-row head are 2.5 GB.  ``loss_fn`` returns
+the loss go in blocks of rows (``_common.blocked_head_ce``, which takes
+the head's gradient in the forward pass): at 16,384 positions the float32
+logits of a 37,984-row head are 2.5 GB.  ``loss_fn`` returns
 ``(loss, aux)`` (``LOSS_HAS_AUX``) with ``aux["moe"]`` the step's routing
 counts.
 
@@ -47,7 +48,8 @@ Scopes: ``embed``; ``moe/router`` entered FIRST in a layer; ``attn`` with
 with ``dispatch``, ``experts``, ``combine`` and the norm; ``head``;
 ``stack``.  Counters: ``moe.experts_held``, ``moe.experts_total``, the
 routed layer's ``moe.router_input{from=layer_input}`` and
-``moe.unit{kind=relu}``, and attention's own.
+``moe.unit{kind=relu}``, the head's ``head.ce{grad=forward}`` and
+``head.row_blocks``, and attention's own.
 
 Not here yet: a cache (no serving path), and what the early router is FOR
 in the published system, prefetching the chosen experts' weights while
@@ -69,6 +71,7 @@ from ..ops.attention import attention
 from ..ops.remat import REMAT_POLICY
 from ..ops.routed_experts import route, routed_experts
 from . import afmoe as afmoe_mod
+from ._common import blocked_head_ce
 from . import llama as llama_mod
 
 __all__ = [
@@ -311,36 +314,17 @@ def _forward_hidden(params, tokens, cfg, *, mesh=None, attn_impl="auto"):
     }
 
 
-# Rows of the flattened batch the loss takes at a time.
-_HEAD_ROWS = 4096
-
-
 def _head_ce(params, x, targets, cfg: SmallThinkerConfig):
-    """Final norm, head and mean cross-entropy, ``_HEAD_ROWS`` rows at a
-    time under remat: ``llama._head_ce``'s numbers (``cfg.dtype`` logits,
-    float32 log-sum-exp) without ever holding the ``(B * S, V)`` logits
-    whole, nor keeping a block's for the backward pass."""
+    """Final norm, then the head and mean cross-entropy in blocks of rows
+    (:func:`~torchdistx_tpu.models._common.blocked_head_ce`): the float32
+    logits of a 37,984-row head at 16,384 positions are 2.5 GB."""
     with jax.named_scope("head"):
         h = llama_mod._rmsnorm(x, params["norm"]["weight"], cfg.norm_eps)
-        h = h.reshape(-1, h.shape[-1])
-        flat = targets.reshape(-1)
-        n = h.shape[0]
-        size = _HEAD_ROWS if n % _HEAD_ROWS == 0 else n
-        w = params["lm_head"]["weight"].astype(cfg.dtype)
-
-        @jax.checkpoint
-        def block(total, rows):
-            hb, tb = rows
-            logits = hb @ w
-            lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-            tgt = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
-            return total + (lse - tgt.astype(jnp.float32)).sum(), None
-
-        total, _ = jax.lax.scan(
-            block, jnp.zeros((), jnp.float32),
-            (h.reshape(-1, size, h.shape[-1]), flat.reshape(-1, size)),
+        return blocked_head_ce(
+            h.reshape(-1, h.shape[-1]),
+            params["lm_head"]["weight"].astype(cfg.dtype),
+            targets.reshape(-1), vocab_major=False,
         )
-        return total / n
 
 
 def forward(params, tokens, cfg: SmallThinkerConfig, *, mesh=None,
