@@ -18,10 +18,15 @@ ships JAX-native model families designed for the TPU training stack:
   experts; full and window attention layers 1 to 3
   (:mod:`torchdistx_tpu.models.smallthinker_torch` for the deferred-init
   path).
+* :mod:`torchdistx_tpu.models.minicpm_sala` — block-sparse attention
+  that picks its own blocks per token and decayed linear (lightning)
+  attention mixed by layer, MiniCPM's muP scalings
+  (:mod:`torchdistx_tpu.models.minicpm_sala_torch` for the deferred-init
+  path).
 * :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
   — routed-expert families (imported where used).
 """
 
-from . import afmoe, gpt2, jamba, llama, smallthinker  # noqa: F401
+from . import afmoe, gpt2, jamba, llama, minicpm_sala, smallthinker  # noqa: F401
 
-__all__ = ["afmoe", "gpt2", "jamba", "llama", "smallthinker"]
+__all__ = ["afmoe", "gpt2", "jamba", "llama", "minicpm_sala", "smallthinker"]

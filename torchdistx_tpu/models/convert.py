@@ -37,6 +37,7 @@ __all__ = [
     "afmoe_params_from_hf",
     "afmoe_params_to_hf",
     "smallthinker_params_from_hf",
+    "minicpm_sala_params_from_hf",
 ]
 
 
@@ -425,6 +426,37 @@ def smallthinker_params_from_hf(arrays: Dict[str, Any], cfg):
                 {k: k for k in ("gate", "up", "down")},
             ),
         },
+        "norm": {"weight": _get(arrays, "norm.weight")},
+        "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
+    }
+
+
+# MiniCPM-SALA: leaf of a kind's stack -> name in a layer.
+_MINICPM_SALA_LAYER = {
+    "attn_norm": "input_layernorm", "mlp_norm": "post_attention_layernorm",
+    "wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
+    "wv": "self_attn.v_proj", "wg": "self_attn.o_gate",
+    "wo": "self_attn.o_proj", "q_norm": "self_attn.q_norm",
+    "k_norm": "self_attn.k_norm", "w_gate": "mlp.gate_proj",
+    "w_up": "mlp.up_proj", "w_down": "mlp.down_proj",
+}
+
+
+def minicpm_sala_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat MiniCPM-SALA param dict (the names of
+    :mod:`~torchdistx_tpu.models.minicpm_sala_torch`) -> the two stacks of
+    :mod:`~torchdistx_tpu.models.minicpm_sala`, each in layer order over
+    the layers of its kind (linears transposed to ``(in, out)``)."""
+    from .minicpm_sala import LIGHTNING, SPARSE
+
+    def of(kind):
+        return [i for i, k in enumerate(cfg.mixer_types) if k == kind]
+
+    lightning = dict(_MINICPM_SALA_LAYER, o_norm="self_attn.o_norm")
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "sparse_layers": _stack_layers(arrays, of(SPARSE), _MINICPM_SALA_LAYER),
+        "lightning_layers": _stack_layers(arrays, of(LIGHTNING), lightning),
         "norm": {"weight": _get(arrays, "norm.weight")},
         "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
     }

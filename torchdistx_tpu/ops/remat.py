@@ -48,6 +48,18 @@ The four names go together: a policy that keeps the products and lets the
 replay choose again pairs them with another order's rows wherever a near
 tie flips (bfloat16 scores round otherwise in the replay's program).
 
+MiniCPM-SALA's two mixers (PR 39).  ``sparse_choice``: the blocks each
+(token, group) chose, ``(B, Hkv, S, ceil(S/64/32))`` int32 bits, 1 MiB at
+16,384 positions, kept because a replay's bfloat16 scores could choose
+again near a tie and pair the kept result with another choice;
+``sparse_out`` ``(B, S, Hq*D)`` and ``sparse_lse`` ``(B, Hq, S)`` float32,
+the block-sparse kernel's result and log-sum-exp (``sparse_attention.
+_sparse_fwd``); ``lightning_out`` ``(B, S, H, D)`` and ``lightning_starts``
+``(B, H, S/chunk, D, D)`` float32, the decayed linear attention's result
+and chunk-start states (``lightning_attention._la_fwd``): at 16,384
+positions, 32 heads of 128, chunks of 256, 128 MiB + 128 MiB a layer, for
+which ``lightning_fwd`` runs once a layer.
+
 The banded flash kernels of a window call (``flash_win_*``) name the same
 two arrays, so a window layer's forward kernel runs once a layer too.
 
@@ -65,4 +77,6 @@ __all__ = ["REMAT_POLICY"]
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
     "flash_out", "flash_lse", "ssm_out", "ssm_starts",
     "moe_selected", "moe_gate", "moe_up", "moe_out",
+    "sparse_choice", "sparse_out", "sparse_lse",
+    "lightning_out", "lightning_starts",
 )
