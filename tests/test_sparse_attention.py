@@ -144,12 +144,22 @@ def test_attention_over_the_chosen_keys(impl, monkeypatch):
     assert 6 <= float(stats["kv_blocks"]) <= 16
 
 
-def test_kernels_backward_is_the_oracles(monkeypatch):
+@pytest.mark.parametrize("n, bk", [(16, 8), (16, 16), (32, 8), (32, 16)])
+def test_kernels_backward_is_the_oracles(n, bk, monkeypatch):
     """The three interpreted kernels against the ``jnp`` path's written-out
-    backward, and both against ``jax.grad`` of the dense masked form."""
-    monkeypatch.setattr(sa, "_TILE", (16,))
+    backward, and both against ``jax.grad`` of the dense masked form, at q
+    tiles of 16 and 32 tokens and kv blocks of one and two rule blocks."""
+    monkeypatch.setattr(sa, "_tiling", lambda t, block_size: (n, bk))
     q, k, v = _qkv()
     chosen = brute_choice(q, k, CFG)
+    # dkv's lists: kv block 0 (every token's init block) holds every tile,
+    # the last kv block only the last tile, the rest of its list repeated
+    member = sa._membership(sa.select_blocks(q, k, CFG), 128, CFG, n, bk)
+    k_lists, k_counts = (
+        np.asarray(x) for x in sa._lists(jnp.swapaxes(member, 2, 3))
+    )
+    assert (k_counts[..., 0] == 128 // n).all() and (k_counts[..., -1] == 1).all()
+    assert (k_lists[..., -1, :] == 128 // n - 1).all()
     w = jax.random.normal(jax.random.PRNGKey(5), q.shape)
 
     def loss(fn):
@@ -165,6 +175,44 @@ def test_kernels_backward_is_the_oracles(monkeypatch):
         )(q, k, v)
         for a, b in zip(got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def _pallas_calls(jaxpr):
+    """``(name, eqn)`` of every ``pallas_call`` in ``jaxpr`` and the jaxprs
+    it holds."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, tuple) else (val,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _pallas_calls(sub)
+
+
+def test_dkv_takes_the_statistics_as_rows():
+    """The Pallas backward hands ``sparse_flash_bwd_dkv`` no float32
+    operand with a minor dimension of 1 (``lse`` and ``delta`` enter as
+    rows, not columns padded to 128 lanes), and counts which layout each
+    backward kernel was built with."""
+    q, k, v = _qkv(t=64)
+    c0 = telemetry.counters()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: sa.sparse_attention(q, k, v, CFG, impl="pallas")[0].sum(),
+        argnums=(0, 1, 2),
+    ))(q, k, v).jaxpr
+    c1 = telemetry.counters()
+    calls = {name: eqn for name, eqn in _pallas_calls(jaxpr)}
+    assert {"sparse_flash_fwd", "sparse_flash_bwd_dq", "sparse_flash_bwd_dkv"} <= set(calls)
+    dkv = [x.aval for x in calls["sparse_flash_bwd_dkv"].invars]
+    assert [a.shape for a in dkv].count((2, 2, 3, 64)) == 2  # (Bsz, Hkv, G, T)
+    assert not [a for a in dkv if a.dtype == jnp.float32 and a.shape[-1] == 1]
+    dq = [x.aval for x in calls["sparse_flash_bwd_dq"].invars]
+    assert [a.shape for a in dq].count((2, 6, 64, 1)) == 2  # (Bsz, H, T, 1)
+    for layout in ("rows", "columns"):
+        name = f"sparse_attn.bwd_stats{{layout={layout}}}"
+        assert c1[name] == c0.get(name, 0) + 1
 
 
 def test_kernel_lists_walk_each_tiles_union(monkeypatch):

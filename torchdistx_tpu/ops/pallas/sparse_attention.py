@@ -21,7 +21,11 @@ read and write where the model keeps its activations, ``(Bsz, T, H*D)``:
   ``(n, W)`` int32 words a token (bit ``b % 32`` of word ``b // 32`` says
   block ``b`` is chosen), and ``key <= token``.
 
-The forward keeps ``lse`` per (token, head), ``(Bsz, H, T, 1)`` float32.
+The forward keeps ``lse`` per (token, head), ``(Bsz, H, T, 1)`` float32,
+and ``dq`` reads it so, once a q tile.  ``dkv``, which fetches a q tile's
+operands again at every kv block, runs its step KEY-MAJOR: ``p^T``, ``ds^T``
+``(bk, n)``, with ``lse`` and ``delta`` ``(G, n)`` rows and the chosen bits
+``(W, n)`` words, so no operand of it is a column padded to 128 lanes.
 Matrix products take the operands' dtype with float32 accumulation; the
 softmax runs in float32 in the log2 domain, as ``flash_attention``'s.
 """
@@ -50,17 +54,20 @@ def _dot(a, b, contract):
     )
 
 
-def _keep(words, tile, kb, *, n, bk, bs):
-    """``(n, bk)`` bool: token ``tile*n + row`` sees key ``kb*bk + col``."""
-    tok = tile * n + _iota((n, bk), 0)
-    key = kb * bk + _iota((n, bk), 1)
-    lane = _iota(words.shape, 1)
-    part = _iota((n, bk), 1) // bs
-    chosen = jnp.zeros((n, bk), jnp.bool_)
+def _keep(words, tile, kb, *, n, bk, bs, key_axis=1):
+    """Token ``tile*n + t`` sees key ``kb*bk + j``, as a bool ``(n, bk)``
+    from words ``(n, W)`` (``key_axis`` 1), or key-major ``(bk, n)`` from
+    words ``(W, n)`` (``key_axis`` 0)."""
+    shape = (n, bk) if key_axis == 1 else (bk, n)
+    tok = tile * n + _iota(shape, 1 - key_axis)
+    key = kb * bk + _iota(shape, key_axis)
+    slot = _iota(words.shape, key_axis)
+    part = _iota(shape, key_axis) // bs
+    chosen = jnp.zeros(shape, jnp.bool_)
     for r in range(bk // bs):
         blk = kb * (bk // bs) + r
         word = jnp.sum(
-            jnp.where(lane == blk // 32, words, 0), axis=1, keepdims=True
+            jnp.where(slot == blk // 32, words, 0), axis=key_axis, keepdims=True
         )
         bit = jax.lax.shift_right_logical(
             word, jnp.full(word.shape, blk % 32, jnp.int32)
@@ -169,16 +176,20 @@ def _dkv_kernel(lists, counts, q_ref, k_ref, v_ref, w_ref, do_ref, lse_ref,
     @pl.when(s < counts[flat])
     def _():
         i = lists[flat * nq + s]
-        keep = _keep(w_ref[0, 0], i, kb, n=n, bk=bk, bs=bs)
+        keep = _keep(w_ref[0, 0], i, kb, n=n, bk=bk, bs=bs, key_axis=0)
         k, v = k_ref[0], v_ref[0]
         for h in range(heads):
             cols = slice(h * d, (h + 1) * d)
             q, do = q_ref[0, :, cols], do_ref[0, :, cols]
+            # Key-major: _p_ds with the roles swapped gives p^T and ds^T
+            # (bk, n), the statistics one (1, n) row each, so both products
+            # below contract the tile's n tokens as a plain matmul does.
             p, ds = _p_ds(
-                q, k, v, do, lse_ref[0, h], delta_ref[0, h], keep, scale
+                k, q, do, v, lse_ref[0, 0, h:h + 1], delta_ref[0, 0, h:h + 1],
+                keep, scale,
             )
-            dv_acc[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
-            dk_acc[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
+            dv_acc[...] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+            dk_acc[...] += _dot(ds.astype(q.dtype), q, ((1,), (0,)))
 
     @pl.when(s == nq - 1)
     def _():
@@ -290,7 +301,10 @@ def backward_dq(q, k, v, words, do, lse, delta, q_lists, q_counts, *, n, bk,
 
 def backward_dkv(q, k, v, words, do, lse, delta, k_lists, k_counts, *, n, bk,
                  bs, interpret):
-    """``(dk, dv)``, each ``(Bsz, T, Hkv*D)``."""
+    """``(dk, dv)``, each ``(Bsz, T, Hkv*D)``; ``lse``, ``delta`` ``(Bsz, H,
+    T)`` float32.  The step is key-major: a q tile's statistics enter as
+    ``(G, n)`` rows and its chosen bits as ``(W, n)`` words, where columns
+    would be tiled to 128 lanes and fetched again at every kv block."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -299,14 +313,14 @@ def backward_dkv(q, k, v, words, do, lse, delta, k_lists, k_counts, *, n, bk,
     def q_at(b, gi, kb, s, lists):
         return lists[((b * hkv + gi) * nk + kb) * nq + s]
 
+    def lanes(b, gi, kb, s, lists, counts):
+        return (b, gi, 0, q_at(b, gi, kb, s, lists))
+
     tile = pl.BlockSpec(
         (1, n, g * d),
         lambda b, gi, kb, s, lists, counts: (b, q_at(b, gi, kb, s, lists), gi),
     )
-    rows = pl.BlockSpec(
-        (1, g, n, 1),
-        lambda b, gi, kb, s, lists, counts: (b, gi, q_at(b, gi, kb, s, lists), 0),
-    )
+    rows = pl.BlockSpec((1, 1, g, n), lanes)
     kv = pl.BlockSpec((1, bk, d), lambda b, gi, kb, s, *_: (b, kb, gi))
     kern = functools.partial(
         _dkv_kernel, heads=g, d=d, n=n, bk=bk, bs=bs, scale=d**-0.5, hkv=hkv,
@@ -318,14 +332,7 @@ def backward_dkv(q, k, v, words, do, lse, delta, k_lists, k_counts, *, n, bk,
             num_scalar_prefetch=2,
             grid=(bsz, hkv, nk, nq),
             in_specs=[
-                tile, kv, kv,
-                pl.BlockSpec(
-                    (1, 1, n, w),
-                    lambda b, gi, kb, s, lists, counts: (
-                        b, gi, q_at(b, gi, kb, s, lists), 0
-                    ),
-                ),
-                tile, rows, rows,
+                tile, kv, kv, pl.BlockSpec((1, 1, w, n), lanes), tile, rows, rows,
             ],
             out_specs=[kv, kv],
             scratch_shapes=[
@@ -339,4 +346,7 @@ def backward_dkv(q, k, v, words, do, lse, delta, k_lists, k_counts, *, n, bk,
         ],
         name="sparse_flash_bwd_dkv",
         **_params(interpret),
-    )(k_lists, k_counts, q, k, v, words, do, lse, delta)
+    )(
+        k_lists, k_counts, q, k, v, jnp.swapaxes(words, 2, 3), do,
+        lse.reshape(bsz, hkv, g, t), delta.reshape(bsz, hkv, g, t),
+    )

@@ -47,7 +47,10 @@ and the kept result would belong to another choice than the backward's).
 ``stats["kv_blocks"]``: the mean over q tiles of the keys a tile's kernel
 steps run, in blocks of ``block_size`` (a device scalar; a token chose
 ``topk``).  Counted per trace: ``sparse_attn.mode{mode=dense|sparse}``,
-``sparse_attn{impl=}``, ``sparse_attn{interpret=}`` (Pallas only).
+``sparse_attn{impl=}``, ``sparse_attn{interpret=}`` (Pallas only); per
+trace of the Pallas backward, ``sparse_attn.bwd_stats{layout=}`` once for
+each kernel by how ``lse`` and ``delta`` enter it: ``columns`` (dq,
+``(Bsz, H, T, 1)``), ``rows`` (dkv, ``(Bsz, H, T)``).
 """
 
 from __future__ import annotations
@@ -344,13 +347,18 @@ def _sparse_bwd(cfg, impl, interpret, res, do):
         q_lists, q_counts = _lists(member)
         k_lists, k_counts = _lists(jnp.swapaxes(member, 2, 3))
         kw = dict(n=n, bk=bk, bs=cfg.block_size, interpret=interpret)
-        args = (q, k, v, words, do, lse[..., None], delta[..., None])
+        # dq reads a tile's statistics once, as (T, 1) columns; dkv at every
+        # kv block, so it takes them as the (T,) rows they are.
         dq = kernels.backward_dq(
-            *args, q_lists.reshape(-1), q_counts.reshape(-1), **kw
+            q, k, v, words, do, lse[..., None], delta[..., None],
+            q_lists.reshape(-1), q_counts.reshape(-1), **kw
         )
         dk, dv = kernels.backward_dkv(
-            *args, k_lists.reshape(-1), k_counts.reshape(-1), **kw
+            q, k, v, words, do, lse, delta, k_lists.reshape(-1),
+            k_counts.reshape(-1), **kw
         )
+        _telemetry.counter("sparse_attn.bwd_stats", layout="columns").add()
+        _telemetry.counter("sparse_attn.bwd_stats", layout="rows").add()
         return dq, dk, dv, None
     g = h // hkv
     dq, dk, dv = _jnp_backward(
