@@ -177,6 +177,34 @@ def test_kernels_backward_is_the_oracles(n, bk, monkeypatch):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
+@pytest.mark.parametrize("n, bk", [(16, 8), (16, 16), (32, 8), (32, 16)])
+def test_forward_is_the_oracles(n, bk, monkeypatch):
+    """The interpreted key-major forward's ``o`` and ``lse`` against the
+    ``jnp`` oracle's, at the backward test's tilings: tiles whose lists end
+    in repeats past their counts, and steps in which a token keeps no key
+    (its row's statistics must pass through such a step unchanged)."""
+    monkeypatch.setattr(sa, "_tiling", lambda t, block_size: (n, bk))
+    q, k, v = _qkv()
+    b, t, h, d = q.shape
+    words = sa.select_blocks(q, k, CFG)
+    member = sa._membership(words, t, CFG, n, bk)  # (Bsz, Hkv, tile, kv block)
+    assert (np.asarray(sa._lists(member)[1]) < t // bk).any()  # repeats
+    seen = np.asarray(sa._keys_seen(words, jnp.arange(t), t, CFG)).reshape(
+        b, -1, t // n, n, t // bk, bk
+    ).any(-1)  # (Bsz, Hkv, tile, token, kv block): the token keeps a key there
+    assert seen[..., 0].all()  # every row's first step keeps a key
+    assert (np.asarray(member)[:, :, :, None] & ~seen).any()  # steps it keeps none
+    args = (
+        q.reshape(b, t, h * d), k.reshape(b, t, -1), v.reshape(b, t, -1),
+        words, CFG,
+    )
+    got = sa._forward(*args, "pallas", True)
+    want = sa._forward(*args, "jnp", False)
+    assert got[1].shape == want[1].shape == (b, h, t)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=2e-5)
+
+
 def _pallas_calls(jaxpr):
     """``(name, eqn)`` of every ``pallas_call`` in ``jaxpr`` and the jaxprs
     it holds."""
@@ -191,11 +219,18 @@ def _pallas_calls(jaxpr):
                     yield from _pallas_calls(sub)
 
 
-def test_dkv_takes_the_statistics_as_rows():
-    """The Pallas backward hands ``sparse_flash_bwd_dkv`` no float32
-    operand with a minor dimension of 1 (``lse`` and ``delta`` enter as
-    rows, not columns padded to 128 lanes), and counts which layout each
-    backward kernel was built with."""
+@pytest.mark.parametrize("kernel, counter, stats_shape, n_stats", [
+    ("sparse_flash_fwd", "fwd_stats{layout=rows}", (2, 2, 3, 64), 1),
+    ("sparse_flash_bwd_dkv", "bwd_stats{layout=rows}", (2, 2, 3, 64), 2),
+    ("sparse_flash_bwd_dq", "bwd_stats{layout=columns}", (2, 6, 64, 1), 2),
+])
+def test_dkv_takes_the_statistics_as_rows(kernel, counter, stats_shape, n_stats):
+    """How each Pallas kernel of the sparse path takes or returns its
+    float32 statistics, in one trace of the gradient: the key-major forward
+    returns ``lse`` and dkv takes ``lse`` and ``delta`` as ``(Bsz, Hkv, G,
+    T)`` rows, with no float32 array of a minor dimension of 1 among their
+    operands and results (a column padded to 128 lanes); dq takes both as
+    ``(Bsz, H, T, 1)`` columns.  Each kernel's layout is counted once."""
     q, k, v = _qkv(t=64)
     c0 = telemetry.counters()
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -205,14 +240,13 @@ def test_dkv_takes_the_statistics_as_rows():
     c1 = telemetry.counters()
     calls = {name: eqn for name, eqn in _pallas_calls(jaxpr)}
     assert {"sparse_flash_fwd", "sparse_flash_bwd_dq", "sparse_flash_bwd_dkv"} <= set(calls)
-    dkv = [x.aval for x in calls["sparse_flash_bwd_dkv"].invars]
-    assert [a.shape for a in dkv].count((2, 2, 3, 64)) == 2  # (Bsz, Hkv, G, T)
-    assert not [a for a in dkv if a.dtype == jnp.float32 and a.shape[-1] == 1]
-    dq = [x.aval for x in calls["sparse_flash_bwd_dq"].invars]
-    assert [a.shape for a in dq].count((2, 6, 64, 1)) == 2  # (Bsz, H, T, 1)
-    for layout in ("rows", "columns"):
-        name = f"sparse_attn.bwd_stats{{layout={layout}}}"
-        assert c1[name] == c0.get(name, 0) + 1
+    eqn = calls[kernel]
+    avals = [x.aval for x in (*eqn.invars, *eqn.outvars)]
+    assert [a.shape for a in avals].count(stats_shape) == n_stats
+    columns = [a for a in avals if a.dtype == jnp.float32 and a.shape[-1] == 1]
+    assert len(columns) == (n_stats if stats_shape[-1] == 1 else 0)
+    name = f"sparse_attn.{counter}"
+    assert c1[name] == c0.get(name, 0) + 1
 
 
 def test_kernel_lists_walk_each_tiles_union(monkeypatch):

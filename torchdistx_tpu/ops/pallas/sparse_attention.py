@@ -21,11 +21,17 @@ read and write where the model keeps its activations, ``(Bsz, T, H*D)``:
   ``(n, W)`` int32 words a token (bit ``b % 32`` of word ``b // 32`` says
   block ``b`` is chosen), and ``key <= token``.
 
-The forward keeps ``lse`` per (token, head), ``(Bsz, H, T, 1)`` float32,
-and ``dq`` reads it so, once a q tile.  ``dkv``, which fetches a q tile's
-operands again at every kv block, runs its step KEY-MAJOR: ``p^T``, ``ds^T``
-``(bk, n)``, with ``lse`` and ``delta`` ``(G, n)`` rows and the chosen bits
-``(W, n)`` words, so no operand of it is a column padded to 128 lanes.
+The forward runs its step KEY-MAJOR: logits ``s^T = k q_h^T`` ``(bk, n)``,
+so a token's running max and sum are ``(1, n)`` float32 rows reduced down
+the sublanes (a ``(n, bk)`` step at ``bk`` 128 pays a 128-lane reduction
+for every logit vreg), the accumulator ``(G, D, n)`` takes ``v^T p^T``,
+and ``lse`` leaves as ``(Bsz, Hkv, G, T)`` rows.  ``dq`` reads ``lse``
+per (token, head) as ``(Bsz, H, T, 1)`` columns, once a q tile.  ``dkv``,
+which fetches a q tile's operands again at every kv block, runs its step
+key-major too: ``p^T``, ``ds^T`` ``(bk, n)``, with ``lse`` and ``delta``
+``(G, n)`` rows.  The key-major kernels take the chosen bits as ``(W, n)``
+words, so no float32 operand or result of theirs is a column padded to
+128 lanes.
 Matrix products take the operands' dtype with float32 accumulation; the
 softmax runs in float32 in the log2 domain, as ``flash_attention``'s.
 """
@@ -93,31 +99,31 @@ def _fwd_kernel(lists, counts, q_ref, k_ref, v_ref, w_ref, o_ref, lse_ref,
     @pl.when(s < counts[flat])
     def _():
         kb = lists[flat * nk + s]
-        keep = _keep(w_ref[0, 0], i, kb, n=n, bk=bk, bs=bs)
-        k, v = k_ref[0], v_ref[0]
+        keep = _keep(w_ref[0, 0], i, kb, n=n, bk=bk, bs=bs, key_axis=0)
+        k = k_ref[0]
+        vt = v_ref[0].T  # (d, bk): the group's, shared by its heads
         for h in range(heads):
-            cols = slice(h * d, (h + 1) * d)
-            logits = _dot(q_ref[0, :, cols], k, ((1,), (1,))) * (scale * _LOG2E)
-            logits = jnp.where(keep, logits, _MASK)
-            m_prev = m_scr[h][:, :1]
-            m_next = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            # Key-major: s^T (bk, n), so a token's running max and sum are
+            # reductions down the sublanes into its (1, n) rows.  A row's
+            # first step keeps a key: kv block 0 holds every token's init
+            # block and comes first in every list.
+            st = _dot(k, q_ref[0, :, h * d:(h + 1) * d], ((1,), (1,)))
+            st = jnp.where(keep, st * (scale * _LOG2E), _MASK)
+            m_prev = m_scr[h]
+            m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             alpha = jnp.exp2(m_prev - m_next)
-            p = jnp.exp2(logits - m_next)
-            l_next = l_scr[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            m_scr[h] = jnp.broadcast_to(m_next, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_next, l_scr.shape[1:])
-            acc[:, cols] = acc[:, cols] * alpha + _dot(
-                p.astype(v.dtype), v, ((1,), (0,))
-            )
+            p = jnp.exp2(st - m_next)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            m_scr[h] = m_next
+            acc[h] = acc[h] * alpha + _dot(vt, p.astype(vt.dtype), ((1,), (0,)))
 
     @pl.when(s == nk - 1)
     def _():
         for h in range(heads):
-            cols = slice(h * d, (h + 1) * d)
-            l = l_scr[h][:, :1]
+            l = l_scr[h]
             l = jnp.where(l == 0.0, 1.0, l)  # a row that kept no key
-            o_ref[0, :, cols] = (acc[:, cols] / l).astype(o_ref.dtype)
-            lse_ref[0, h] = m_scr[h][:, :1] / _LOG2E + jnp.log(l)
+            o_ref[0, :, h * d:(h + 1) * d] = (acc[h] / l).T.astype(o_ref.dtype)
+            lse_ref[0, 0, h:h + 1] = m_scr[h] / _LOG2E + jnp.log(l)
 
 
 def _p_ds(q, k, v, do, lse, delta, keep, scale):
@@ -219,7 +225,9 @@ def _shapes(q, k, words, n, bk):
 def forward(q, k, v, words, q_lists, q_counts, *, n, bk, bs, interpret):
     """``q (Bsz, T, H*D)``, ``k``/``v (Bsz, T, Hkv*D)``, ``words (Bsz, Hkv,
     T, W)`` int32, the tiles' lists flat -> ``(o (Bsz, T, H*D), lse (Bsz,
-    H, T, 1) float32)``."""
+    Hkv, G, T) float32)``.  The step is key-major: a token's running max
+    and sum are ``(1, n)`` rows, its chosen bits ``(W, n)`` words, the
+    accumulator ``(G, D, n)``, and ``lse`` leaves as ``(G, n)`` rows."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
@@ -227,6 +235,9 @@ def forward(q, k, v, words, q_lists, q_counts, *, n, bk, bs, interpret):
 
     def kv_index(b, gi, i, s, lists, counts):
         return (b, lists[((b * hkv + gi) * nq + i) * nk + s], gi)
+
+    def lanes(b, gi, i, s, *_):
+        return (b, gi, 0, i)
 
     tile = pl.BlockSpec((1, n, g * d), lambda b, gi, i, s, *_: (b, i, gi))
     kv = pl.BlockSpec((1, bk, d), kv_index)
@@ -239,27 +250,21 @@ def forward(q, k, v, words, q_lists, q_counts, *, n, bk, bs, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bsz, hkv, nq, nk),
-            in_specs=[
-                tile, kv, kv,
-                pl.BlockSpec((1, 1, n, w), lambda b, gi, i, s, *_: (b, gi, i, 0)),
-            ],
-            out_specs=[
-                tile,
-                pl.BlockSpec((1, g, n, 1), lambda b, gi, i, s, *_: (b, gi, i, 0)),
-            ],
+            in_specs=[tile, kv, kv, pl.BlockSpec((1, 1, w, n), lanes)],
+            out_specs=[tile, pl.BlockSpec((1, 1, g, n), lanes)],
             scratch_shapes=[
-                pltpu.VMEM((n, g * d), jnp.float32),
-                pltpu.VMEM((g, n, 128), jnp.float32),
-                pltpu.VMEM((g, n, 128), jnp.float32),
+                pltpu.VMEM((g, d, n), jnp.float32),
+                pltpu.VMEM((g, 1, n), jnp.float32),
+                pltpu.VMEM((g, 1, n), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bsz, hkv * g, t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, hkv, g, t), jnp.float32),
         ],
         name="sparse_flash_fwd",
         **_params(interpret),
-    )(q_lists, q_counts, q, k, v, words)
+    )(q_lists, q_counts, q, k, v, jnp.swapaxes(words, 2, 3))
 
 
 def backward_dq(q, k, v, words, do, lse, delta, q_lists, q_counts, *, n, bk,

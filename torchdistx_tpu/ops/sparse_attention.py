@@ -44,13 +44,18 @@ The forward rule names its result ``sparse_out`` and its log-sum-exp
 replay must not choose again (its bfloat16 scores could flip a near tie,
 and the kept result would belong to another choice than the backward's).
 
+The forward kernel runs key-major and hands ``lse`` back as ``(Bsz, Hkv,
+G, T)`` rows, which reshape to ``(Bsz, H, T)`` for free.
+
 ``stats["kv_blocks"]``: the mean over q tiles of the keys a tile's kernel
 steps run, in blocks of ``block_size`` (a device scalar; a token chose
 ``topk``).  Counted per trace: ``sparse_attn.mode{mode=dense|sparse}``,
 ``sparse_attn{impl=}``, ``sparse_attn{interpret=}`` (Pallas only); per
-trace of the Pallas backward, ``sparse_attn.bwd_stats{layout=}`` once for
-each kernel by how ``lse`` and ``delta`` enter it: ``columns`` (dq,
-``(Bsz, H, T, 1)``), ``rows`` (dkv, ``(Bsz, H, T)``).
+trace of the Pallas forward, ``sparse_attn.fwd_stats{layout=rows}`` (its
+running statistics and ``lse`` are rows); per trace of the Pallas
+backward, ``sparse_attn.bwd_stats{layout=}`` once for each kernel by how
+``lse`` and ``delta`` enter it: ``columns`` (dq, ``(Bsz, H, T, 1)``),
+``rows`` (dkv, ``(Bsz, H, T)``).
 """
 
 from __future__ import annotations
@@ -307,7 +312,8 @@ def _forward(q, k, v, words, cfg, impl, interpret):
             q, k, v, words, lists.reshape(-1), counts.reshape(-1), n=n, bk=bk,
             bs=cfg.block_size, interpret=interpret,
         )
-        return o, lse[..., 0]
+        _telemetry.counter("sparse_attn.fwd_stats", layout="rows").add()
+        return o, lse.reshape(b, hkv * g, t)
     o, lse = _jnp_forward(
         q.reshape(b, t, hkv, g, d), k.reshape(b, t, hkv, d),
         v.reshape(b, t, hkv, d), words, cfg,
