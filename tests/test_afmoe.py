@@ -9,9 +9,6 @@ CPU, float32, seeded: values and counts only.
 """
 
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,37 +20,13 @@ from torchdistx_tpu.models import afmoe, convert
 from torchdistx_tpu.models import deepseek_v3 as ds
 from torchdistx_tpu.models import llama as llama_mod
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-from families import afmoe as family  # noqa: E402
-from reference import afmoe as ref  # noqa: E402
-from reference import common  # noqa: E402
+import _families  # puts benchmarks/ on sys.path
+from families import afmoe as family
+from reference import afmoe as ref
+from reference import common
 
 CONFIG = "trinity-mini"
-
-
-def _sizes(**over):
-    """The configuration file's ``tiny`` block over its published keys."""
-    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
-        c = json.load(f)
-    c.update(c.pop("tiny"))
-    c.update(over)
-    return c
-
-
-def _ref_loss(params, tokens, targets, sizes):
-    with common.precision(jnp.float32):
-        x = ref.hidden(params, tokens, sizes, jnp.float32)
-        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
-
-
-def _tokens(sizes, shape=(2, 80), seed=1):
-    ids = np.random.default_rng(seed).integers(
-        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
-    )
-    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+SHAPE = (2, 80)
 
 
 def _seeded(cfg, seed=0):
@@ -82,12 +55,20 @@ SHARES = {
 @pytest.fixture(scope="module", params=list(SHARES))
 def held(request):
     """Sizes, native config and seeded parameters, whole and as a share."""
-    sizes = _sizes(**SHARES[request.param])
+    sizes = _families.sizes(CONFIG, **SHARES[request.param])
     _, cfg = family.native(sizes, jnp.float32)
     if request.param == "share":
         assert (cfg.held, cfg.first_expert_held, cfg.n_experts) == (4, 2, 8)
     assert cfg.layer_types == (afmoe.WINDOW, afmoe.WINDOW, afmoe.FULL)
     return sizes, cfg, _seeded(cfg)
+
+
+@pytest.fixture(scope="module")
+def reference(held):
+    """The plain reference's loss and gradients at the held parameters,
+    evaluated once a shape of tokens."""
+    sizes, _, params = held
+    return _families.reference_at(ref, sizes, params)
 
 
 @pytest.mark.parametrize("seq", [40, 80])
@@ -97,7 +78,7 @@ def test_reference_matches_the_torch_module(seq):
     the band's lower edge (window 48) is crossed, at 40 it is not."""
     import torch
 
-    sizes = _sizes(num_experts=8, first_expert_held=0)
+    sizes = _families.sizes(CONFIG, num_experts=8, first_expert_held=0)
     build, torch_config = family.hf(sizes)
     torch.manual_seed(0)
     module = build(torch_config).eval()
@@ -110,7 +91,7 @@ def test_reference_matches_the_torch_module(seq):
     _, cfg = family.native(sizes, jnp.float32)
     arrays = {k: v.detach().numpy() for k, v in module.state_dict().items()}
     params = convert.afmoe_params_from_hf(arrays, cfg)
-    tokens, _ = _tokens(sizes, (2, seq))
+    tokens, _ = _families.tokens(sizes, (2, seq))
     with torch.no_grad():
         want = module(torch.tensor(np.asarray(tokens))).numpy()
     with common.precision(jnp.float32):
@@ -126,25 +107,18 @@ def test_reference_matches_the_torch_module(seq):
 
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_loss_and_gradients_match_the_reference(held, impl, remat):
+def test_loss_and_gradients_match_the_reference(held, reference, impl, remat):
     """Whole and as a share: loss and every gradient, through jnp attention
     and the interpreted flash kernels (banded in the window layers), with
     and without remat."""
     sizes, cfg, params = held
     cfg = dataclasses.replace(cfg, remat=remat)
-    tokens, targets = _tokens(sizes)
+    tokens, targets = _families.tokens(sizes, SHAPE)
     (loss, aux), grads = jax.value_and_grad(
         lambda p: afmoe.loss_fn(p, tokens, targets, cfg, attn_impl=impl),
         has_aux=True,
     )(params)
-    want, want_grads = jax.value_and_grad(_ref_loss)(
-        params, tokens, targets, sizes
-    )
-    assert abs(float(loss) - float(want)) < 1e-5
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
-        scale = float(jnp.abs(w).max()) + 1e-8
-        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    _families.assert_matches_reference(loss, grads, *reference(tokens.shape))
     # the selection bias is a buffer: no gradient moves it
     assert not np.asarray(grads["moe_layers"]["router_bias"]).any()
     n = tokens.size * cfg.experts_per_token * cfg.n_moe_layers
@@ -156,8 +130,8 @@ def test_loss_and_gradients_match_the_reference(held, impl, remat):
 def test_four_shares_add_up_to_the_whole_layer():
     """32 experts in four shares of 8: the routed parts summed, the shared
     expert counted once, are the uncut reference's expert sub-block."""
-    sizes = _sizes(
-        num_experts=32, num_experts_total=32, first_expert_held=0,
+    sizes = _families.sizes(
+        CONFIG, num_experts=32, num_experts_total=32, first_expert_held=0,
         num_experts_per_tok=8,
     )
     _, cfg = family.native(sizes, jnp.float32)
@@ -230,15 +204,15 @@ def test_rope_only_in_window_layers_and_gate_and_norms(monkeypatch, what):
     """Each mechanism taken out of the program moves the loss past the
     tolerance that holds the program to the reference (1e-5), so none can
     be left out unseen."""
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     cfg = dataclasses.replace(cfg, remat=False)  # leaves stay themselves
     params = _seeded(cfg)
     # weights large enough for every sub-block to move the logits
     params = jax.tree.map(lambda a: a * 4.0 if a.ndim > 2 else a, params)
     params["lm_head"]["weight"] = params["lm_head"]["weight"] * 30.0
-    tokens, targets = _tokens(sizes)
-    want = float(_ref_loss(params, tokens, targets, sizes))
+    tokens, targets = _families.tokens(sizes, SHAPE)
+    want = float(_families.ref_loss(ref, params, tokens, targets, sizes))
     got = float(afmoe.loss_fn(params, tokens, targets, cfg, attn_impl="jnp")[0])
     assert abs(got - want) < 1e-5
     if what == "embed_scale":
@@ -270,8 +244,8 @@ def test_layer_kinds_are_static_and_the_published_pattern_runs():
     and end off a period's boundary, and the program still agrees with the
     reference; each window layer builds a banded call, each full layer a
     plain one."""
-    sizes = _sizes(
-        num_hidden_layers=8, num_dense_layers=2, first_full_layer=3,
+    sizes = _families.sizes(
+        CONFIG, num_hidden_layers=8, num_dense_layers=2, first_full_layer=3,
         layer_types=["sliding_attention"] * 3 + ["full_attention"]
         + ["sliding_attention"] * 3 + ["full_attention"],
     )
@@ -279,11 +253,12 @@ def test_layer_kinds_are_static_and_the_published_pattern_runs():
     assert cfg == dataclasses.replace(cfg, layer_types=None)  # the default
     assert afmoe._period(cfg.layer_types[2:]) == 4
     params = _seeded(cfg)
-    tokens, targets = _tokens(sizes)
+    tokens, targets = _families.tokens(sizes, SHAPE)
     c0 = telemetry.counters()
     loss, _ = afmoe.loss_fn(params, tokens, targets, cfg, attn_impl="pallas")
     c1 = telemetry.counters()
-    assert abs(float(loss) - float(_ref_loss(params, tokens, targets, sizes))) < 1e-5
+    want = _families.ref_loss(ref, params, tokens, targets, sizes)
+    assert abs(float(loss) - float(want)) < 1e-5
     # counted per trace (a scan's body once, a block met again from the
     # cache): some calls banded, and some not
     window = c1["attention.flash_window{window=48}"] - c0.get(
@@ -302,7 +277,7 @@ def test_absent_experts_are_never_materialized():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, torch_config = family.hf(sizes)
     module = di.deferred_init(build, torch_config)
     _, cfg = family.native(sizes, jnp.float32)
@@ -341,7 +316,8 @@ def test_scopes_and_counters():
     ``gate`` and the kernels under it (banded in the window layers, plain
     in the full one), ``mlp``, ``moe/router|dispatch|experts|combine|
     shared``; the host counters of the share and of the window."""
-    sizes, (_, cfg) = _sizes(), family.native(_sizes(), jnp.float32)
+    sizes = _families.sizes(CONFIG)
+    _, cfg = family.native(sizes, jnp.float32)
     params = jax.eval_shape(lambda: afmoe.init_params(jax.random.PRNGKey(0), cfg))
     tok = jax.ShapeDtypeStruct((1, 128), jnp.int32)
     c0, h0 = telemetry.counters(), telemetry.histograms()

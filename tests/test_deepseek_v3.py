@@ -8,9 +8,6 @@ CPU, float32, seeded: values and counts only.
 
 import dataclasses
 import functools
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,43 +19,19 @@ from torchdistx_tpu.models import convert, deepseek_v3 as ds, llama as llama_mod
 from torchdistx_tpu.ops import routed_experts as routed_mod
 from torchdistx_tpu.ops.routed_experts import routed_experts
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-from families import deepseek_v3 as family  # noqa: E402
-from reference import common, deepseek_v3 as ref  # noqa: E402
+import _families  # puts benchmarks/ on sys.path
+from families import deepseek_v3 as family
+from reference import common, deepseek_v3 as ref
 
 CONFIG = "kanana-2-30b-a3b-instruct-2601"
-
-
-def _sizes(**over):
-    """The configuration file's ``tiny`` block over its published keys."""
-    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
-        c = json.load(f)
-    c.update(c.pop("tiny"))
-    c.update(over)
-    return c
-
-
-def _ref_loss(params, tokens, targets, sizes):
-    with common.precision(jnp.float32):
-        x = ref.hidden(params, tokens, sizes, jnp.float32)
-        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
-
-
-def _tokens(sizes, shape=(2, 48), seed=1):
-    ids = np.random.default_rng(seed).integers(
-        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
-    )
-    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+SHAPE = (2, 48)
 
 
 @pytest.fixture(scope="module")
 def share():
     """Sizes, native config and seeded parameters of a share: experts 2-5
     of 8, selection bias not zero."""
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     mod, cfg = family.native(sizes, jnp.float32)
     assert (cfg.held, cfg.first_expert_held, cfg.n_experts) == (4, 2, 8)
     params = mod.init_params(jax.random.PRNGKey(0), cfg)
@@ -68,12 +41,20 @@ def share():
     return sizes, cfg, params
 
 
+@pytest.fixture(scope="module")
+def reference(share):
+    """The plain reference's loss and gradients at the share's parameters,
+    evaluated once a shape of tokens."""
+    sizes, _, params = share
+    return _families.reference_at(ref, sizes, params)
+
+
 def test_reference_matches_transformers():
     """(a) every expert held: the plain reference's logits are those of
     ``DeepseekV3ForCausalLM`` (eager attention) on the same weights."""
     import torch
 
-    sizes = _sizes(n_routed_experts=8, first_expert_held=0)
+    sizes = _families.sizes(CONFIG, n_routed_experts=8, first_expert_held=0)
     build, hf_config = family.hf(sizes)
     hf_config._attn_implementation = "eager"
     torch.manual_seed(0)
@@ -88,7 +69,7 @@ def test_reference_matches_transformers():
         }.items()
     }
     params = convert.deepseek_v3_params_from_hf(arrays, cfg)
-    tokens, _ = _tokens(sizes)
+    tokens, _ = _families.tokens(sizes, SHAPE)
     with torch.no_grad():
         want = module(torch.tensor(np.asarray(tokens))).logits.numpy()
     with common.precision(jnp.float32):
@@ -104,24 +85,17 @@ def test_reference_matches_transformers():
 
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_loss_and_gradients_match_the_reference(share, impl, remat):
+def test_loss_and_gradients_match_the_reference(share, reference, impl, remat):
     """(b) a held subset: loss and every gradient, through jnp attention
     and the interpreted flash kernels, with and without remat."""
     sizes, cfg, params = share
     cfg = dataclasses.replace(cfg, remat=remat)
-    tokens, targets = _tokens(sizes)
+    tokens, targets = _families.tokens(sizes, SHAPE)
     (loss, aux), grads = jax.value_and_grad(
         lambda p: ds.loss_fn(p, tokens, targets, cfg, attn_impl=impl),
         has_aux=True,
     )(params)
-    want, want_grads = jax.value_and_grad(_ref_loss)(
-        params, tokens, targets, sizes
-    )
-    assert abs(float(loss) - float(want)) < 1e-5
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
-        scale = float(jnp.abs(w).max()) + 1e-8
-        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    _families.assert_matches_reference(loss, grads, *reference(tokens.shape))
     # the selection bias is a buffer: no gradient moves it
     assert not np.asarray(grads["moe_layers"]["router_bias"]).any()
     n = tokens.size * cfg.experts_per_token * cfg.n_moe_layers
@@ -132,9 +106,9 @@ def test_loss_and_gradients_match_the_reference(share, impl, remat):
 def test_four_shares_add_up_to_the_whole_layer():
     """(c) 32 experts in four shares of 8: the routed parts summed, the
     shared expert counted once, are the uncut reference's layer output."""
-    sizes = _sizes(
-        n_routed_experts=32, n_routed_experts_total=32, first_expert_held=0,
-        num_experts_per_tok=6,
+    sizes = _families.sizes(
+        CONFIG, n_routed_experts=32, n_routed_experts_total=32,
+        first_expert_held=0, num_experts_per_tok=6,
     )
     _, cfg = family.native(sizes, jnp.float32)
     cfg = dataclasses.replace(cfg, n_dense_layers=0, n_moe_layers=1)
@@ -625,7 +599,7 @@ def test_absent_experts_are_never_materialized():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, hf_config = family.hf(sizes)
     module = di.deferred_init(build, hf_config)
     _, cfg = family.native(sizes, jnp.float32)
@@ -667,7 +641,8 @@ def test_scopes_and_counters(monkeypatch):
     """The names a trace is read by: ``attn`` with the kernels under it,
     ``mlp``, ``moe/router|dispatch|experts|combine|shared``; the host
     counters of the share and of the rotation's form."""
-    sizes, (_, cfg) = _sizes(), family.native(_sizes(), jnp.float32)
+    sizes = _families.sizes(CONFIG)
+    _, cfg = family.native(sizes, jnp.float32)
     params = jax.eval_shape(lambda: ds.init_params(jax.random.PRNGKey(0), cfg))
     tok = jax.ShapeDtypeStruct((1, 32), jnp.int32)
     traced, attn = [], ds._attn

@@ -10,10 +10,7 @@ CPU, float32, seeded: values and counts only.
 
 import dataclasses
 import functools
-import json
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -26,48 +23,13 @@ from torchdistx_tpu.ops.attention import mha_reference
 from torchdistx_tpu.ops.pallas.flash_attention import flash_attention
 from torchdistx_tpu.ops.selective_scan import selective_scan
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-from benchlib import ssm  # noqa: E402
-from families import jamba as family  # noqa: E402
-from reference import common, jamba as ref  # noqa: E402
+import _families  # puts benchmarks/ on sys.path
+from benchlib import ssm
+from families import jamba as family
+from reference import common, jamba as ref
 
 CONFIG = "ai21-jamba2-3b"
-
-
-def _file():
-    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
-        return json.load(f)
-
-
-def _sizes(**over):
-    """The configuration file's ``tiny`` block over its published keys."""
-    c = _file()
-    c.update(c.pop("tiny"))
-    c.update(over)
-    return c
-
-
-class _Static(dict):
-    """A configuration's sizes as a static argument."""
-
-    def __hash__(self):
-        return hash(json.dumps(self, sort_keys=True))
-
-
-def _ref_loss(params, tokens, targets, sizes):
-    with common.precision(jnp.float32):
-        x = ref.hidden(params, tokens, sizes, jnp.float32)
-        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
-
-
-def _tokens(sizes, shape=(2, 40), seed=1):
-    ids = np.random.default_rng(seed).integers(
-        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
-    )
-    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+SHAPE = (2, 40)
 
 
 def _unsettle(params, seed=7):
@@ -87,10 +49,18 @@ def _unsettle(params, seed=7):
 def tiny():
     """Sizes, native config and seeded parameters: two periods of four
     layers, the attention layer second."""
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     assert (cfg.n_periods, cfg.stacks) == (2, {"mamba_a": 1, "mamba_b": 2})
     return sizes, cfg, _unsettle(jamba.init_params(jax.random.PRNGKey(0), cfg))
+
+
+@pytest.fixture(scope="module")
+def reference(tiny):
+    """The plain reference's loss and gradients at the tiny parameters,
+    evaluated once a shape of tokens."""
+    sizes, _, params = tiny
+    return _families.reference_at(ref, sizes, params)
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +73,7 @@ def test_reference_matches_transformers():
     and so are the native family's through both scans."""
     import torch
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, hf_config = family.hf(sizes)
     assert hf_config.use_mamba_kernels is False
     hf_config._attn_implementation = "eager"
@@ -119,7 +89,7 @@ def test_reference_matches_transformers():
     assert jax.tree.map(jnp.shape, params) == jax.tree.map(
         lambda a: a.shape, jamba.abstract_params(cfg)
     )
-    tokens, _ = _tokens(sizes)
+    tokens, _ = _families.tokens(sizes, SHAPE)
     with torch.no_grad():
         want = module(torch.tensor(np.asarray(tokens))).logits.numpy()
     with common.precision(jnp.float32):
@@ -140,31 +110,26 @@ def test_reference_matches_transformers():
 @pytest.mark.parametrize("seq", [40, 64])
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_loss_and_gradients_match_the_reference(tiny, impl, remat, seq):
+def test_loss_and_gradients_match_the_reference(
+    tiny, reference, impl, remat, seq
+):
     """Loss and every gradient, through the ``jnp`` scan with jnp attention
     and through the interpreted kernels (scan and flash), with and without
     the blocks' remat, across a period boundary."""
     sizes, cfg, params = tiny
     cfg = dataclasses.replace(cfg, remat=remat, scan_impl=impl)
-    tokens, targets = _tokens(sizes, (2, seq))
+    tokens, targets = _families.tokens(sizes, (2, seq))
     loss, grads = jax.jit(jax.value_and_grad(
         lambda p: jamba.loss_fn(p, tokens, targets, cfg, attn_impl=impl)
     ))(params)
-    want, want_grads = jax.jit(
-        jax.value_and_grad(_ref_loss), static_argnums=3
-    )(params, tokens, targets, _Static(sizes))
-    assert abs(float(loss) - float(want)) < 1e-5
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
-        scale = float(jnp.abs(w).max()) + 1e-8
-        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    _families.assert_matches_reference(loss, grads, *reference(tokens.shape))
 
 
 def test_row_blocked_head_is_the_whole_head(tiny, monkeypatch):
     """The loss in row blocks is ``llama._head_ce``'s loss
     and gradient (the tied embedding's included)."""
     sizes, cfg, params = tiny
-    tokens, targets = _tokens(sizes, (2, 64))
+    tokens, targets = _families.tokens(sizes, (2, 64))
     whole = jax.value_and_grad(
         lambda p: jamba.loss_fn(p, tokens, targets, cfg, attn_impl="jnp")
     )(params)
@@ -180,7 +145,7 @@ def test_row_blocked_head_is_the_whole_head(tiny, monkeypatch):
 def test_parameters_are_counted_as_the_configuration_file_states():
     """One period at published widths: 1,598,556,096 parameters; the
     whole model 3,029.3M; the matrices a token multiplies 1,596,948,480."""
-    sizes = _file()
+    sizes = _families.published(CONFIG)
     mod, cfg = family.native(sizes, jnp.bfloat16)
     assert mod is jamba and (cfg.n_periods, cfg.d_inner) == (1, 5120)
     assert jamba.num_params(cfg) == 1_598_556_096
@@ -370,7 +335,7 @@ def test_scopes_and_counters():
     """The names a trace is read by: ``mamba`` with its five parts and the
     scan kernels under ``mamba/scan``, ``attn`` with the flash kernels,
     ``mlp``, ``embed``, ``head``; the scan's counters."""
-    _, cfg = family.native(_sizes(), jnp.float32)
+    _, cfg = family.native(_families.sizes(CONFIG), jnp.float32)
     cfg = dataclasses.replace(cfg, scan_impl="pallas")
     params = jax.eval_shape(lambda: jamba.init_params(jax.random.PRNGKey(0), cfg))
     tok = jax.ShapeDtypeStruct((1, 64), jnp.int32)
@@ -406,7 +371,7 @@ def test_deferred_init_materializes_the_state_space_fills():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, hf_config = family.hf(sizes)
     module = di.deferred_init(build, hf_config)
     _, cfg = family.native(sizes, jnp.float32)
@@ -452,7 +417,7 @@ def test_no_row_of_the_embedding_is_a_pad_row():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, hf_config = family.hf(sizes)
     assert hf_config.pad_token_id is None
     arrays = M.materialize_module_jax(
@@ -466,7 +431,7 @@ def test_no_row_of_the_embedding_is_a_pad_row():
     # What a pad row did: three Mamba layers before the attention layer.
     cfg = dataclasses.replace(cfg, n_layers=4, attn_offset=3)
     params = jamba.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, targets = _tokens(sizes, (1, 32), seed=2)
+    tokens, targets = _families.tokens(sizes, (1, 32), seed=2)
     tokens = jnp.maximum(tokens, 1).at[0, 9].set(0)  # token 0 once
 
     def row_norms(p):

@@ -12,9 +12,6 @@ CPU, float32, seeded: values and counts only.
 """
 
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,39 +22,14 @@ from torchdistx_tpu import telemetry
 from torchdistx_tpu.models import convert, minicpm_sala
 from torchdistx_tpu.ops import sparse_attention as sa
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-from families import minicpm_sala as family  # noqa: E402
-from reference import common  # noqa: E402
-from reference import minicpm_sala as ref  # noqa: E402
+import _families  # puts benchmarks/ on sys.path
+from families import minicpm_sala as family
+from reference import common
+from reference import minicpm_sala as ref
 
 CONFIG = "minicpm-sala"
+SHAPE = (2, 128)
 SPARSE, LIGHTNING = minicpm_sala.SPARSE, minicpm_sala.LIGHTNING
-
-
-def _sizes(**over):
-    """The configuration file's ``tiny`` block over its published keys."""
-    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
-        c = json.load(f)
-    c.update(c.pop("tiny"))
-    c["training"] = dict(c["training"], lightning_chunk=32)
-    c.update(over)
-    return c
-
-
-def _tokens(sizes, shape=(2, 128), seed=1):
-    ids = np.random.default_rng(seed).integers(
-        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
-    )
-    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
-
-
-def _ref_loss(params, tokens, targets, sizes):
-    with common.precision(jnp.float32):
-        x = ref.hidden(params, tokens, sizes, jnp.float32)
-        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
 
 
 def _seeded(cfg, seed=0):
@@ -78,11 +50,19 @@ def _seeded(cfg, seed=0):
 
 @pytest.fixture(scope="module")
 def tiny():
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     assert cfg.mixer_types == (SPARSE, LIGHTNING, LIGHTNING, LIGHTNING) * 2
     assert cfg.n_heads // cfg.n_kv_heads == 2
     return sizes, cfg, _seeded(cfg)
+
+
+@pytest.fixture(scope="module")
+def reference(tiny):
+    """The plain reference's loss and gradients at the tiny parameters,
+    evaluated once a shape of tokens."""
+    sizes, _, params = tiny
+    return _families.reference_at(ref, sizes, params)
 
 
 @pytest.mark.parametrize("seq", [32, 128])
@@ -93,7 +73,7 @@ def test_reference_matches_the_torch_module(seq):
     8), at 32 (``dense_len``) they are dense."""
     import torch
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, torch_config = family.hf(sizes)
     torch.manual_seed(0)
     module = build(torch_config).eval()
@@ -106,7 +86,7 @@ def test_reference_matches_the_torch_module(seq):
     _, cfg = family.native(sizes, jnp.float32)
     arrays = {k: v.detach().numpy() for k, v in module.state_dict().items()}
     params = convert.minicpm_sala_params_from_hf(arrays, cfg)
-    tokens, _ = _tokens(sizes, (2, seq))
+    tokens, _ = _families.tokens(sizes, (2, seq))
     with torch.no_grad():
         want = module(torch.tensor(np.asarray(tokens))).numpy()
     with common.precision(jnp.float32):
@@ -122,23 +102,20 @@ def test_reference_matches_the_torch_module(seq):
 @pytest.mark.parametrize("seq", [32, 128])
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_loss_and_gradients_match_the_reference(tiny, impl, remat, seq):
+def test_loss_and_gradients_match_the_reference(
+    tiny, reference, impl, remat, seq
+):
     """Loss and every gradient, on the sparse path (128 > ``dense_len``)
     and the dense one (32), through the jnp paths and the interpreted
     kernels, with and without remat; two periods under the period scan."""
     sizes, cfg, params = tiny
     cfg = dataclasses.replace(cfg, remat=remat)
-    tokens, targets = _tokens(sizes, (2, seq))
+    tokens, targets = _families.tokens(sizes, (2, seq))
     (loss, aux), grads = jax.value_and_grad(
         lambda p: minicpm_sala.loss_fn(p, tokens, targets, cfg, attn_impl=impl),
         has_aux=True,
     )(params)
-    want, want_grads = jax.value_and_grad(_ref_loss)(params, tokens, targets, sizes)
-    assert abs(float(loss) - float(want)) < 1e-5
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), w in zip(flat, jax.tree.leaves(want_grads), strict=True):
-        scale = float(jnp.abs(w).max()) + 1e-8
-        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    _families.assert_matches_reference(loss, grads, *reference(tokens.shape))
     if seq > sizes["sparse_dense_len"]:
         assert 6 <= float(aux["sparse"]["kv_blocks"]) <= 16
     else:
@@ -150,7 +127,7 @@ def test_the_programs_choice_is_the_references(tiny):
     in float32 exactly the reference's choice; a choice handed to the
     reference is the one it attends."""
     sizes, cfg, params = tiny
-    tokens, _ = _tokens(sizes)
+    tokens, _ = _families.tokens(sizes, SHAPE)
     words = minicpm_sala.sparse_choice(params, tokens, cfg)
     program = np.asarray(sa.unpack_blocks(words, 16))
     with common.precision(jnp.float32):
@@ -181,7 +158,7 @@ def test_the_paper_path_gives_the_native_tree():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, torch_config = family.hf(sizes)
     module = di.deferred_init(build, torch_config)
     _, cfg = family.native(sizes, jnp.float32)
@@ -194,7 +171,7 @@ def test_the_paper_path_gives_the_native_tree():
     assert (np.asarray(params["lightning_layers"]["o_norm"]) == 1).all()
     assert 0.8 < float(np.asarray(params["embed"]["weight"]).std()) < 1.2
     assert 0.015 < float(np.asarray(params["sparse_layers"]["wq"]).std()) < 0.025
-    tokens, _ = _tokens(sizes)
+    tokens, _ = _families.tokens(sizes, SHAPE)
     logits = minicpm_sala.forward(params, tokens, cfg, attn_impl="jnp")
     assert np.isfinite(np.asarray(logits)).all()
 
@@ -212,7 +189,9 @@ def test_published_sizes():
     with pytest.raises(ValueError, match="mixer_types"):
         minicpm_sala.MiniCPMSALAConfig(n_layers=4)
     with pytest.raises(ValueError, match="disagree"):
-        family.native(dict(_sizes(), first_sparse_layer=1), jnp.float32)
+        family.native(
+            dict(_families.sizes(CONFIG), first_sparse_layer=1), jnp.float32
+        )
 
 
 def test_scopes_and_counters(tiny):

@@ -11,9 +11,6 @@ CPU, float32, seeded: values and counts only.
 """
 
 import dataclasses
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,38 +22,14 @@ from torchdistx_tpu.models import _common, convert, smallthinker
 from torchdistx_tpu.models import llama as llama_mod
 from torchdistx_tpu.ops import routed_experts as routed_mod
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-
-from families import smallthinker as family  # noqa: E402
-from reference import common  # noqa: E402
-from reference import smallthinker as ref  # noqa: E402
+import _families  # puts benchmarks/ on sys.path
+from families import smallthinker as family
+from reference import common
+from reference import smallthinker as ref
 
 CONFIG = "smallthinker-21ba3b-instruct"
+SHAPE = (2, 80)
 WINDOW, FULL = smallthinker.WINDOW, smallthinker.FULL
-
-
-def _sizes(**over):
-    """The configuration file's ``tiny`` block over its published keys."""
-    with open(os.path.join(BENCH, "configs", f"{CONFIG}.json")) as f:
-        c = json.load(f)
-    c.update(c.pop("tiny"))
-    c.update(over)
-    return c
-
-
-def _ref_loss(params, tokens, targets, sizes):
-    with common.precision(jnp.float32):
-        x = ref.hidden(params, tokens, sizes, jnp.float32)
-        return common.cross_entropy(ref.head(params, x, jnp.float32), targets)
-
-
-def _tokens(sizes, shape=(2, 80), seed=1):
-    ids = np.random.default_rng(seed).integers(
-        0, sizes["vocab_size"], size=(shape[0], shape[1] + 1)
-    )
-    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
 
 
 def _seeded(cfg, seed=0):
@@ -85,13 +58,21 @@ SHARES = {
 @pytest.fixture(scope="module", params=list(SHARES))
 def held(request):
     """Sizes, native config and seeded parameters, whole and as a share."""
-    sizes = _sizes(**SHARES[request.param])
+    sizes = _families.sizes(CONFIG, **SHARES[request.param])
     _, cfg = family.native(sizes, jnp.float32)
     if request.param == "share":
         assert (cfg.held, cfg.first_expert_held, cfg.n_experts) == (4, 2, 8)
     assert cfg.layer_types == (FULL, WINDOW, WINDOW, WINDOW) * 2
     assert cfg.n_heads // cfg.n_kv_heads == 3  # not a power of two
     return sizes, cfg, _seeded(cfg)
+
+
+@pytest.fixture(scope="module")
+def reference(held):
+    """The plain reference's loss and gradients at the held parameters,
+    evaluated once a shape of tokens."""
+    sizes, _, params = held
+    return _families.reference_at(ref, sizes, params)
 
 
 @pytest.mark.parametrize("seq", [40, 80])
@@ -102,7 +83,7 @@ def test_reference_matches_the_torch_module(seq):
     crossed, at 40 it is not.  And so are the native family's."""
     import torch
 
-    sizes = _sizes(moe_num_primary_experts=8, first_expert_held=0)
+    sizes = _families.sizes(CONFIG, moe_num_primary_experts=8, first_expert_held=0)
     build, torch_config = family.hf(sizes)
     torch.manual_seed(0)
     module = build(torch_config).eval()
@@ -113,7 +94,7 @@ def test_reference_matches_the_torch_module(seq):
     _, cfg = family.native(sizes, jnp.float32)
     arrays = {k: v.detach().numpy() for k, v in module.state_dict().items()}
     params = convert.smallthinker_params_from_hf(arrays, cfg)
-    tokens, _ = _tokens(sizes, (2, seq))
+    tokens, _ = _families.tokens(sizes, (2, seq))
     with torch.no_grad():
         want = module(torch.tensor(np.asarray(tokens))).numpy()
     with common.precision(jnp.float32):
@@ -128,26 +109,19 @@ def test_reference_matches_the_torch_module(seq):
 
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_loss_and_gradients_match_the_reference(held, impl, remat):
+def test_loss_and_gradients_match_the_reference(held, reference, impl, remat):
     """Whole and as a share (``first_expert_held`` 2): loss and every
     gradient, through jnp attention and the interpreted flash kernels
     (banded in the window layers) at a group of three, with and without
     remat; the row-blocked head against the reference's whole one."""
     sizes, cfg, params = held
     cfg = dataclasses.replace(cfg, remat=remat)
-    tokens, targets = _tokens(sizes)
+    tokens, targets = _families.tokens(sizes, SHAPE)
     (loss, aux), grads = jax.value_and_grad(
         lambda p: smallthinker.loss_fn(p, tokens, targets, cfg, attn_impl=impl),
         has_aux=True,
     )(params)
-    want, want_grads = jax.value_and_grad(_ref_loss)(
-        params, tokens, targets, sizes
-    )
-    assert abs(float(loss) - float(want)) < 1e-5
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), w in zip(flat, jax.tree.leaves(want_grads), strict=True):
-        scale = float(jnp.abs(w).max()) + 1e-8
-        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, path
+    _families.assert_matches_reference(loss, grads, *reference(tokens.shape))
     n = tokens.size * cfg.experts_per_token * cfg.n_layers
     assigned = float(aux["moe"]["local_assignments"])
     assert assigned == n if cfg.held == cfg.n_experts else 0 < assigned < n
@@ -156,10 +130,10 @@ def test_loss_and_gradients_match_the_reference(held, impl, remat):
 
 def test_the_head_goes_in_row_blocks(monkeypatch):
     """Several blocks of rows give the loss and the gradients of one."""
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     params = _seeded(cfg)
-    tokens, targets = _tokens(sizes, (2, 64))
+    tokens, targets = _families.tokens(sizes, (2, 64))
 
     def run():
         return jax.value_and_grad(
@@ -180,8 +154,8 @@ def test_four_shares_add_up_to_the_whole_layer():
     """32 experts in four shares of 8, six a token: the routed parts the
     shares give, each routed by the LAYER'S INPUT and fed the experts'
     own, summed, are the uncut reference's routed sum."""
-    sizes = _sizes(
-        moe_num_primary_experts=32, moe_num_primary_experts_total=32,
+    sizes = _families.sizes(
+        CONFIG, moe_num_primary_experts=32, moe_num_primary_experts_total=32,
         first_expert_held=0, moe_num_active_primary_experts=6,
     )
     _, cfg = family.native(sizes, jnp.float32)
@@ -207,10 +181,10 @@ def test_four_shares_add_up_to_the_whole_layer():
 def test_the_period_scan_is_the_layers_one_by_one():
     """Two periods under one scan against the eight layers unrolled, each
     by the block of its own kind: the same hidden states and counts."""
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     params = _seeded(cfg)
-    tokens, _ = _tokens(sizes)
+    tokens, _ = _families.tokens(sizes, SHAPE)
     x, moe = smallthinker._forward_hidden(params, tokens, cfg, attn_impl="jnp")
     block_of = smallthinker._build_block(cfg, attn_impl="jnp")
     y, assigned = llama_mod._embed(params, tokens, cfg), 0.0
@@ -261,11 +235,11 @@ def _loss_with(monkeypatch, what, cfg, params, tokens, targets):
     "window layers plain causal",
 ])
 def test_each_mechanism_is_seen_by_the_loss(monkeypatch, what):
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, cfg = family.native(sizes, jnp.float32)
     params = _seeded(cfg)
-    tokens, targets = _tokens(sizes)
-    want = float(_ref_loss(params, tokens, targets, sizes))
+    tokens, targets = _families.tokens(sizes, SHAPE)
+    want = float(_families.ref_loss(ref, params, tokens, targets, sizes))
     assert abs(
         _loss_with(monkeypatch, None, cfg, params, tokens, targets) - want
     ) < 2e-6
@@ -284,7 +258,7 @@ def test_the_published_layout_is_the_default():
     from torchdistx_tpu.models import afmoe
 
     assert afmoe._period(cfg.layer_types) == 4
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     _, native = family.native(sizes, jnp.float32)
     assert native == dataclasses.replace(native, layer_types=None)
     with pytest.raises(ValueError, match="disagree"):
@@ -302,7 +276,7 @@ def test_absent_experts_are_never_materialized():
     import torchdistx_tpu.deferred_init as di
     import torchdistx_tpu.materialize as M
 
-    sizes = _sizes()
+    sizes = _families.sizes(CONFIG)
     build, torch_config = family.hf(sizes)
     module = di.deferred_init(build, torch_config)
     _, cfg = family.native(sizes, jnp.float32)
@@ -339,7 +313,8 @@ def test_scopes_and_counters():
     kernels under it (banded in the window layers, plain in the full
     ones), ``moe/dispatch|experts|combine``, ``stack``; the counters of the
     share, the unit, the router's input and the window."""
-    sizes, (_, cfg) = _sizes(), family.native(_sizes(), jnp.float32)
+    sizes = _families.sizes(CONFIG)
+    _, cfg = family.native(sizes, jnp.float32)
     params = jax.eval_shape(
         lambda: smallthinker.init_params(jax.random.PRNGKey(0), cfg)
     )

@@ -79,14 +79,20 @@ def _step(family, tx=None, **kw):
 
 
 @functools.lru_cache(maxsize=None)
-def _paths(family, attn_impl="auto"):
-    """Every ``op_name`` path of the family's optimized train step (one
-    compile a family for the tests that only read paths)."""
+def _compiled(family, attn_impl):
+    """The text of the family's optimized train step: one compile a family
+    (and attention) for every test that reads the scoped program."""
     cfg, mesh, init_fn, step_fn = _step(family, attn_impl=attn_impl)
     state = init_fn(jax.random.PRNGKey(0))
-    text = step_fn.lower(state, _batch(cfg, mesh)).compile().as_text()
+    return step_fn.lower(state, _batch(cfg, mesh)).compile().as_text()
+
+
+@functools.lru_cache(maxsize=None)
+def _paths(family, attn_impl="auto"):
+    """Every ``op_name`` path of the family's optimized train step."""
     return frozenset(
-        p for ps in perf.hlo_scopes(text).values() for p in ps if p
+        p for ps in perf.hlo_scopes(_compiled(family, attn_impl)).values()
+        for p in ps if p
     )
 
 
@@ -174,16 +180,14 @@ def test_compiled_step_carries_the_six_scopes(family):
 def test_scopes_are_metadata_only(family, monkeypatch):
     """The optimized program built with every ``jax.named_scope`` a no-op
     is, metadata aside, the program built with them."""
-    cfg, mesh, init_fn, step_fn = _step(family)
-    state = init_fn(jax.random.PRNGKey(0))
-    batch = _batch(cfg, mesh)
-    scoped = step_fn.lower(state, batch).compile().as_text()
+    scoped = _compiled(family, "auto")
     assert "/optimizer/" in scoped
     monkeypatch.setattr(
         jax, "named_scope", lambda name: contextlib.nullcontext()
     )
-    _, _, _, plain_fn = _step(family)
-    plain = plain_fn.lower(state, batch).compile().as_text()
+    cfg, mesh, init_fn, plain_fn = _step(family)
+    state = init_fn(jax.random.PRNGKey(0))
+    plain = plain_fn.lower(state, _batch(cfg, mesh)).compile().as_text()
     assert "/optimizer/" not in plain
     assert _bare(scoped) == _bare(plain)
 
