@@ -42,6 +42,9 @@ def one_chip():
         (4096, 8, 2, 128, 128, ["flash_bwd_fused"]),
         # the VMEM budget's edge, 16 MiB of dq, and twice that
         (32768, 1, 1, 128, 128, ["flash_bwd_fused"]),
+        # Olmo-Hybrid-7B's full layer at 16k: 30 heads on 30, a group of
+        # one, 8 MiB of dq
+        (16384, 30, 30, 128, 128, ["flash_bwd_fused"]),
         (65536, 1, 1, 128, 128, ["flash_bwd_dkv", "flash_bwd_dq"]),
     ],
 )
@@ -190,3 +193,29 @@ def test_selective_scan_kernels_compile_for_a_v5e(one_chip, seq, chunk):
         lambda *x: kernels.backward(*x, chunk=chunk, interpret=False)
     ).lower(wide, wide, a, narrow, narrow, d, starts, wide).compile()
     assert "ssm_scan_bwd" in backward.as_text()
+
+
+@pytest.mark.parametrize("chunk", [128])
+def test_delta_rule_kernels_compile_for_a_v5e(one_chip, chunk):
+    """``delta_rule_fwd`` and ``delta_rule_bwd`` at Olmo-Hybrid-7B's widths
+    (30 heads, keys of 96 and values of 192, neither a multiple of the 128
+    lanes; bfloat16) over 16,384 positions: the blocks tile, a step's row
+    of the gates is read and written where it lies, and the chunk's tiles
+    fit the VMEM the kernels ask for."""
+    from torchdistx_tpu.ops.pallas import delta_rule as kernels
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    seq, heads = 16384, 30
+    qk, v = spec((1, heads, seq, 96)), spec((1, heads, seq, 192))
+    gate = spec((1, heads, seq), jnp.float32)
+    forward = jax.jit(
+        lambda *x: kernels.forward(*x, chunk=chunk, interpret=False)
+    ).lower(qk, qk, v, gate, gate).compile()
+    assert "delta_rule_fwd" in forward.as_text()
+    starts = spec((1, heads, seq // chunk, 96, 192), jnp.float32)
+    backward = jax.jit(
+        lambda *x: kernels.backward(*x, chunk=chunk, interpret=False)
+    ).lower(qk, qk, v, gate, gate, starts, v).compile()
+    assert "delta_rule_bwd" in backward.as_text()

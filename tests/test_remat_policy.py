@@ -17,7 +17,7 @@ import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
 from torchdistx_tpu.models import (
-    afmoe, deepseek_v3, gpt2, jamba, llama, smallthinker,
+    afmoe, deepseek_v3, gpt2, jamba, llama, olmo_hybrid, smallthinker,
 )
 from torchdistx_tpu.ops import routed_experts as routed_mod
 from torchdistx_tpu.ops.pallas.flash_attention import (
@@ -43,6 +43,9 @@ FAMILIES = {
     # Every layer an expert layer whose router reads the layer's input:
     # two periods of four layers under one scan.
     "smallthinker": (smallthinker, smallthinker.smallthinker_test),
+    # Gated delta-rule layers 3 to 1 with a full attention layer: two
+    # periods under one scan; ``impl`` is the delta rule's too.
+    "olmo_hybrid": (olmo_hybrid, olmo_hybrid.olmo_hybrid_test),
 }
 families = pytest.mark.parametrize("family", ["gpt2", "jamba", "llama"])
 routed = pytest.mark.parametrize("family", sorted(ROUTED))
@@ -137,6 +140,11 @@ def test_backward_holds_no_second_flash_fwd(family, seq, n_calls, monkeypatch):
 # recomputes them too: op by op they agree bit for bit (the test below).
 FUSED_APART = dict.fromkeys([*ROUTED, "smallthinker"], 2e-6)
 SUM_ORDER = {"jamba": 1e-6, **FUSED_APART}
+# The delta rule's family: the kept and the recomputed programs fused apart
+# too (7.6e-7 at most), and without remat 2.0e-6 (the gates' leaves, whose
+# gradients are sums over positions of terms far larger than what is left).
+FUSED_APART["olmo_hybrid"] = 2e-6
+SUM_ORDER["olmo_hybrid"] = 5e-6
 
 
 def _gap(a, b):

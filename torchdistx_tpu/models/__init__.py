@@ -23,10 +23,20 @@ ships JAX-native model families designed for the TPU training stack:
   attention mixed by layer, MiniCPM's muP scalings
   (:mod:`torchdistx_tpu.models.minicpm_sala_torch` for the deferred-init
   path).
+* :mod:`torchdistx_tpu.models.olmo_hybrid` — gated delta-rule linear
+  attention (Gated DeltaNet) and full attention with no position term,
+  3 to 1 by layer, OLMo 3's post-norm block
+  (:mod:`torchdistx_tpu.models.olmo_hybrid_torch` for the deferred-init
+  path).
 * :mod:`torchdistx_tpu.models.moe`, :mod:`torchdistx_tpu.models.deepseek_v3`
   — routed-expert families (imported where used).
 """
 
-from . import afmoe, gpt2, jamba, llama, minicpm_sala, smallthinker  # noqa: F401
+from . import (  # noqa: F401
+    afmoe, gpt2, jamba, llama, minicpm_sala, olmo_hybrid, smallthinker,
+)
 
-__all__ = ["afmoe", "gpt2", "jamba", "llama", "minicpm_sala", "smallthinker"]
+__all__ = [
+    "afmoe", "gpt2", "jamba", "llama", "minicpm_sala", "olmo_hybrid",
+    "smallthinker",
+]

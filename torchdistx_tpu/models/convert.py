@@ -38,6 +38,7 @@ __all__ = [
     "afmoe_params_to_hf",
     "smallthinker_params_from_hf",
     "minicpm_sala_params_from_hf",
+    "olmo_hybrid_params_from_hf",
 ]
 
 
@@ -457,6 +458,56 @@ def minicpm_sala_params_from_hf(arrays: Dict[str, Any], cfg):
         "embed": {"weight": _get(arrays, "embed_tokens.weight")},
         "sparse_layers": _stack_layers(arrays, of(SPARSE), _MINICPM_SALA_LAYER),
         "lightning_layers": _stack_layers(arrays, of(LIGHTNING), lightning),
+        "norm": {"weight": _get(arrays, "norm.weight")},
+        "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
+    }
+
+
+# Olmo-Hybrid: leaf of a kind's stack -> name in a layer.
+_OLMO_HYBRID_BLOCK = {
+    "post_attn_norm": "post_attention_layernorm",
+    "post_ff_norm": "post_feedforward_layernorm", "w_gate": "mlp.gate_proj",
+    "w_up": "mlp.up_proj", "w_down": "mlp.down_proj",
+}
+_OLMO_HYBRID_FULL = {
+    **_OLMO_HYBRID_BLOCK, "wq": "self_attn.q_proj", "wk": "self_attn.k_proj",
+    "wv": "self_attn.v_proj", "wo": "self_attn.o_proj",
+    "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm",
+}
+_OLMO_HYBRID_LINEAR = {
+    **_OLMO_HYBRID_BLOCK, "wq": "linear_attn.q_proj", "wk": "linear_attn.k_proj",
+    "wv": "linear_attn.v_proj", "wz": "linear_attn.g_proj",
+    "wb": "linear_attn.b_proj", "wa": "linear_attn.a_proj",
+    "wo": "linear_attn.o_proj", "o_norm": "linear_attn.o_norm",
+}
+
+
+def olmo_hybrid_params_from_hf(arrays: Dict[str, Any], cfg):
+    """Flat Olmo-Hybrid param dict (the names of
+    :mod:`~torchdistx_tpu.models.olmo_hybrid_torch`) -> the two stacks of
+    :mod:`~torchdistx_tpu.models.olmo_hybrid`, each in layer order over the
+    layers of its kind (linears transposed to ``(in, out)``, a depthwise
+    convolution's ``(C, 1, K)`` to its taps ``(K, C)``)."""
+    from .olmo_hybrid import FULL, LINEAR
+
+    def of(kind):
+        return [i for i, k in enumerate(cfg.layer_types) if k == kind]
+
+    linear = of(LINEAR)
+    stack = _stack_layers(arrays, linear, _OLMO_HYBRID_LINEAR)
+    for name in ("q", "k", "v"):
+        stack[f"conv_{name}"] = jnp.stack([
+            _get(arrays, f"layers.{i}.linear_attn.{name}_conv1d.weight")[:, 0, :].T
+            for i in linear
+        ])
+    for name in ("A_log", "dt_bias"):
+        stack[name] = jnp.stack([
+            _get(arrays, f"layers.{i}.linear_attn.{name}") for i in linear
+        ])
+    return {
+        "embed": {"weight": _get(arrays, "embed_tokens.weight")},
+        "linear_layers": stack,
+        "full_layers": _stack_layers(arrays, of(FULL), _OLMO_HYBRID_FULL),
         "norm": {"weight": _get(arrays, "norm.weight")},
         "lm_head": {"weight": _get(arrays, "lm_head.weight").T},
     }

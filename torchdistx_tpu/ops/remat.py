@@ -60,6 +60,13 @@ and chunk-start states (``lightning_attention._la_fwd``): at 16,384
 positions, 32 heads of 128, chunks of 256, 128 MiB + 128 MiB a layer, for
 which ``lightning_fwd`` runs once a layer.
 
+Olmo-Hybrid's gated delta rule: ``delta_out`` ``(B, H, S, d_v)`` in the
+model's dtype and ``delta_starts`` ``(B, H, S/chunk, d_k, d_v)`` float32,
+its result and chunk-start states (``delta_rule._dr_fwd``): at 16,384
+positions, 30 heads of 96 / 192, 0.19 GB for the result and, at a chunk of
+128, 0.28 GB for the starts a layer (0.57 GB at 64), for which
+``delta_rule_fwd`` runs once a layer.
+
 The banded flash kernels of a window call (``flash_win_*``) name the same
 two arrays, so a window layer's forward kernel runs once a layer too.
 
@@ -78,5 +85,5 @@ REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
     "flash_out", "flash_lse", "ssm_out", "ssm_starts",
     "moe_selected", "moe_gate", "moe_up", "moe_out",
     "sparse_choice", "sparse_out", "sparse_lse",
-    "lightning_out", "lightning_starts",
+    "lightning_out", "lightning_starts", "delta_out", "delta_starts",
 )
